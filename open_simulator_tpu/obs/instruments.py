@@ -519,7 +519,7 @@ PULSE_ACHIEVED = gauge(
     "simon_pulse_achieved_fraction",
     "Most recent achieved fraction of the roofline model-optimal time per "
     "warm dispatch: model_optimal_s / measured wall, from cost_analysis "
-    "FLOPs/bytes at OPEN_SIMULATOR_PEAK_GFLOPS / OPEN_SIMULATOR_PEAK_GBS.",
+    "FLOPs/bytes at the device's published peaks (obs/pulse.py PEAKS).",
     ("kernel", "bucket"))
 
 # ---------------------------------------------------------- capacity search ---

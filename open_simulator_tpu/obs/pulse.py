@@ -32,9 +32,9 @@ Three parts:
    optionally at dispatch time (OPEN_SIMULATOR_PULSE_ROOFLINE=1) on each
    COLD dispatch at the real shape, giving per-(kernel, digest)
    model-optimal seconds `max(flops/peak_flops, bytes/peak_bw)` and an
-   achieved-fraction gauge per warm dispatch. Peaks come from
-   OPEN_SIMULATOR_PEAK_GFLOPS / OPEN_SIMULATOR_PEAK_GBS (conservative host
-   defaults; set them to the accelerator's datasheet numbers there).
+   achieved-fraction gauge per warm dispatch. Peaks come from `PEAKS`, the
+   device's published numbers keyed by jax's `device_kind`; a device not in
+   the table has no roofline (null), never a default.
 
 3. **Drift detection.** Rolling per-(kernel, digest) warm-wall windows with
    MAD outlier flagging: a warm dispatch slower than
@@ -71,6 +71,7 @@ import functools
 import json
 import os
 import statistics
+import sys
 import threading
 import time
 from collections import deque
@@ -90,12 +91,12 @@ DEFAULT_MAD_K = 5.0
 DEFAULT_MAD_WINDOW = 64
 DEFAULT_MAD_MIN = 8
 DEFAULT_JSONL_MAX_MB = 64.0
-# Conservative single-host defaults: a few-core AVX2 box sustains tens of
-# GFLOP/s and tens of GB/s on the kernels' mixed int/float work. They exist
-# so achieved-fraction is always computable; absolute calibration comes from
-# the env knobs on real accelerators.
-DEFAULT_PEAK_GFLOPS = 50.0
-DEFAULT_PEAK_GBS = 20.0
+# Published per-chip peaks (FLOP/s, HBM bytes/s), keyed by jax's
+# `device_kind`. TPU v5e: 197 TFLOP/s bf16 and 819 GB/s HBM (Google Cloud
+# documentation, "TPU v5e").
+PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 RUN_PHASES = ("encode", "table_build", "to_device", "dispatch", "fetch",
               "commit")
@@ -124,20 +125,24 @@ def _env_int(name: str, default: int) -> int:
 # ------------------------------------------------------------ roofline math ---
 
 
-def peak_rates() -> Tuple[float, float]:
-    """(peak FLOP/s, peak bytes/s) from the env knobs (GFLOPS / GB/s)."""
-    return (_env_float("OPEN_SIMULATOR_PEAK_GFLOPS", DEFAULT_PEAK_GFLOPS) * 1e9,
-            _env_float("OPEN_SIMULATOR_PEAK_GBS", DEFAULT_PEAK_GBS) * 1e9)
+def device_kind() -> Optional[str]:
+    """jax's `device_kind` of the first device, or None while this process
+    has not imported jax (pulse itself never imports it)."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.devices()[0].device_kind
+
+
+def peak_rates(kind: Optional[str]) -> Optional[Tuple[float, float]]:
+    """(peak FLOP/s, peak bytes/s) of `kind`, or None when it has no
+    published peaks in `PEAKS`."""
+    return PEAKS.get(kind) if kind else None
 
 
 def normalize_cost(raw) -> Optional[Dict[str, float]]:
     """cost_analysis() output → {"flops", "bytes_accessed"}, or None.
 
-    jax returns a dict on current versions and a one-element list of dicts
-    on older ones; bytes may be keyed "bytes accessed" or split per operand
-    ("bytes accessed operand 0 {}" etc. — the total key wins when present)."""
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else None
+    Bytes may be keyed "bytes accessed" or split per operand ("bytes
+    accessed operand 0 {}" etc. — the total key wins when present)."""
     if not isinstance(raw, dict):
         return None
     flops = float(raw.get("flops", 0.0) or 0.0)
@@ -151,29 +156,38 @@ def normalize_cost(raw) -> Optional[Dict[str, float]]:
     return {"flops": flops, "bytes_accessed": by}
 
 
+def peaks_doc(kind: Optional[str]) -> dict:
+    """The summary's peaks block: the device and its published GFLOP/s and
+    GB/s, null where the table has none."""
+    peaks = peak_rates(kind)
+    return {"device_kind": kind,
+            "gflops": None if peaks is None else peaks[0] / 1e9,
+            "gbs": None if peaks is None else peaks[1] / 1e9}
+
+
 def model_optimal_s(cost: Dict[str, float],
-                    peak_flops: Optional[float] = None,
-                    peak_bw: Optional[float] = None) -> float:
+                    peaks: Optional[Tuple[float, float]]) -> Optional[float]:
     """Roofline model-optimal seconds: the kernel cannot run faster than its
     FLOPs at peak compute nor its bytes at peak bandwidth — whichever wall
-    it hits first is the model optimum."""
-    pf, pb = peak_rates()
-    if peak_flops:
-        pf = peak_flops
-    if peak_bw:
-        pb = peak_bw
+    it hits first is the model optimum. None without published peaks."""
+    if peaks is None:
+        return None
+    pf, pb = peaks
     return max(cost.get("flops", 0.0) / pf, cost.get("bytes_accessed", 0.0) / pb)
 
 
-def roofline_table(golden_dir: Optional[str] = None) -> List[dict]:
+def roofline_table(golden_dir: Optional[str] = None,
+                   kind: Optional[str] = None) -> List[dict]:
     """The static roofline: one row per (kernel, bucket, mesh) audit
     certificate carrying a `cost` field — {kernel, bucket, mesh, flops,
-    bytes_accessed, model_optimal_s}. Reads the checked-in simonaudit
+    bytes_accessed, model_optimal_s}, the last against `kind`'s published
+    peaks (None for a device without them). Reads the checked-in simonaudit
     goldens; no jax, no compilation."""
     if golden_dir is None:
         from ..analysis.hlo import _default_golden_dir
 
         golden_dir = _default_golden_dir()
+    peaks = peak_rates(kind)
     rows: List[dict] = []
     if not os.path.isdir(golden_dir):
         return rows
@@ -196,7 +210,7 @@ def roofline_table(golden_dir: Optional[str] = None) -> List[dict]:
                 "mesh": cert.get("mesh", ""),
                 "flops": cost["flops"],
                 "bytes_accessed": cost["bytes_accessed"],
-                "model_optimal_s": model_optimal_s(cost),
+                "model_optimal_s": model_optimal_s(cost, peaks),
             })
     return rows
 
@@ -279,6 +293,7 @@ class Pulse:
             "OPEN_SIMULATOR_PULSE_JSONL_MAX_MB", DEFAULT_JSONL_MAX_MB)) * 1e6
         self._jsonl_f = None
         self._jsonl_warned = False
+        self._kind: Optional[str] = None  # device_kind, resolved at first use
 
     # ----------------------------------------------------------- appending --
 
@@ -416,13 +431,20 @@ class Pulse:
                 with self._lock:
                     self._reg_counts[key] = self._reg_counts.get(key, 0) + 1
         if cost is not None and wall_s > 0.0:
-            opt = model_optimal_s(cost)
-            if opt > 0.0:
+            opt = model_optimal_s(cost, peak_rates(self.device_kind()))
+            if opt is not None and opt > 0.0:
                 frac = min(1.0, opt / wall_s)
                 rec["achieved_frac"] = round(frac, 6)
                 rec["model_optimal_s"] = round(opt, 9)
                 PULSE_ACHIEVED.labels(kernel=kernel, bucket=digest).set(
                     round(frac, 6))
+
+    def device_kind(self) -> Optional[str]:
+        """The device this ledger's dispatches ran on (resolved once a
+        dispatch has imported jax)."""
+        if self._kind is None:
+            self._kind = device_kind()
+        return self._kind
 
     def _harvest_cost(self, fn) -> Optional[Dict[str, float]]:
         """Dispatch-shape cost_analysis harvest, cold dispatches only
@@ -552,16 +574,16 @@ class Pulse:
             if cost is not None:
                 row["flops"] = cost["flops"]
                 row["bytes_accessed"] = cost["bytes_accessed"]
-                row["model_optimal_s"] = round(model_optimal_s(cost), 9)
+                opt = model_optimal_s(cost, peak_rates(self.device_kind()))
+                row["model_optimal_s"] = None if opt is None else round(opt, 9)
             row["wall_s"] = round(row["wall_s"], 9)
-        pf, pb = peak_rates()
         return {
             "records_total": n_total,
             "records_dropped": n_dropped,
             "ring_len": len(recs),
             "capacity": self.capacity,
             "regressions_total": sum(reg_counts.values()),
-            "peaks": {"gflops": pf / 1e9, "gbs": pb / 1e9},
+            "peaks": peaks_doc(self.device_kind()),
             "phase_seconds": {k: round(v, 9)
                               for k, v in sorted(phase_totals.items())},
             "runs": runs,
@@ -707,14 +729,13 @@ def summarize_records(recs: List[dict]) -> dict:
             row["warm_mad_s"] = round(
                 statistics.median(abs(x - med) for x in win), 9)
         row["wall_s"] = round(row["wall_s"], 9)
-    pf, pb = peak_rates()
     return {
         "records_total": len(recs),
         "records_dropped": 0,
         "ring_len": len(recs),
         "capacity": 0,
         "regressions_total": n_reg,
-        "peaks": {"gflops": pf / 1e9, "gbs": pb / 1e9},
+        "peaks": peaks_doc(None),  # a JSONL spill does not name its device
         "phase_seconds": {k: round(v, 9)
                           for k, v in sorted(phase_totals.items())},
         "runs": runs,
@@ -758,21 +779,29 @@ def format_summary(doc: dict) -> str:
     return "\n".join(out)
 
 
-def format_roofline(rows: List[dict]) -> str:
+def format_roofline(rows: List[dict], kind: Optional[str]) -> str:
     """Human table for `simon pulse --roofline` from roofline_table()."""
-    pf, pb = peak_rates()
-    out = [f"roofline @ {pf / 1e9:g} GFLOP/s, {pb / 1e9:g} GB/s "
-           f"(OPEN_SIMULATOR_PEAK_GFLOPS / OPEN_SIMULATOR_PEAK_GBS)"]
+    peaks = peak_rates(kind)
+    if peaks is None:
+        out = [f"no published peaks for device kind {kind!r}: "
+               f"model-optimal times not available"]
+    else:
+        out = [f"roofline @ {peaks[0] / 1e9:g} GFLOP/s, {peaks[1] / 1e9:g} "
+               f"GB/s ({kind})"]
     hdr = (f"{'kernel':<28} {'bucket':<8} {'mesh':<10} {'GFLOP':>10} "
            f"{'MB':>10} {'optimal':>10} {'bound':>5}")
     out.append(hdr)
     out.append("-" * len(hdr))
     for r in rows:
         opt = r["model_optimal_s"]
-        flop_s = r["flops"] / pf
-        bound = "flop" if flop_s >= opt - 1e-18 and flop_s > 0 else "mem"
+        if opt is None:
+            opt_s, bound = "-", "-"
+        else:
+            flop_s = r["flops"] / peaks[0]
+            opt_s = f"{opt * 1e6:.1f}us"
+            bound = "flop" if flop_s >= opt - 1e-18 and flop_s > 0 else "mem"
         out.append(
             f"{r['kernel']:<28} {r['bucket']:<8} {r['mesh']:<10} "
             f"{r['flops'] / 1e9:>10.4f} {r['bytes_accessed'] / 1e6:>10.3f} "
-            f"{opt * 1e6:>9.1f}us {bound:>5}")
+            f"{opt_s:>11} {bound:>5}")
     return "\n".join(out)

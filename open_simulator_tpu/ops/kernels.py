@@ -33,7 +33,6 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 from .contracts import shaped
@@ -1260,14 +1259,14 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
                 cond_s, body, (j0, jnp.int32(0), jnp.int32(1)))
 
         Pn = PartitionSpec(ax)
-        j, placed, _ = shard_map(
+        j, placed, _ = jax.shard_map(
             loop_sharded, mesh=mesh,
             in_specs=(Pn, Pn, PartitionSpec(ax, None), PartitionSpec(ax, None),
                       {k: (PartitionSpec(None, ax) if v.ndim == 2 else Pn)
                        for k, v in st.items()},
                       {k: PartitionSpec() for k in st_norm},
                       PartitionSpec(), PartitionSpec()),
-            out_specs=(PartitionSpec(),) * 3, check_rep=False,
+            out_specs=(PartitionSpec(),) * 3, check_vma=False,
         )(capacity, base_feas, alloc_cm, cry.nonzero, st, st_norm,
           tb.grp_nonzero[g], m)
     else:
@@ -2086,11 +2085,11 @@ def schedule_affinity_wave(tb: Tables, cry: Carry, g, m, cap1,
                     else PartitionSpec(None, ax))
 
         state_specs = AffinityWaveState(*((PartitionSpec(),) * 10))
-        final = shard_map(
+        final = jax.shard_map(
             loop_sharded, mesh=mesh,
             in_specs=({k: nd_spec(k, v) for k, v in nd_full.items()},
                       {k: PartitionSpec() for k in repl}, state_specs),
-            out_specs=state_specs, check_rep=False,
+            out_specs=state_specs, check_vma=False,
         )(nd_full, repl, init)
     else:
         final = jax.lax.while_loop(cond, body, init)

@@ -22,9 +22,7 @@ it owns the failure semantics too. Four parts:
   metric reconciliation) back to exactly the pre-call state.
 - `guard` (simonguard) — what happens NEXT after the rollback: watchdog-
   supervised dispatch (wedged backends are quarantined and the run fails
-  over to CPU, resuming from the last committed segment; a real — not
-  injected — wedge may later be lifted by a bounded subprocess re-probe,
-  once per OPEN_SIMULATOR_QUARANTINE_REPROBE_S window), device-OOM
+  over to CPU, resuming from the last committed segment), device-OOM
   containment by pod-batch bisection (split-vs-unsplit placements are
   bit-identical), and a crash-consistent fsync'd capacity-search journal
   (`simon apply --resume-journal` skips completed probes; a digest guard

@@ -9,10 +9,7 @@ accelerator or a device OOM degrades the run instead of killing it:
   (kernel dispatch, result fetch, probe fan-out round) runs in a worker
   thread under a deadline scaled by batch size and tightened by the
   contextvar `Deadline` (resilience/policy.py). On expiry the backend is
-  classified *wedged*, quarantined for the process (a REAL expiry — never an
-  injected one — may later clear its name through one bounded subprocess
-  re-probe per `OPEN_SIMULATOR_QUARANTINE_REPROBE_S` window, so a slow
-  compile outlier doesn't degrade the process forever), and
+  classified *wedged*, quarantined for the process, and
   `BackendWedged` is raised — which the engine's failover loop catches. The
   blocked worker thread is a daemon and is abandoned (a dispatch stuck in a
   driver ioctl cannot be interrupted from Python); the quarantine is exactly
@@ -69,7 +66,7 @@ class GuardError(RuntimeError):
 
 class BackendWedged(GuardError):
     """A supervised device computation blew its watchdog deadline: the
-    backend is presumed hung (tunnel wedge, driver deadlock) and has been
+    backend is presumed hung (driver deadlock, lost device) and has been
     quarantined for the process."""
 
     def __init__(self, site: str, backend: str, injected: bool = False) -> None:
@@ -159,43 +156,12 @@ def events() -> List[Tuple]:
 # ------------------------------------------------------------- quarantine -----
 
 _QUARANTINED: Dict[str, str] = {}  # backend platform -> cause
-# real (watchdog-observed, non-injected) wedges only: when the entry was
-# created and when the next bounded re-probe may run. Injected wedges carry
-# no meta and never re-probe — fault-smoke determinism.
-_QUARANTINE_META: Dict[str, dict] = {}
-# backends whose quarantine was lifted by a re-probe once already: a SECOND
-# real wedge proves the subprocess probe cannot see this process's wedged
-# state (the abandoned worker thread holds in-process locks a fresh python
-# never touches), so the re-quarantine is permanent — the lift/burn cycle is
-# bounded at one, not one per window.
-_LIFTED: set = set()
 
 
-def quarantine_reprobe_s() -> float:
-    """Seconds after which a REAL (non-injected) wedge quarantine becomes
-    eligible for one bounded subprocess re-probe per window
-    (OPEN_SIMULATOR_QUARANTINE_REPROBE_S; 0 makes quarantines permanent).
-    A slow-but-healthy outlier — a cold XLA compile past the watchdog
-    budget — must not pin every later Simulator in the process to the CPU
-    fallback forever; a probe that finds the backend responsive lifts the
-    quarantine."""
-    return _env_float("OPEN_SIMULATOR_QUARANTINE_REPROBE_S", 600.0)
-
-
-def quarantine(backend: str, cause: str, *, reprobe: bool = False) -> None:
-    """Quarantine `backend`. `reprobe=True` (real watchdog expiries only —
-    never injected faults) marks the entry eligible for the bounded
-    re-probe/expiry path in `default_quarantined`, unless a previous lift
-    already failed to stick (see _LIFTED)."""
+def quarantine(backend: str, cause: str) -> None:
+    """Quarantine `backend` for the rest of the process."""
     with _STATE_LOCK:
-        if backend not in _QUARANTINED:
-            _QUARANTINED[backend] = cause
-            if reprobe and backend not in _LIFTED:
-                # monotonic like policy.py's Deadline: the window is an
-                # interval, and a wall-clock step must not stretch or
-                # collapse it
-                _QUARANTINE_META[backend] = {"ts": time.monotonic(),
-                                             "next_probe": 0.0}
+        _QUARANTINED.setdefault(backend, cause)
     obs.GUARD_QUARANTINED.labels(backend=backend).set(1)
 
 
@@ -204,90 +170,26 @@ def quarantined() -> Dict[str, str]:
         return dict(_QUARANTINED)
 
 
-def _unquarantine(backend: str, why: str) -> None:
-    with _STATE_LOCK:
-        _QUARANTINED.pop(backend, None)
-        _QUARANTINE_META.pop(backend, None)
-        _LIFTED.add(backend)  # a second real wedge is permanent
-    obs.GUARD_QUARANTINED.labels(backend=backend).set(0)
-    record_event("unquarantine", backend, why)
-    import logging
-
-    logging.getLogger("open_simulator_tpu").warning(
-        "backend %r responded to a re-probe; lifting its quarantine (%s)",
-        backend, why)
-
-
-def _maybe_lift_quarantine(backend: str) -> None:
-    """Bounded re-probe of a REAL wedge quarantine: once per
-    quarantine_reprobe_s window, run the existing subprocess probe
-    (utils/devices.probe_default_backend — deadline-bounded, never
-    in-process) in a BACKGROUND daemon thread — default_quarantined() sits
-    on hot dispatch paths and under callers' Deadline budgets, so the
-    state check itself must never block on a 60s probe. A responsive
-    backend is un-quarantined (for later calls) so one compile outlier
-    doesn't degrade the whole process permanently; a lift that fails to
-    stick makes the re-quarantine permanent (_LIFTED). Injected
-    quarantines (no meta) and the window==0 config never re-probe."""
-    window = quarantine_reprobe_s()
-    if window <= 0:
-        return
-    now = time.monotonic()
-    with _STATE_LOCK:
-        meta = _QUARANTINE_META.get(backend)
-        if meta is None or now - meta["ts"] < window or now < meta["next_probe"]:
-            return
-        # claim this window before dropping the lock: concurrent callers
-        # must not stack subprocess probes
-        meta["next_probe"] = now + window
-    threading.Thread(target=_reprobe_and_lift, args=(backend,),
-                     name="simon-guard-reprobe", daemon=True).start()
-
-
-def _reprobe_and_lift(backend: str) -> None:
-    from ..utils.devices import probe_default_backend
-
-    try:
-        ok, _rec = probe_default_backend()
-    except Exception:  # a failed probe just leaves the quarantine standing
-        return
-    if ok:
-        _unquarantine(backend, "reprobe_ok")
-
-
 def current_backend() -> str:
-    """The default JAX backend's platform name. Safe at the points the guard
-    calls it: either a dispatch already initialized the backend, or the
-    process-startup probe (utils/devices.py) verified it responsive."""
+    """The platform this thread's JAX work lands on: the device a caller's
+    `jax.default_device` scope names, else the default backend."""
     import jax
 
+    dev = jax.config.jax_default_device
+    if dev is not None:
+        return dev if isinstance(dev, str) else dev.platform
     return jax.default_backend()
 
 
 def default_quarantined() -> bool:
     """True when the process's default backend is quarantined (device work
     must route to the CPU fallback). Never touches jax when nothing is
-    quarantined — the common case stays import-free. A real-wedge entry past
-    its re-probe window kicks off one bounded BACKGROUND subprocess probe
-    here (this call never blocks on it); a responsive backend is
-    un-quarantined for subsequent calls."""
+    quarantined — the common case stays import-free."""
     with _STATE_LOCK:
         if not _QUARANTINED:
             return False
         q = dict(_QUARANTINED)
-    b = current_backend()
-    if b not in q:
-        return False
-    _maybe_lift_quarantine(b)
-    with _STATE_LOCK:
-        return b in _QUARANTINED
-
-
-# Carried INTO supervised worker threads via contextvars.copy_context():
-# jax.default_device is thread-scoped, so the scope entered on the caller
-# thread does not reach the worker — the flag does, and the worker re-enters
-# the scope itself (see _call_in_scope).
-_FALLBACK_SCOPE = contextvars.ContextVar("simon_guard_fallback", default=False)
+    return current_backend() in q
 
 
 def _cpu_device():
@@ -300,45 +202,43 @@ def _cpu_device():
 def fallback_scope():
     """Context manager placing all JAX work inside it on the CPU fallback
     device (the degraded-mode execution target after a wedge/OOM).
-
-    Enters jax.default_device on the CALLING thread and raises a contextvar
-    flag: JAX device/config scopes are thread-local and copy_context() does
-    not carry them, so `supervised` re-establishes the scope inside its
-    worker thread whenever the flag is set — otherwise a post-failover
-    dispatch with uncommitted inputs would still target the quarantined
-    backend and burn another watchdog timeout per attempt."""
-    import jax
-
-    token = _FALLBACK_SCOPE.set(True)
-    try:
-        with jax.default_device(_cpu_device()):
-            yield
-    finally:
-        _FALLBACK_SCOPE.reset(token)
-
-
-def _call_in_scope(fn: Callable[[], T]) -> T:
-    """Run `fn`, re-entering the CPU fallback device scope in the CURRENT
-    thread when the caller held fallback_scope() (the contextvar flag is
-    copied into supervised workers; the thread-local jax scope is not)."""
-    if not _FALLBACK_SCOPE.get():
-        return fn()
+    `supervised` carries the scope into its worker thread."""
     import jax
 
     with jax.default_device(_cpu_device()):
+        yield
+
+
+def _caller_device():
+    """The device a `jax.default_device` scope on THIS thread names, or None.
+    JAX config scopes are thread-local and copy_context() does not carry
+    them, so `supervised` reads this on the caller's thread and re-enters it
+    in its worker: a run under `jax.default_device(cpu)` (the failover's
+    fallback_scope, or a caller's own) must not dispatch on the default
+    backend just because the dispatch happens in another thread."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.config.jax_default_device
+
+
+def _call_in_scope(fn: Callable[[], T], device) -> T:
+    """Run `fn` in the CURRENT thread under the caller's device scope."""
+    if device is None:
+        return fn()
+    import jax
+
+    with jax.default_device(device):
         return fn()
 
 
 def reset_for_tests() -> None:
     """Clear process-global guard state (quarantine + events). Tests and the
-    fault-smoke CI only — production only un-quarantines through the bounded
-    re-probe path (_maybe_lift_quarantine)."""
+    fault-smoke CI only — production never un-quarantines."""
     with _STATE_LOCK:
         for b in _QUARANTINED:
             obs.GUARD_QUARANTINED.labels(backend=b).set(0)
         _QUARANTINED.clear()
-        _QUARANTINE_META.clear()
-        _LIFTED.clear()
         del _EVENTS[:]
 
 
@@ -353,7 +253,6 @@ def state() -> dict:
             "per_pod_s": _env_float("OPEN_SIMULATOR_WATCHDOG_PER_POD_S", 0.005),
         },
         "oom_bisect_floor": oom_bisect_floor(),
-        "quarantine_reprobe_s": quarantine_reprobe_s(),
         "events": [list(e) for e in events()[-64:]],
     }
 
@@ -405,13 +304,13 @@ def supervised(fn: Callable[[], T], *, site: str, pods: int = 0) -> T:
     box: dict = {}
     done = threading.Event()
     ctx = contextvars.copy_context()
+    device = _caller_device()
 
     def worker() -> None:
         try:
-            # _call_in_scope: the copied context carries the fallback FLAG,
-            # not the thread-local jax device scope — re-enter it here so a
-            # failed-over dispatch actually lands on the CPU fallback
-            box["result"] = ctx.run(_call_in_scope, fn)
+            # the copied context does not carry the thread-local jax device
+            # scope: re-enter it so the dispatch lands where its caller chose
+            box["result"] = ctx.run(_call_in_scope, fn, device)
         # simonlint: ignore[swallowed-exception] -- not swallowed: the boxed
         # error re-raises in the supervising caller the moment done is set
         except BaseException as we:  # noqa: BLE001
@@ -440,10 +339,7 @@ def supervised(fn: Callable[[], T], *, site: str, pods: int = 0) -> T:
 
 def _declare_wedged(site: str, injected: bool) -> BackendWedged:
     backend = current_backend()
-    # only a REAL watchdog expiry earns the re-probe/expiry path: a slow-but-
-    # healthy outlier can clear its name, while injected wedges stay pinned
-    # for deterministic tests and the fault-smoke CI
-    quarantine(backend, f"{CAUSE_WEDGE}@{site}", reprobe=not injected)
+    quarantine(backend, f"{CAUSE_WEDGE}@{site}")
     obs.GUARD_WATCHDOG_EXPIRIES.labels(site=site).inc()
     record_event("wedge", site, backend)
     return BackendWedged(site, backend, injected=injected)

@@ -6,6 +6,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# CPU test entries must never fill the checkout's persistent compile cache
+# (utils/devices.enable_compilation_cache), which the driver copies. The env
+# var also reaches the CLI/tool subprocesses the tests start.
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+
 from open_simulator_tpu.utils.devices import force_cpu_platform, request_cpu_devices
 
 request_cpu_devices(8)

@@ -203,7 +203,8 @@ def test_full_matrix_matches_goldens():
     """Every registered hot kernel at every canonical bucket x mesh shape
     agrees with its golden certificate (the CI gate, in-process)."""
     certs = hlo.run_targets(None, hlo.DEFAULT_BUCKETS, hlo.DEFAULT_SHARDS)
-    assert len(certs) == len(kernels.HOT_KERNELS) * 2 * 3 + 2
+    # + the wave chain (nodes 1/8) + the affinity epoch (every mesh shape)
+    assert len(certs) == len(kernels.HOT_KERNELS) * 2 * 3 + 2 + 2 * 3
     regressions, _ = hlo.check_certs(certs, str(GOLDEN))
     assert regressions == [], "\n".join(regressions)
 

@@ -5,8 +5,7 @@ OOM batch bisection (split-vs-unsplit placements bit-identical, odd sizes
 included, floor-hit structured failure), watchdog wedge → quarantine → CPU
 failover resuming from the committed prefix, the crash-consistent
 capacity-search journal (resume skips completed probes; digest mismatch
-rejected; torn tails ignored), the probe-cooldown persistence, and the
-preemption replay cap."""
+rejected; torn tails ignored), and the preemption replay cap."""
 
 import copy
 import json
@@ -168,71 +167,30 @@ def test_quarantine_routes_later_simulators_to_fallback():
     assert _census(sim2) == _census(sim)
 
 
+def test_supervised_keeps_the_callers_device_scope():
+    """The watchdog worker is another thread, and jax.default_device is
+    thread-local: a dispatch made under a caller's device scope must still
+    land on that device (the CPU reference run, or the failover's
+    fallback_scope), and current_backend must name its platform."""
+    import jax
+    import jax.numpy as jnp
+
+    dev = jax.devices("cpu")[-1]
+    assert dev != jax.devices()[0]
+    with jax.default_device(dev):
+        out = guard.supervised(lambda: jnp.zeros(3) + 1, site="dispatch")
+        assert guard.current_backend() == "cpu"
+    assert out.devices() == {dev}
+    assert guard.supervised(lambda: jnp.zeros(3), site="dispatch").devices() == {
+        jax.devices()[0]}
+
+
 def test_supervised_real_timeout_declares_wedge(monkeypatch):
     monkeypatch.setenv("OPEN_SIMULATOR_WATCHDOG_BASE_S", "0.2")
     monkeypatch.setenv("OPEN_SIMULATOR_WATCHDOG_PER_POD_S", "0")
     with pytest.raises(guard.BackendWedged):
         guard.supervised(lambda: time.sleep(3), site="dispatch", pods=0)
     assert "cpu" in guard.quarantined()
-
-
-def test_real_wedge_quarantine_lifts_after_successful_reprobe(monkeypatch):
-    """A REAL watchdog expiry (e.g. one slow compile outlier) must not pin
-    the process to CPU forever: past the re-probe window, one bounded
-    BACKGROUND subprocess probe that finds the backend responsive lifts the
-    quarantine — and a lift that fails to stick (a second real wedge) makes
-    the re-quarantine permanent, bounding the lift/burn cycle at one."""
-    import open_simulator_tpu.utils.devices as devices
-
-    monkeypatch.setenv("OPEN_SIMULATOR_WATCHDOG_BASE_S", "0.2")
-    monkeypatch.setenv("OPEN_SIMULATOR_WATCHDOG_PER_POD_S", "0")
-    monkeypatch.setenv("OPEN_SIMULATOR_QUARANTINE_REPROBE_S", "0.01")
-    probes = []
-    monkeypatch.setattr(devices, "probe_default_backend",
-                        lambda *a, **k: (probes.append(1) or True,
-                                         {"outcome": "ok"}))
-    with pytest.raises(guard.BackendWedged):
-        guard.supervised(lambda: time.sleep(3), site="dispatch", pods=0)
-    assert "cpu" in guard.quarantined()
-    time.sleep(0.05)  # past the re-probe window
-    guard.default_quarantined()  # kicks off the async re-probe; never blocks
-    deadline = time.monotonic() + 5.0
-    while guard.quarantined() and time.monotonic() < deadline:
-        time.sleep(0.01)
-    assert guard.quarantined() == {}, "responsive backend must be lifted"
-    assert probes, "the lift must come from an actual re-probe"
-    assert any(e[0] == "unquarantine" for e in guard.events())
-
-    # the lift did not stick: a SECOND real wedge re-quarantines PERMANENTLY
-    # (the subprocess probe demonstrably cannot see this process's state)
-    with pytest.raises(guard.BackendWedged):
-        guard.supervised(lambda: time.sleep(3), site="dispatch", pods=0)
-    assert "cpu" in guard.quarantined()
-    n_probes = len(probes)
-    time.sleep(0.05)
-    assert guard.default_quarantined()
-    time.sleep(0.05)
-    assert guard.default_quarantined(), "re-quarantine must be permanent"
-    assert len(probes) == n_probes, "a permanent quarantine never re-probes"
-
-
-def test_injected_wedge_quarantine_never_reprobes(monkeypatch):
-    """Injected wedges stay deterministically quarantined — the fault-smoke
-    replay-equality criterion must never depend on a live probe."""
-    import open_simulator_tpu.utils.devices as devices
-
-    monkeypatch.setenv("OPEN_SIMULATOR_QUARANTINE_REPROBE_S", "0.01")
-
-    def _no_probe(*a, **k):
-        raise AssertionError("injected quarantine must not probe")
-
-    monkeypatch.setattr(devices, "probe_default_backend", _no_probe)
-    with installed(FaultPlan([FaultSpec("watchdog_wedge", 1)])):
-        with pytest.raises(guard.BackendWedged):
-            guard.supervised(lambda: None, site="dispatch", pods=0)
-    time.sleep(0.05)
-    assert guard.default_quarantined()
-    assert guard.quarantined()
 
 
 def test_supervised_prefers_deadline_over_wedge(monkeypatch):
@@ -493,40 +451,6 @@ def test_search_contains_wedge_by_falling_back_to_fresh_probes():
     assert p1.stats["path"] == "fresh"
     assert any(e[0] == "failover" and e[2] == "capacity_search"
                for e in guard.events())
-
-
-# ------------------------------------------------------- probe cooldown ------
-
-
-def test_probe_cooldown_short_circuits_known_wedge(tmp_path, monkeypatch):
-    from open_simulator_tpu.utils.devices import probe_default_backend
-
-    state = str(tmp_path / "probe_state.json")
-    monkeypatch.setenv("OPEN_SIMULATOR_PROBE_COOLDOWN_S", "600")
-    with open(state, "w") as f:
-        json.dump({"ts_epoch": time.time(), "outcome": "timeout"}, f)
-    t0 = time.perf_counter()
-    ok, rec = probe_default_backend(timeout=30.0, state_path=state)
-    assert not ok
-    assert rec["outcome"] == "cooldown"
-    assert rec["last_outcome"] == "timeout"
-    assert time.perf_counter() - t0 < 1.0, "cooldown hit must not probe"
-
-
-def test_probe_cooldown_expired_state_does_not_short_circuit(tmp_path, monkeypatch):
-    """An old wedge record is past the window: the probe must actually run
-    (observable as the state file being rewritten with a fresh outcome)."""
-    from open_simulator_tpu.utils.devices import probe_default_backend
-
-    state = str(tmp_path / "probe_state.json")
-    monkeypatch.setenv("OPEN_SIMULATOR_PROBE_COOLDOWN_S", "1")
-    with open(state, "w") as f:
-        json.dump({"ts_epoch": time.time() - 3600, "outcome": "timeout"}, f)
-    ok, rec = probe_default_backend(timeout=120.0, state_path=state)
-    assert rec["outcome"] != "cooldown"
-    with open(state) as f:
-        st = json.load(f)
-    assert st["outcome"] == rec["outcome"]
 
 
 # ---------------------------------------------------- preemption replay cap --

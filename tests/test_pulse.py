@@ -249,30 +249,54 @@ def test_mad_needs_min_window_before_flagging():
     assert p.summary()["regressions_total"] == 0
 
 
-def test_achieved_roofline_fraction_on_warm_dispatch():
+V5E = "TPU v5 lite"
+
+
+def test_achieved_roofline_fraction_on_warm_dispatch(monkeypatch):
+    monkeypatch.setattr(pulse, "device_kind", lambda: V5E)
     p = pulse.enable(capacity=64)
     dims = {"N": 8, "P": 4}
     key = ("schedule_wave", dispatch_digest("schedule_wave", dims))
-    cost = {"flops": 5e7, "bytes_accessed": 2e7}
+    cost = {"flops": 5e10, "bytes_accessed": 2e10}
     with p._lock:
         p._costs[key] = cost                  # as _harvest_cost would
-    opt = pulse.model_optimal_s(cost)
-    assert opt > 0.0
+    opt = pulse.model_optimal_s(cost, pulse.PEAKS[V5E])
+    assert opt == pytest.approx(2e10 / 819e9)  # HBM-bound on v5e
     _commit(p, dims=dims, wall_s=2.0 * opt)
     rec = p.records()[-1]
     assert rec["model_optimal_s"] == pytest.approx(opt)
     assert rec["achieved_frac"] == pytest.approx(0.5, abs=1e-6)
-    (row,) = p.summary()["kernels"]
+    s = p.summary()
+    (row,) = s["kernels"]
     assert row["flops"] == cost["flops"]
     assert row["bytes_accessed"] == cost["bytes_accessed"]
     assert row["achieved_frac"] == pytest.approx(0.5, abs=1e-6)
+    assert s["peaks"] == {"device_kind": V5E, "gflops": 197e3, "gbs": 819.0}
+
+
+def test_unknown_device_has_null_roofline(monkeypatch):
+    """A device without published peaks gets no roofline share, never a
+    default one."""
+    monkeypatch.setattr(pulse, "device_kind", lambda: "cpu")
+    p = pulse.enable(capacity=64)
+    dims = {"N": 8, "P": 4}
+    key = ("schedule_wave", dispatch_digest("schedule_wave", dims))
+    with p._lock:
+        p._costs[key] = {"flops": 5e10, "bytes_accessed": 2e10}
+    _commit(p, dims=dims, wall_s=1e-3)
+    rec = p.records()[-1]
+    assert "achieved_frac" not in rec and "model_optimal_s" not in rec
+    s = p.summary()
+    assert s["kernels"][0]["model_optimal_s"] is None
+    assert s["peaks"] == {"device_kind": "cpu", "gflops": None, "gbs": None}
+    assert pulse.peak_rates(None) is None
 
 
 # ------------------------------------------------------- static roofline -----
 
 
 def test_roofline_table_covers_all_hot_kernels():
-    rows = pulse.roofline_table()
+    rows = pulse.roofline_table(kind=V5E)
     assert rows, "audit goldens carry no cost fields (run simon audit --update)"
     have = set()
     for r in rows:
@@ -285,6 +309,10 @@ def test_roofline_table_covers_all_hot_kernels():
             for b in ("s16x32", "m48x96") for s in (1, 2, 8)}
     missing = need - have
     assert not missing, f"roofline holes: {sorted(missing)[:6]}"
+    assert "GB/s (TPU v5 lite)" in pulse.format_roofline(rows, V5E)
+    unknown = pulse.roofline_table(kind="cpu")
+    assert all(r["model_optimal_s"] is None for r in unknown)
+    assert "no published peaks" in pulse.format_roofline(unknown, "cpu")
 
 
 # --------------------------------------------------------- runs and phases ---
