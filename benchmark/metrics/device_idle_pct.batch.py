@@ -1,0 +1,7 @@
+"""device_idle_pct.batch: see BENCHMARK.json and PERF.md section 3."""
+
+from _layer import idle_pct
+
+
+def read(layer: dict):
+    return idle_pct(layer)

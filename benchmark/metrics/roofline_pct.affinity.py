@@ -1,0 +1,7 @@
+"""roofline_pct.affinity: see BENCHMARK.json and PERF.md section 3."""
+
+from _layer import kernel_roofline
+
+
+def read(layer: dict):
+    return kernel_roofline(layer, "schedule_affinity_wave", "affinity", terms=1)
