@@ -339,64 +339,14 @@ def _row_gpushare():
     }
 
 
-def _hard_segment_breakdown(n_nodes=5_000, n_pods=50_000):
-    """Per-segment-kind pod counts and wall share for the hard-predicate
-    workload, from ONE extra instrumented run (OPEN_SIMULATOR_SEGMENT_TIMING
-    blocks on every segment, so it never taints the timed rows). Registry
-    deltas isolate this run from the timed repeats in the same process."""
-    import re
-
-    from open_simulator_tpu.obs import REGISTRY
-    from open_simulator_tpu.utils.synth import synth_cluster
-
-    def seg_values():
-        out = {}
-        pat = re.compile(
-            r"^simon_segment_(pods_total|wall_seconds_total)\{kind=\"(\w+)\"\}$")
-        for key, val in REGISTRY.values().items():
-            mt = pat.match(key)
-            if mt:
-                out[(mt.group(2), mt.group(1))] = float(val)
-        return out
-
-    before = seg_values()
-    os.environ["OPEN_SIMULATOR_SEGMENT_TIMING"] = "1"
-    try:
-        nodes, pods = synth_cluster(n_nodes, n_pods, hard_predicates=True)
-        _schedule_run(nodes, pods)
-    finally:
-        os.environ.pop("OPEN_SIMULATOR_SEGMENT_TIMING", None)
-    after = seg_values()
-    kinds = sorted({k for k, _ in after})
-    wall = {k: after.get((k, "wall_seconds_total"), 0.0)
-            - before.get((k, "wall_seconds_total"), 0.0) for k in kinds}
-    total_wall = sum(wall.values()) or 1.0
-    return {
-        k: {
-            "pods": int(after.get((k, "pods_total"), 0.0)
-                        - before.get((k, "pods_total"), 0.0)),
-            "wall_s": round(wall[k], 3),
-            "wall_share": round(wall[k] / total_wall, 4),
-        }
-        for k in kinds
-    }
-
-
 def _row_hard():
     rate, placed, total, dt = bench_throughput(5_000, 50_000, hard=True)
-    row = {
+    return {
         "metric": "hard_predicate_pods_per_sec_50k_pods_5k_nodes",
         "value": round(rate, 1), "unit": "pods/s",
         "vs_baseline": round(rate / BASELINE_PODS_PER_SEC, 4),
         "wall_s": round(dt, 3), "scheduled": placed, "total": total,
     }
-    # attribution ride-along: which segment kind owns this row's wall time,
-    # so a future regression is explainable without a profile run
-    try:
-        row["segments"] = _hard_segment_breakdown()
-    except Exception as e:  # the breakdown must never fail the metric
-        row["segments_error"] = f"{type(e).__name__}: {e}"
-    return row
 
 
 def _row_xray_overhead():

@@ -2,9 +2,10 @@
 
 JAX profiling practice exports device timelines as Chrome trace-event JSON
 loadable in perfetto / chrome://tracing; this module gives the HOST spans
-(utils/trace.Span trees: Simulate → schedule_run → encode/dispatch steps)
-the same treatment, so a `--trace-out FILE.json` run drops one file that
-perfetto renders as a nested flame chart.
+(utils/trace.Span trees: Simulate → schedule_pods → schedule_run →
+encode/dispatch/... phases) the same treatment, so a `--trace-out FILE.json`
+run drops one file that perfetto renders as a nested flame chart — the same
+tree a `jax.profiler` trace shows under `simon.*` names, on the host clock.
 
 Format: the JSON-object form of the trace-event spec — a `traceEvents`
 array of complete ("ph": "X") events with microsecond `ts`/`dur`, plus a
@@ -38,21 +39,6 @@ def _span_events(span: Span, pid: int, out: List[dict]) -> None:
         "cat": "span",
         "args": args,
     })
-    # steps are contiguous sub-intervals from the span start (utiltrace
-    # semantics: step(i) measures since the previous mark)
-    t = span.t0
-    for name, dt in span.steps:
-        out.append({
-            "name": name,
-            "ph": "X",
-            "ts": round(t * 1e6, 3),
-            "dur": round(dt * 1e6, 3),
-            "pid": pid,
-            "tid": span.tid,
-            "cat": "step",
-            "args": {},
-        })
-        t += dt
     for child in span.children:
         _span_events(child, pid, out)
 

@@ -68,13 +68,6 @@ SEGMENT_PODS = counter(
     "simon_segment_pods_total",
     "Pods carried by device dispatch segments, by segment kind.",
     ("kind",))
-SEGMENT_WALL = counter(
-    "simon_segment_wall_seconds_total",
-    "Blocking wall seconds per dispatch segment kind. Only collected when "
-    "OPEN_SIMULATOR_SEGMENT_TIMING=1 (the engine then blocks on each "
-    "segment's result, defeating async dispatch — bench attribution runs "
-    "only; see bench.py's hard-predicate segment breakdown).",
-    ("kind",))
 TRANSFER_BYTES = counter(
     "simon_device_transfer_bytes_total",
     "Host->device bytes staged for scheduling/probe table uploads.")

@@ -18,9 +18,10 @@ Three parts:
    `dispatch_digest`), mesh label, pod count, supervised unit wall,
    warm/cold compile flag, and the enclosing run id whose record carries
    the encode / table_build / to_device / dispatch / fetch / commit wall
-   decomposition from the engine's existing Span steps. A digest change
-   across a slowdown means "executable changed"; the same digest means
-   "same executable, slower environment". Optional JSONL spill with size
+   decomposition, each phase the total of the engine's Span of that
+   name (utils/trace: the same extents the profiler shows as simon.*). A
+   digest change across a slowdown means "executable changed"; the same
+   digest means "same executable, slower environment". Optional JSONL spill with size
    rotation keeps every record; the ring keeps the most recent
    OPEN_SIMULATOR_PULSE_CAP and counts every eviction
    (simon_pulse_records_dropped_total — never silent).

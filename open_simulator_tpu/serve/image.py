@@ -58,6 +58,7 @@ from ..ops.resources import CPU_I, MEM_I
 from ..resilience import faults
 from ..resilience import guard
 from ..utils.objutil import name_of, namespaced_name as pod_key
+from ..utils.trace import Span
 from ..simulator.encode import (
     BatchTables,
     bucket_capped,
@@ -924,10 +925,12 @@ class ResidentImage:
             # phase marks + spans run on the watchdog WORKER thread: the
             # copied contextvars carry both the batcher's sink and the trace
             # ctx here, so the trace shows dispatch/fetch on the thread that
-            # actually blocked on them
+            # actually blocked on them (the serve.* Spans put the same two
+            # phases on the profiler clock)
             scope.mark("kernel_begin")
-            with (sc.span("kernel:serve_wave_fanout", cat="dispatch")
-                  if sc is not None else contextlib.nullcontext()):
+            with Span("serve.dispatch"), (
+                    sc.span("kernel:serve_wave_fanout", cat="dispatch")
+                    if sc is not None else contextlib.nullcontext()):
                 carry_s, placed = kns.serve_wave_fanout(
                     self._tables, carry_s, active,
                     jnp.asarray(g_s), jnp.asarray(m_s), jnp.asarray(cap1_s),
@@ -935,8 +938,9 @@ class ResidentImage:
                     kmax=kmax)
             scope.mark("kernel_end")
             faults.maybe_fail("fetch")
-            with (sc.span("fetch:serve_wave_fanout", cat="dispatch")
-                  if sc is not None else contextlib.nullcontext()):
+            with Span("serve.fetch"), (
+                    sc.span("fetch:serve_wave_fanout", cat="dispatch")
+                    if sc is not None else contextlib.nullcontext()):
                 out = np.asarray(placed), np.asarray(carry_s.requested)
             scope.mark("fetch_end")
             return out
@@ -955,8 +959,9 @@ class ResidentImage:
             # gpu/storage clusters AND requests, so the inert subgraphs
             # compile away and an ineligible interned group can never flip
             # the staged flags (and the compiled signature) underneath us
-            with (sc.span("kernel:serve_whatif_fanout", cat="dispatch")
-                  if sc is not None else contextlib.nullcontext()):
+            with Span("serve.dispatch"), (
+                    sc.span("kernel:serve_whatif_fanout", cat="dispatch")
+                    if sc is not None else contextlib.nullcontext()):
                 carry_s, placed = kns.serve_whatif_fanout(
                     self._tables, carry_s, active,
                     jnp.asarray(pod_group), jnp.asarray(forced_node),
@@ -966,8 +971,9 @@ class ResidentImage:
                     w=sim.score_w, filters=sim.filter_flags)
             scope.mark("kernel_end")
             faults.maybe_fail("fetch")
-            with (sc.span("fetch:serve_whatif_fanout", cat="dispatch")
-                  if sc is not None else contextlib.nullcontext()):
+            with Span("serve.fetch"), (
+                    sc.span("fetch:serve_whatif_fanout", cat="dispatch")
+                    if sc is not None else contextlib.nullcontext()):
                 out = np.asarray(placed), np.asarray(carry_s.requested)
             scope.mark("fetch_end")
             return out

@@ -33,23 +33,24 @@ def simulate(
     """
     from ..utils.trace import Span
 
-    with Span("Simulate", log_if_longer=1.0) as span:  # core.go:67-73 LogIfLong
-        cluster = cluster.copy()
-        pods = expand_workloads_excluding_daemonsets(cluster)
-        for ds in cluster.daemon_sets:
-            pods.extend(pods_from_daemonset(ds, cluster.nodes))
-        cluster.pods = pods
-        span.step("expand cluster workloads")
+    with Span("Simulate", log_if_longer=1.0):  # core.go:67-73 LogIfLong
+        with Span("Simulate.expand_workloads"):
+            cluster = cluster.copy()
+            pods = expand_workloads_excluding_daemonsets(cluster)
+            for ds in cluster.daemon_sets:
+                pods.extend(pods_from_daemonset(ds, cluster.nodes))
+            cluster.pods = pods
 
         sim = Simulator(cluster.nodes, disable_progress=disable_progress,
                         patch_pod_funcs=patch_pod_funcs, sched_config=sched_config,
                         extra_plugins=extra_plugins)
-        result = sim.run_cluster(cluster)
-        span.step("sync cluster")
+        with Span("Simulate.sync_cluster"):
+            result = sim.run_cluster(cluster)
         failed = list(result.unscheduled_pods)
         for app in apps:
-            result = sim.schedule_app(app)
-            span.step(f"schedule app {app.name}")
+            with Span("Simulate.schedule_app") as span:
+                span.annotate("app", app.name)
+                result = sim.schedule_app(app)
             failed.extend(result.unscheduled_pods)
         result.unscheduled_pods = failed
     return result

@@ -1637,21 +1637,11 @@ def build_batch_tables(
     function of the encoder + pod order only (computed once per capacity
     search by the incremental prober), build_node_axis_tables carries every
     [*, N] table and the seeds. The pod-axis half runs first — it interns the
-    batch's host ports, which sizes the node-side seed port table."""
-    from ..obs import pulse
-
+    batch's host ports, which sizes the node-side seed port table. The
+    engine's encode (Simulator.encode_batch_raw) runs the two halves itself,
+    each under its own span."""
     pod_side = build_pod_axis_tables(enc, batch, pad_to=pad_to)
-    if pulse.active() is not None:
-        # the ROADMAP-5 instrument: streaming chunks re-enter here once per
-        # chunk, so per-chunk node-axis table-build cost shows up directly
-        # as the table_build slice of the encode phase
-        import time
-
-        t0 = time.perf_counter()
-        node_side = build_node_axis_tables(enc, placed, match_cache)
-        pulse.phase("table_build", time.perf_counter() - t0)
-    else:
-        node_side = build_node_axis_tables(enc, placed, match_cache)
+    node_side = build_node_axis_tables(enc, placed, match_cache)
     return BatchTables(**pod_side, **node_side)
 
 

@@ -27,7 +27,6 @@ import contextlib
 import copy
 import dataclasses
 import functools
-import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -51,6 +50,7 @@ from ..utils.objutil import (
     pod_host_ports,
     selector_from_set,
 )
+from ..utils.trace import Span
 from .encode import (
     SIG_MEMO_KEY,
     plugin_flags,
@@ -59,7 +59,8 @@ from .encode import (
     NodeArrays,
     PlacedGroup,
     bucket_capped,
-    build_batch_tables,
+    build_node_axis_tables,
+    build_pod_axis_tables,
     carried_specs_of_pod,
     pad_batch_tables,
     pad_encoder_axes,
@@ -167,143 +168,143 @@ class Simulator:
         sharded and single-device paths produce identical placements — the
         mesh only distributes the [*, N] tables and carry rows, and XLA
         inserts the cross-shard collectives for normalizers and argmax."""
-        # The simulator owns its node objects, like the reference's fakeclient
-        # (Create deep-copies): the plugins write annotations/allocatable back into
-        # nodes, and repeated simulations over one caller-owned cluster (the
-        # capacity planner's probes) must never see a previous run's mutations.
-        # A columnar NodeStore (simulator/store.py) is immutable by contract and
-        # materializes per-Simulator dict views, so the deepcopy is a no-op
-        # there — UNLESS a block declares gpu/local-storage state, whose
-        # host-mirrored ledgers write node annotations back: those clusters
-        # materialize to real dicts up front (correctness over speed).
-        if isinstance(nodes, NodeStore):
-            if nodes.may_have_gpu or nodes.may_have_local_storage:
-                nodes = [nodes.materialize(i) for i in range(len(nodes))]
-        else:
-            nodes = copy.deepcopy(nodes)
-        from ..api.schedconfig import DEFAULT_SCHEDULER_CONFIG, KERNEL_FILTERS
-        from ..utils.devices import enable_compilation_cache
+        with Span("init"):
+            # The simulator owns its node objects, like the reference's fakeclient
+            # (Create deep-copies): the plugins write annotations/allocatable back into
+            # nodes, and repeated simulations over one caller-owned cluster (the
+            # capacity planner's probes) must never see a previous run's mutations.
+            # A columnar NodeStore (simulator/store.py) is immutable by contract and
+            # materializes per-Simulator dict views, so the deepcopy is a no-op
+            # there — UNLESS a block declares gpu/local-storage state, whose
+            # host-mirrored ledgers write node annotations back: those clusters
+            # materialize to real dicts up front (correctness over speed).
+            if isinstance(nodes, NodeStore):
+                if nodes.may_have_gpu or nodes.may_have_local_storage:
+                    nodes = [nodes.materialize(i) for i in range(len(nodes))]
+            else:
+                nodes = copy.deepcopy(nodes)
+            from ..api.schedconfig import DEFAULT_SCHEDULER_CONFIG, KERNEL_FILTERS
+            from ..utils.devices import enable_compilation_cache
 
-        # persistent XLA cache: fresh processes (CLI runs, server workers)
-        # reuse compiled scan kernels instead of re-paying 15-40s per shape
-        enable_compilation_cache()
-        # ground-truth XLA compile counting (obs/instruments.py, idempotent);
-        # this constructor has already committed to importing jax
-        obs.install_jax_monitoring()
-        # simonpulse per-dispatch ledger (obs/pulse.py): OPEN_SIMULATOR_PULSE=1
-        pulse.maybe_enable_from_env()
+            # persistent XLA cache: fresh processes (CLI runs, server workers)
+            # reuse compiled scan kernels instead of re-paying 15-40s per shape
+            enable_compilation_cache()
+            # ground-truth XLA compile counting (obs/instruments.py, idempotent);
+            # this constructor has already committed to importing jax
+            obs.install_jax_monitoring()
+            # simonpulse per-dispatch ledger (obs/pulse.py): OPEN_SIMULATOR_PULSE=1
+            pulse.maybe_enable_from_env()
 
-        self.sched_config = sched_config or DEFAULT_SCHEDULER_CONFIG
-        self.score_w = kernels.ScoreWeights(**self.sched_config.weight_kwargs())
-        self.filter_flags = kernels.FilterFlags(**{
-            flag: name not in self.sched_config.disabled_kernel_filters
-            for name, flag in KERNEL_FILTERS.items()
-        })
-        self.axis = ResourceAxis()
-        if isinstance(nodes, NodeStore):
-            for k in nodes.resource_names():
-                self.axis.intern(k)
-        else:
-            self.axis.discover(nodes, [])
-        self.model = ClusterModel()
-        self.na = NodeArrays(nodes, self.axis)
-        self.encoder = Encoder(self.na, self.axis, self.model)
-        self.encoder.filter_disabled = self.sched_config.disabled_encoder_filters
-        self.encoder.extra_plugins = list(extra_plugins or [])
-        from ..plugins.gpushare import GpuShareHost
-        from ..plugins.openlocal import OpenLocalHost
+            self.sched_config = sched_config or DEFAULT_SCHEDULER_CONFIG
+            self.score_w = kernels.ScoreWeights(**self.sched_config.weight_kwargs())
+            self.filter_flags = kernels.FilterFlags(**{
+                flag: name not in self.sched_config.disabled_kernel_filters
+                for name, flag in KERNEL_FILTERS.items()
+            })
+            self.axis = ResourceAxis()
+            if isinstance(nodes, NodeStore):
+                for k in nodes.resource_names():
+                    self.axis.intern(k)
+            else:
+                self.axis.discover(nodes, [])
+            self.model = ClusterModel()
+            with Span("init.nodes"):
+                self.na = NodeArrays(nodes, self.axis)
+            with Span("init.encoder"):
+                self.encoder = Encoder(self.na, self.axis, self.model)
+            self.encoder.filter_disabled = self.sched_config.disabled_encoder_filters
+            self.encoder.extra_plugins = list(extra_plugins or [])
+            from ..plugins.gpushare import GpuShareHost
+            from ..plugins.openlocal import OpenLocalHost
 
-        self.gpu_host = GpuShareHost(self.na.nodes)
-        self.encoder.gpu_host = self.gpu_host
-        self.local_host = OpenLocalHost(self.na.nodes)
-        self.encoder.local_host = self.local_host
-        self.placed: Dict[object, PlacedGroup] = {}  # signature → aggregated commits
-        # per-node placement registry: dict lists + columnar spans, lazy
-        # materialization on read-back (simulator/store.py PodsOnNode)
-        self.pods_on_node: PodsOnNode = PodsOnNode(self.na.N)
-        # pod-store bases with bulk-committed rows: the _sig_rec fallback for
-        # preemption bookkeeping the bulk path skips per-pod
-        self._bulk_stores: List[object] = []
-        self.homeless: List[dict] = []  # bound to a node name we don't know
-        # Preemption bookkeeping (simulator/preemption.py). _sig_of and
-        # _commits_prio are maintained on every commit (a dict store + int
-        # append per pod): evictions must find any placed pod's signature,
-        # and commit order proxies pod start time. The commit LOG (pod-dict
-        # undo info for the rewind) only fills once mixed priorities arm the
-        # PostFilter.
-        self.preempted: List[dict] = []   # {pod, node, by} eviction records
-        self._sig_of: Dict[int, tuple] = {}   # id(pod) → (sig, node_i, seq)
-        self._commits_prio: List[int] = []    # spec.priority per commit, in order
-        # (pod, prev_gpu_index, prev_assume, prev_node_name, prev_status)
-        self._commit_log: List[tuple] = []
-        # nominatedNodeName writes on failed preemptors (not commits):
-        # (pod, had_status, prev_value, had_key) — undone by restore()
-        self._nominate_log: List[tuple] = []
-        self._preempt_armed = False
-        # Crash consistency (resilience/): _transaction() arms full commit
-        # logging so ANY failure rolls host state back; the two counters keep
-        # the commits−rollbacks−victims metric reconciliation exact when a
-        # batch dies between its commits and its batch-end COMMITS increment.
-        self._txn_armed = False
-        self._commit_events = 0    # _commit_pod calls, monotone
-        self._commits_counted = 0  # commit events already in obs.COMMITS
-        self._priority_seen: set = set()
-        self.match_cache: Dict[Tuple[int, object], bool] = {}  # (counter id, sched signature)
-        self.disable_progress = disable_progress
-        self.patch_pod_funcs = patch_pod_funcs or []
-        self._last_tables: Optional[BatchTables] = None
-        self._last_carry = None
-        # Wave scheduling (ops/kernels.py schedule_wave): runs of identical pods
-        # whose only self-interaction is capacity commit in bulk. Settable to
-        # False to force the pure serial scan (used by the parity tests).
-        self.use_waves = True
-        self.use_mesh = use_mesh
-        self._mesh = _UNSET
-        # simonguard (resilience/guard.py): backends this run executed on, in
-        # order — ["tpu", "cpu"] after a mid-run failover. Seeded lazily at
-        # the first device call; surfaced on SimulateResult.backend_path so
-        # a degraded run is never silent. _fallback pins the rest of this
-        # simulator's life to the CPU fallback after a containment.
-        self.backend_path: List[str] = []
-        self._fallback = False
-        # routing cache, keyed by a flags/weights digest so mutating
-        # filter_flags/score_w on a reused Simulator can never return stale
-        # routes (_route_digest; the stale-cache regression test covers it)
-        self._wave_elig_cache: Dict[int, GroupRoute] = {}
-        self._wave_elig_key: tuple = ()
-        self._domain_count_cache: Dict[str, int] = {}  # topo key → #domains
-        import os as _os
+            with Span("init.plugins"):
+                self.gpu_host = GpuShareHost(self.na.nodes)
+                self.local_host = OpenLocalHost(self.na.nodes)
+            self.encoder.gpu_host = self.gpu_host
+            self.encoder.local_host = self.local_host
+            self.placed: Dict[object, PlacedGroup] = {}  # signature → aggregated commits
+            # per-node placement registry: dict lists + columnar spans, lazy
+            # materialization on read-back (simulator/store.py PodsOnNode)
+            self.pods_on_node: PodsOnNode = PodsOnNode(self.na.N)
+            # pod-store bases with bulk-committed rows: the _sig_rec fallback for
+            # preemption bookkeeping the bulk path skips per-pod
+            self._bulk_stores: List[object] = []
+            self.homeless: List[dict] = []  # bound to a node name we don't know
+            # Preemption bookkeeping (simulator/preemption.py). _sig_of and
+            # _commits_prio are maintained on every commit (a dict store + int
+            # append per pod): evictions must find any placed pod's signature,
+            # and commit order proxies pod start time. The commit LOG (pod-dict
+            # undo info for the rewind) only fills once mixed priorities arm the
+            # PostFilter.
+            self.preempted: List[dict] = []   # {pod, node, by} eviction records
+            self._sig_of: Dict[int, tuple] = {}   # id(pod) → (sig, node_i, seq)
+            self._commits_prio: List[int] = []    # spec.priority per commit, in order
+            # (pod, prev_gpu_index, prev_assume, prev_node_name, prev_status)
+            self._commit_log: List[tuple] = []
+            # nominatedNodeName writes on failed preemptors (not commits):
+            # (pod, had_status, prev_value, had_key) — undone by restore()
+            self._nominate_log: List[tuple] = []
+            self._preempt_armed = False
+            # Crash consistency (resilience/): _transaction() arms full commit
+            # logging so ANY failure rolls host state back; the two counters keep
+            # the commits−rollbacks−victims metric reconciliation exact when a
+            # batch dies between its commits and its batch-end COMMITS increment.
+            self._txn_armed = False
+            self._commit_events = 0    # _commit_pod calls, monotone
+            self._commits_counted = 0  # commit events already in obs.COMMITS
+            self._priority_seen: set = set()
+            self.match_cache: Dict[Tuple[int, object], bool] = {}  # (counter id, sched signature)
+            self.disable_progress = disable_progress
+            self.patch_pod_funcs = patch_pod_funcs or []
+            self._last_tables: Optional[BatchTables] = None
+            self._last_carry = None
+            # Wave scheduling (ops/kernels.py schedule_wave): runs of identical pods
+            # whose only self-interaction is capacity commit in bulk. Settable to
+            # False to force the pure serial scan (used by the parity tests).
+            self.use_waves = True
+            self.use_mesh = use_mesh
+            self._mesh = _UNSET
+            # simonguard (resilience/guard.py): backends this run executed on, in
+            # order — ["tpu", "cpu"] after a mid-run failover. Seeded lazily at
+            # the first device call; surfaced on SimulateResult.backend_path so
+            # a degraded run is never silent. _fallback pins the rest of this
+            # simulator's life to the CPU fallback after a containment.
+            self.backend_path: List[str] = []
+            self._fallback = False
+            # routing cache, keyed by a flags/weights digest so mutating
+            # filter_flags/score_w on a reused Simulator can never return stale
+            # routes (_route_digest; the stale-cache regression test covers it)
+            self._wave_elig_cache: Dict[int, GroupRoute] = {}
+            self._wave_elig_key: tuple = ()
+            self._domain_count_cache: Dict[str, int] = {}  # topo key → #domains
+            import os as _os
 
-        # Break-even fallback: live-DNS groups whose every self topology has
-        # fewer than this many domains ride the fused group-serial scan
-        # instead of the affinity wave. Default 0: the wave's multi-round
-        # epochs amortize one sort over the whole segment, so it wins at all
-        # cardinalities measured; the knob remains for backends where that
-        # trade flips (placements are exact on either path).
-        try:
-            self._spread_wave_min_domains = int(
-                _os.environ.get("OPEN_SIMULATOR_SPREAD_WAVE_MIN_DOMAINS", "0"))
-        except ValueError:  # pure-performance knob: fall back, don't crash
-            self._spread_wave_min_domains = 0
-        # Per-segment wall-clock attribution (bench BENCH_DETAIL breakdown):
-        # blocks on every segment's result, so it is OFF unless asked for.
-        self._segment_timing = _os.environ.get(
-            "OPEN_SIMULATOR_SEGMENT_TIMING") == "1"
-        # Streaming segment encode (_schedule_run_streaming): runs longer
-        # than this many pods schedule as double-buffered chunks. 0 disables
-        # (monolithic runs); the default keeps every existing bench shape
-        # (<=100k-pod runs) on the single-dispatch path.
-        self._stream_explicit = "OPEN_SIMULATOR_STREAM_PODS" in _os.environ
-        try:
-            self._stream_chunk = max(0, int(_os.environ.get(
-                "OPEN_SIMULATOR_STREAM_PODS", "131072")))
-        except ValueError:  # pure-performance knob: fall back, don't crash
-            self._stream_chunk = 131072
-        # simonxray (obs/xray.py): per-attempt staging for the flight
-        # recorder. None unless recording is active — the off path costs one
-        # None-check per schedule/probe call and nothing else (no extra
-        # dispatches, no extra fetches, unchanged dispatch signatures).
-        self._xray_run = None
+            # Break-even fallback: live-DNS groups whose every self topology has
+            # fewer than this many domains ride the fused group-serial scan
+            # instead of the affinity wave. Default 0: the wave's multi-round
+            # epochs amortize one sort over the whole segment, so it wins at all
+            # cardinalities measured; the knob remains for backends where that
+            # trade flips (placements are exact on either path).
+            try:
+                self._spread_wave_min_domains = int(
+                    _os.environ.get("OPEN_SIMULATOR_SPREAD_WAVE_MIN_DOMAINS", "0"))
+            except ValueError:  # pure-performance knob: fall back, don't crash
+                self._spread_wave_min_domains = 0
+            # Streaming segment encode (_schedule_run_streaming): runs longer
+            # than this many pods schedule as double-buffered chunks. 0 disables
+            # (monolithic runs); the default keeps every existing bench shape
+            # (<=100k-pod runs) on the single-dispatch path.
+            self._stream_explicit = "OPEN_SIMULATOR_STREAM_PODS" in _os.environ
+            try:
+                self._stream_chunk = max(0, int(_os.environ.get(
+                    "OPEN_SIMULATOR_STREAM_PODS", "131072")))
+            except ValueError:  # pure-performance knob: fall back, don't crash
+                self._stream_chunk = 131072
+            # simonxray (obs/xray.py): per-attempt staging for the flight
+            # recorder. None unless recording is active — the off path costs one
+            # None-check per schedule/probe call and nothing else (no extra
+            # dispatches, no extra fetches, unchanged dispatch signatures).
+            self._xray_run = None
 
     # ------------------------------------------------------------- state ----------
 
@@ -557,36 +558,35 @@ class Simulator:
 
         sc = scope.active()  # one None-check: the scope-off hot path pays
         #                      nothing (same contract as xray.begin_run)
-        t0 = time.perf_counter()
         if sc is not None:
             cm = sc.span("engine.schedule_pods", cat="engine", pods=len(pods))
         else:
             cm = contextlib.nullcontext()
-        with cm:
-            return self._schedule_pods_timed(pods, t0)
-
-    def _schedule_pods_timed(self, pods: List[dict], t0: float
-                             ) -> List[UnscheduledPod]:
+        span = Span("schedule_pods", log_if_longer=30.0)
         try:
-            def attempt():
-                # fresh xray staging per ATTEMPT: records of a failed attempt
-                # die with its rolled-back transaction, so a failover replay
-                # never leaves phantom rows (committed records then carry the
-                # full backend_path including the failover)
-                self._xray_run = xray.begin_run("schedule")
-                with self._transaction(memo_pods=pods):
-                    if self._track_priorities(pods):
-                        from .preemption import schedule_with_preemption
-
-                        return schedule_with_preemption(self, pods)
-                    return self._schedule_pods_inner(pods)
-
-            result = self._run_contained(attempt)
-            self._xray_commit()
-            return result
+            with cm, span:
+                return self._schedule_pods_contained(pods)
         finally:
             self._xray_run = None
-            obs.E2E_SECONDS.observe(time.perf_counter() - t0)
+            obs.E2E_SECONDS.observe(span.total)
+
+    def _schedule_pods_contained(self, pods: List[dict]) -> List[UnscheduledPod]:
+        def attempt():
+            # fresh xray staging per ATTEMPT: records of a failed attempt
+            # die with its rolled-back transaction, so a failover replay
+            # never leaves phantom rows (committed records then carry the
+            # full backend_path including the failover)
+            self._xray_run = xray.begin_run("schedule")
+            with self._transaction(memo_pods=pods):
+                if self._track_priorities(pods):
+                    from .preemption import schedule_with_preemption
+
+                    return schedule_with_preemption(self, pods)
+                return self._schedule_pods_inner(pods)
+
+        result = self._run_contained(attempt)
+        self._xray_commit()
+        return result
 
     # ------------------------------------------------ guard / failover -------
 
@@ -746,7 +746,8 @@ class Simulator:
         (repeated probes skip re-encoding), on success and failure alike."""
         from .preemption import restore, snapshot
 
-        snap = snapshot(self)
+        with Span("schedule_pods.snapshot"):
+            snap = snapshot(self)
         base_events = self._commit_events
         base_counted = self._commits_counted
         prev = self._txn_armed
@@ -758,7 +759,8 @@ class Simulator:
                          - (self._commits_counted - base_counted))
             if uncounted > 0:
                 obs.COMMITS.inc(uncounted)
-            restore(self, snap)
+            with Span("schedule_pods.restore"):
+                restore(self, snap)
             # store batches never carry per-pod memos (templates do,
             # transiently) — iterating one here would materialize the whole
             # batch as dicts mid-failover, the exact cost the store removes
@@ -782,11 +784,12 @@ class Simulator:
         if getattr(self.sched_config, "preemption_disabled", False):
             return False
         seen = self._priority_seen
-        if is_pod_store(pods):
-            seen.update(pods.priorities_present())
-        else:
-            seen.update((p.get("spec") or {}).get("priority") or 0
-                        for p in pods)
+        with Span("schedule_pods.priorities"):
+            if is_pod_store(pods):
+                seen.update(pods.priorities_present())
+            else:
+                seen.update((p.get("spec") or {}).get("priority") or 0
+                            for p in pods)
         self._preempt_armed = len(seen) > 1
         return self._preempt_armed
 
@@ -891,25 +894,27 @@ class Simulator:
         """Encode a pod batch into device-ready tables (no scheduling). Exposed for
         the bench/graft harnesses and the parallel (mesh-sharded) path."""
         bt = self.encode_batch_raw(to_schedule)
-        # Pad encoder-derived axes (G/T/Tc/D/ports/term slots) to pow2 buckets: the
-        # encoder interns cumulatively across apps, so without this every
-        # ScheduleApp batch would get fresh shapes and a fresh XLA compile.
-        bt = pad_encoder_axes(bt)
-        # Pad the node axis the same way: the capacity planner re-simulates at N,
-        # N+1, N+2... nodes (apply.go:203-259) — bucketed N keeps the XLA compile
-        # cache warm across probes. Phantom nodes are infeasible by construction.
-        target = bucket_capped(self.na.N, 1024)
-        mesh = self._resolve_mesh()
-        if mesh is not None:
-            # pre-partition at encode time: align the padded node axis to the
-            # mesh's shard count here (pow2 buckets already divide pow2 shard
-            # counts; this covers the rest), so to_device_sharded's own pad is
-            # provably a no-op and every table transfers pre-partitioned
-            from ..parallel.mesh import NODE_AXIS
+        with Span("encode.pads"):
+            # Pad encoder-derived axes (G/T/Tc/D/ports/term slots) to pow2 buckets:
+            # the encoder interns cumulatively across apps, so without this every
+            # ScheduleApp batch would get fresh shapes and a fresh XLA compile.
+            bt = pad_encoder_axes(bt)
+            # Pad the node axis the same way: the capacity planner re-simulates at
+            # N, N+1, N+2... nodes (apply.go:203-259) — bucketed N keeps the XLA
+            # compile cache warm across probes. Phantom nodes are infeasible by
+            # construction.
+            target = bucket_capped(self.na.N, 1024)
+            mesh = self._resolve_mesh()
+            if mesh is not None:
+                # pre-partition at encode time: align the padded node axis to the
+                # mesh's shard count here (pow2 buckets already divide pow2 shard
+                # counts; this covers the rest), so to_device_sharded's own pad is
+                # provably a no-op and every table transfers pre-partitioned
+                from ..parallel.mesh import NODE_AXIS
 
-            shards = mesh.shape[NODE_AXIS]
-            target += (-target) % shards
-        return pad_batch_tables(bt, target)
+                shards = mesh.shape[NODE_AXIS]
+                target += (-target) % shards
+            return pad_batch_tables(bt, target)
 
     def encode_batch_raw(self, to_schedule: List[dict]) -> BatchTables:
         """encode_batch WITHOUT the encoder-axis/node-axis padding: the exact
@@ -918,11 +923,22 @@ class Simulator:
         its node-axis extension path can append template columns before the
         bucketed pads are applied."""
         faults.maybe_fail("encode")
-        batch = self.encode_batch_ids(to_schedule)
+        with Span("encode.ids"):
+            batch = self.encode_batch_ids(to_schedule)
         # Pad the scan length to bound compile-cache churn: powers of two up to 2048,
         # then multiples of 2048 (a 10k batch scans 10240 steps, not 16384).
         pad = bucket_capped(len(batch), 2048)
-        return build_batch_tables(self.encoder, batch, self.placed, self.match_cache, pad_to=pad)
+        # build_batch_tables' two halves, each its own phase
+        with Span("encode.pod_tables"):
+            pod_side = build_pod_axis_tables(self.encoder, batch, pad_to=pad)
+        # the ROADMAP-5 instrument: streaming chunks re-enter here once per
+        # chunk, so per-chunk node-axis table-build cost shows up directly as
+        # the table_build slice of the encode phase
+        with Span("encode.table_build") as sp:
+            node_side = build_node_axis_tables(self.encoder, self.placed,
+                                               self.match_cache)
+        pulse.phase("table_build", sp.total)
+        return BatchTables(**pod_side, **node_side)
 
     def encode_batch_ids(self, to_schedule: List[dict]) -> List[Tuple[int, int]]:
         """The pod-axis half of an encode: (group_id, forced_node) per pod, in
@@ -1318,26 +1334,22 @@ class Simulator:
         return failed
 
     def _schedule_run_once(self, to_schedule: List[dict]) -> List[UnscheduledPod]:
-        from ..utils.trace import Span
-
         # simonpulse run window: dispatch records inside carry this run's id;
         # the run record closes with the LIVE pod count (supervised sees
         # padded counts — useless for attempts reconciliation) and the
-        # encode/to_device/dispatch/fetch/commit wall decomposition.
-        with pulse.run_window(len(to_schedule)), \
-                Span("schedule_run", log_if_longer=30.0) as span:
-            t_enc = time.perf_counter()
-            bt = self.encode_batch(to_schedule)
-            dt_enc = time.perf_counter() - t_enc
-            obs.ENCODE_SECONDS.observe(dt_enc)
+        # encode/to_device/dispatch/fetch/commit wall decomposition, each
+        # phase's wall its Span's (one clock for pulse, the histograms,
+        # --trace-out and the profiler).
+        with pulse.run_window(len(to_schedule)), Span("schedule_run") as span:
+            with Span("encode") as sp:
+                bt = self.encode_batch(to_schedule)
+            obs.ENCODE_SECONDS.observe(sp.total)
             obs.ENCODE_BYTES.inc(batch_tables_nbytes(bt))
             obs.BATCH_PODS.observe(len(to_schedule))
-            pulse.phase("encode", dt_enc)
-            span.step("encode")
-            t_dev = time.perf_counter()
-            tables, carry = self._to_device(bt)
-            pulse.phase("to_device", time.perf_counter() - t_dev)
-            span.step("to_device")
+            pulse.phase("encode", sp.total)
+            with Span("to_device") as sp:
+                tables, carry = self._to_device(bt)
+            pulse.phase("to_device", sp.total)
             failed = self._dispatch_and_commit(to_schedule, bt, tables, carry,
                                                span)
         return failed
@@ -1345,23 +1357,26 @@ class Simulator:
     def _dispatch_and_commit(self, to_schedule: List[dict], bt: BatchTables,
                              tables, carry, span) -> List[UnscheduledPod]:
         failed: List[UnscheduledPod] = []
-        enable_gpu, enable_storage = plugin_flags(bt)
-        self._last_flags = (enable_gpu, enable_storage)
-        jnp = _jax()
         P = len(to_schedule)
+        with Span("route"):
+            enable_gpu, enable_storage = plugin_flags(bt)
+            self._last_flags = (enable_gpu, enable_storage)
+            segs = (self._segments(bt, P) if self.use_waves
+                    else [("serial", 0, P)])
+            dims = self._dispatch_dims(bt)
+            for seg in segs:
+                obs.SEGMENTS.labels(kind=seg[0]).inc()
+                obs.SEGMENT_PODS.labels(kind=seg[0]).inc(seg[2])
+        jnp = _jax()
         choices = np.full(P, -1, np.int32)  # node indices; matches the kernels' i32 outputs
-        segs = self._segments(bt, P) if self.use_waves else [("serial", 0, P)]
-        dims = self._dispatch_dims(bt)
-        for seg in segs:
-            obs.SEGMENTS.labels(kind=seg[0]).inc()
-            obs.SEGMENT_PODS.labels(kind=seg[0]).inc(seg[2])
-        # simonxray: stage one batch record per dispatch run. want_stats also
-        # turns on the affinity kernel's epoch counters for the segment-timing
-        # breakdown (a distinct compiled program — the flag joins its dispatch
-        # signature below, so stats/no-stats shapes never alias).
+        # simonxray: stage one batch record per dispatch run. Recording also
+        # turns on the affinity kernel's epoch counters (a distinct compiled
+        # program — the flag joins its dispatch signature below, so
+        # stats/no-stats shapes never alias).
         xr = self._xray_run
-        want_stats = xr is not None or self._segment_timing
-        aff_stats: Dict[int, object] = {}  # outs index -> [3] i32 device array
+        want_stats = xr is not None
+        # outs index -> ([3] i32 device array, the segment's dispatch span)
+        aff_stats: Dict[int, tuple] = {}
         # Sharded executables + donation: the carry buffers chain in place
         # between segments. Donation is OFF while recording — the xray
         # decision sets are evaluated against segment-START carries AFTER the
@@ -1384,186 +1399,172 @@ class Simulator:
         # waiting on ~35ms of actual device work. `placed` is recovered on the
         # host as sum(counts), never fetched separately.
         outs: List[tuple] = []  # (seg, device array, carry AFTER the segment)
-        t_disp = time.perf_counter()
-        for seg in segs:
-            faults.maybe_fail("dispatch")
-            faults.maybe_fail("oom_dispatch")
-            t_seg = time.perf_counter() if self._segment_timing else 0.0
-            if seg[0] == "serial":
-                _, start, length = seg
-                pad = bucket_capped(length, 2048)
-                pg = np.zeros(pad, np.int32)
-                pg[:length] = bt.pod_group[start:start + length]
-                fn = np.full(pad, -1, np.int32)
-                fn[:length] = bt.forced_node[start:start + length]
-                vd = np.zeros(pad, bool)
-                vd[:length] = True
-                obs.record_dispatch("schedule_batch", P=pad, zones=bt.n_zones,
-                                    gpu=enable_gpu, storage=enable_storage,
-                                    **dims)
-                call = functools.partial(
-                    kns.schedule_batch,
-                    tables, carry, pg, fn, vd,
-                    n_zones=bt.n_zones, enable_gpu=enable_gpu,
-                    enable_storage=enable_storage,
-                    w=self.score_w, filters=self.filter_flags,
-                )
-                carry, ch = guard.supervised(call, site="dispatch", pods=pad)
-                outs.append((seg, ch, carry))
-            elif seg[0] == "spread":
-                _, start, length, g, cap1, ss_live, sa_live = seg
-                pad = bucket_capped(length, 2048)
-                vd = np.zeros(pad, bool)
-                vd[:length] = True
-                obs.record_dispatch("schedule_group_serial", P=pad, ss=ss_live,
-                                    sa=sa_live,
-                                    zones=bt.n_zones if ss_live else 2, **dims)
-                call = functools.partial(
-                    kns.schedule_group_serial,
-                    tables, carry, np.int32(g), vd, np.bool_(cap1),
-                    w=self.score_w, filters=self.filter_flags,
-                    # n_zones only shapes the ss_live zone table; pin it for
-                    # DNS-only segments so new zone labels don't recompile them
-                    ss_live=ss_live, sa_live=sa_live,
-                    n_zones=bt.n_zones if ss_live else 2,
-                )
-                carry, counts, _ = guard.supervised(
-                    call, site="dispatch", pods=pad)
-                outs.append((seg, counts, carry))
-            elif seg[0] == "affinity":
-                # counter-live hard predicates (self spread/affinity/anti,
-                # live SelectorSpread): epoch-batched affinity wave instead
-                # of one pod per scan step
-                _, start, length, g, cap1, ss_live = seg
-                block = kernels.wave_block_for(length, self.na.N)
-                obs.record_dispatch("schedule_affinity_wave", block=block,
-                                    ss=ss_live,
-                                    zones=bt.n_zones if ss_live else 2, **dims,
-                                    **({"stats": True} if want_stats else {}))
-                call = functools.partial(
-                    kns.schedule_affinity_wave,
-                    tables, carry, np.int32(g), np.int32(length),
-                    np.bool_(cap1), ss_live=ss_live,
-                    w=self.score_w, filters=self.filter_flags,
-                    block=block,
-                    n_zones=bt.n_zones if ss_live else 2,
-                    stats=want_stats,
-                )
-                if want_stats:
-                    carry, counts, _, stv = guard.supervised(
-                        call, site="dispatch", pods=length)
-                    aff_stats[len(outs)] = stv
-                else:
-                    carry, counts, _ = guard.supervised(
-                        call, site="dispatch", pods=length)
-                outs.append((seg, counts, carry))
+        with Span("dispatch") as sp_dispatch:
+            for seg in segs:
+                with Span("dispatch." + seg[0]) as sp_seg:
+                    faults.maybe_fail("dispatch")
+                    faults.maybe_fail("oom_dispatch")
+                    if seg[0] == "serial":
+                        _, start, length = seg
+                        pad = bucket_capped(length, 2048)
+                        pg = np.zeros(pad, np.int32)
+                        pg[:length] = bt.pod_group[start:start + length]
+                        fn = np.full(pad, -1, np.int32)
+                        fn[:length] = bt.forced_node[start:start + length]
+                        vd = np.zeros(pad, bool)
+                        vd[:length] = True
+                        obs.record_dispatch("schedule_batch", P=pad, zones=bt.n_zones,
+                                            gpu=enable_gpu, storage=enable_storage,
+                                            **dims)
+                        call = functools.partial(
+                            kns.schedule_batch,
+                            tables, carry, pg, fn, vd,
+                            n_zones=bt.n_zones, enable_gpu=enable_gpu,
+                            enable_storage=enable_storage,
+                            w=self.score_w, filters=self.filter_flags,
+                        )
+                        carry, ch = guard.supervised(call, site="dispatch", pods=pad)
+                        outs.append((seg, ch, carry))
+                    elif seg[0] == "spread":
+                        _, start, length, g, cap1, ss_live, sa_live = seg
+                        pad = bucket_capped(length, 2048)
+                        vd = np.zeros(pad, bool)
+                        vd[:length] = True
+                        obs.record_dispatch("schedule_group_serial", P=pad, ss=ss_live,
+                                            sa=sa_live,
+                                            zones=bt.n_zones if ss_live else 2, **dims)
+                        call = functools.partial(
+                            kns.schedule_group_serial,
+                            tables, carry, np.int32(g), vd, np.bool_(cap1),
+                            w=self.score_w, filters=self.filter_flags,
+                            # n_zones only shapes the ss_live zone table; pin it for
+                            # DNS-only segments so new zone labels don't recompile them
+                            ss_live=ss_live, sa_live=sa_live,
+                            n_zones=bt.n_zones if ss_live else 2,
+                        )
+                        carry, counts, _ = guard.supervised(
+                            call, site="dispatch", pods=pad)
+                        outs.append((seg, counts, carry))
+                    elif seg[0] == "affinity":
+                        # counter-live hard predicates (self spread/affinity/anti,
+                        # live SelectorSpread): epoch-batched affinity wave instead
+                        # of one pod per scan step
+                        _, start, length, g, cap1, ss_live = seg
+                        block = kernels.wave_block_for(length, self.na.N)
+                        obs.record_dispatch("schedule_affinity_wave", block=block,
+                                            ss=ss_live,
+                                            zones=bt.n_zones if ss_live else 2, **dims,
+                                            **({"stats": True} if want_stats else {}))
+                        call = functools.partial(
+                            kns.schedule_affinity_wave,
+                            tables, carry, np.int32(g), np.int32(length),
+                            np.bool_(cap1), ss_live=ss_live,
+                            w=self.score_w, filters=self.filter_flags,
+                            block=block,
+                            n_zones=bt.n_zones if ss_live else 2,
+                            stats=want_stats,
+                        )
+                        if want_stats:
+                            carry, counts, _, stv = guard.supervised(
+                                call, site="dispatch", pods=length)
+                            aff_stats[len(outs)] = (stv, sp_seg)
+                        else:
+                            carry, counts, _ = guard.supervised(
+                                call, site="dispatch", pods=length)
+                        outs.append((seg, counts, carry))
+                    else:
+                        _, start, length, g, cap1, gpu_live = seg
+                        block = kernels.wave_block_for(length, self.na.N)
+                        kmax = kernels.wave_kmax(length, self.na.N, block)
+                        obs.record_dispatch("schedule_wave", block=block, k=kmax,
+                                            gpu_live=gpu_live, **dims)
+                        call = functools.partial(
+                            kns.schedule_wave,
+                            tables, carry, np.int32(g), np.int32(length),
+                            np.bool_(cap1), gpu_live=gpu_live,
+                            w=self.score_w, filters=self.filter_flags,
+                            block=block, kmax=kmax,
+                        )
+                        carry, counts, _ = guard.supervised(
+                            call, site="dispatch", pods=length)
+                        outs.append((seg, counts, carry))
+                    if sharded:
+                        self._audit_reshard(kns, carry)
+        pulse.phase("dispatch", sp_dispatch.total)
+        with Span("fetch") as sp_fetch:
+            final_carry = carry
+            seg_of = np.zeros(P, np.int32)
+            if outs:
+                faults.maybe_fail("fetch")
+                # every kernel returns i32 counts/choices; fetch each (one
+                # pipeline drain — dispatches are async) and stitch on the host,
+                # avoiding 2 eager device ops per segment
+                flat = guard.supervised(
+                    lambda: np.concatenate(
+                        [np.asarray(a, np.int32) for _, a, _ in outs]),
+                    site="fetch", pods=P)
+                off = 0
+                for k, (seg, a, _) in enumerate(outs):
+                    part = flat[off:off + a.shape[0]]
+                    off += a.shape[0]
+                    start, length = seg[1], seg[2]
+                    seg_of[start:start + length] = k
+                    if seg[0] == "serial":
+                        choices[start:start + length] = part[:length]
+                    else:
+                        counts = part
+                        placed = int(counts.sum())
+                        # pods of one group are interchangeable: assign in node
+                        # order; the (length - placed) unschedulable pods stay -1
+                        assign = np.repeat(np.arange(counts.shape[0]), counts)
+                        choices[start:start + placed] = assign[:placed]
+            if aff_stats:
+                # ONE packed fetch for every affinity segment's epoch counters
+                # (the designated spill point — never a fetch per segment), each
+                # then annotated onto its segment's dispatch span, so the fast
+                # path shows up in the Chrome trace
+                order = sorted(aff_stats)
+                vals = guard.supervised(
+                    lambda: np.asarray(jnp.stack([aff_stats[k][0] for k in order])),
+                    site="fetch", pods=len(order))
+                for k, v in zip(order, vals):
+                    st = {"epochs": int(v[0]), "head_fallbacks": int(v[1]),
+                          "rounds": int(v[2])}
+                    aff_stats[k][1].annotate("affinity", {"group": segs[k][3], **st})
+                    if xb is not None:
+                        xb.segments[k]["stats"] = st
+            # Carry snapshots for failure diagnosis against the state the pod
+            # actually failed under (the end of ITS segment) — much closer to the
+            # reference's mid-batch FitErrors than end-of-batch state. Retained
+            # ONLY for segments that contain a failure: holding every segment's
+            # carry would multiply peak device memory by the segment count.
+            fail_mask = choices[:P] < 0
+            if fail_mask.any() and not (sharded and donate):
+                seg_carry_of: Dict[int, object] = {
+                    int(k): outs[int(k)][2] for k in np.unique(seg_of[fail_mask])
+                }
             else:
-                _, start, length, g, cap1, gpu_live = seg
-                block = kernels.wave_block_for(length, self.na.N)
-                kmax = kernels.wave_kmax(length, self.na.N, block)
-                obs.record_dispatch("schedule_wave", block=block, k=kmax,
-                                    gpu_live=gpu_live, **dims)
-                call = functools.partial(
-                    kns.schedule_wave,
-                    tables, carry, np.int32(g), np.int32(length),
-                    np.bool_(cap1), gpu_live=gpu_live,
-                    w=self.score_w, filters=self.filter_flags,
-                    block=block, kmax=kmax,
-                )
-                carry, counts, _ = guard.supervised(
-                    call, site="dispatch", pods=length)
-                outs.append((seg, counts, carry))
-            if sharded:
-                self._audit_reshard(kns, carry)
-            if self._segment_timing:
-                # per-kind wall attribution (bench breakdown): forces the
-                # async dispatch to finish, so only ever enabled explicitly
-                import jax as _jax_mod
-
-                # simonlint: ignore[fetch-in-wave-loop] -- the per-segment block IS the measurement (OPEN_SIMULATOR_SEGMENT_TIMING bench-attribution runs only)
-                _jax_mod.block_until_ready(outs[-1][1])
-                obs.SEGMENT_WALL.labels(kind=seg[0]).inc(
-                    time.perf_counter() - t_seg)
-        t_fetch = time.perf_counter()
-        pulse.phase("dispatch", t_fetch - t_disp)
-        span.step("dispatch")
-        final_carry = carry
-        seg_of = np.zeros(P, np.int32)
-        if outs:
-            faults.maybe_fail("fetch")
-            # every kernel returns i32 counts/choices; fetch each (one
-            # pipeline drain — dispatches are async) and stitch on the host,
-            # avoiding 2 eager device ops per segment
-            flat = guard.supervised(
-                lambda: np.concatenate(
-                    [np.asarray(a, np.int32) for _, a, _ in outs]),
-                site="fetch", pods=P)
-            off = 0
-            for k, (seg, a, _) in enumerate(outs):
-                part = flat[off:off + a.shape[0]]
-                off += a.shape[0]
-                start, length = seg[1], seg[2]
-                seg_of[start:start + length] = k
-                if seg[0] == "serial":
-                    choices[start:start + length] = part[:length]
-                else:
-                    counts = part
-                    placed = int(counts.sum())
-                    # pods of one group are interchangeable: assign in node
-                    # order; the (length - placed) unschedulable pods stay -1
-                    assign = np.repeat(np.arange(counts.shape[0]), counts)
-                    choices[start:start + placed] = assign[:placed]
-        if aff_stats:
-            # ONE packed fetch for every affinity segment's epoch counters
-            # (the designated spill point — never a fetch per segment), then
-            # per-segment step events so the PR 6 fast path shows up in the
-            # Chrome trace instead of one opaque dispatch block
-            order = sorted(aff_stats)
-            vals = guard.supervised(
-                lambda: np.asarray(jnp.stack([aff_stats[k] for k in order])),
-                site="fetch", pods=len(order))
-            for k, v in zip(order, vals):
-                st = {"epochs": int(v[0]), "head_fallbacks": int(v[1]),
-                      "rounds": int(v[2])}
-                g = segs[k][3]
-                span.step(f"affinity[g={g}] epochs={st['epochs']} "
-                          f"rounds={st['rounds']} "
-                          f"head_fallbacks={st['head_fallbacks']}")
-                if xb is not None:
-                    xb.segments[k]["stats"] = st
-        # Carry snapshots for failure diagnosis against the state the pod
-        # actually failed under (the end of ITS segment) — much closer to the
-        # reference's mid-batch FitErrors than end-of-batch state. Retained
-        # ONLY for segments that contain a failure: holding every segment's
-        # carry would multiply peak device memory by the segment count.
-        fail_mask = choices[:P] < 0
-        if fail_mask.any() and not (sharded and donate):
-            seg_carry_of: Dict[int, object] = {
-                int(k): outs[int(k)][2] for k in np.unique(seg_of[fail_mask])
-            }
-        else:
-            # Donated chain: intermediate carry buffers were consumed in
-            # place, so failure diagnosis evaluates against the end-of-batch
-            # carry instead of the failing segment's end state. Reason DETAIL
-            # may differ from the single-device path by the trailing
-            # segments' placements (a documented deviation, like the serial
-            # path's per-attempt vs segment-end gap); placement itself is
-            # identical on both paths.
-            seg_carry_of = {}
-        if xr is not None:
-            # decision sets are evaluated against segment-START state (what
-            # the segment's first pick saw); keep those carries until the
-            # per-pod loop below has built every referenced set
-            seg_start_carry: Dict[int, object] = {
-                k: (outs[k - 1][2] if k > 0 else carry0)
-                for k in range(len(outs))
-            }
-        else:
-            seg_start_carry = {}
-        outs = None  # drop the per-segment carry references
-        self._last_tables, self._last_carry = bt, final_carry
-        pulse.phase("fetch", time.perf_counter() - t_fetch)
-        span.step("fetch")
+                # Donated chain: intermediate carry buffers were consumed in
+                # place, so failure diagnosis evaluates against the end-of-batch
+                # carry instead of the failing segment's end state. Reason DETAIL
+                # may differ from the single-device path by the trailing
+                # segments' placements (a documented deviation, like the serial
+                # path's per-attempt vs segment-end gap); placement itself is
+                # identical on both paths.
+                seg_carry_of = {}
+            if xr is not None:
+                # decision sets are evaluated against segment-START state (what
+                # the segment's first pick saw); keep those carries until the
+                # per-pod loop below has built every referenced set
+                seg_start_carry: Dict[int, object] = {
+                    k: (outs[k - 1][2] if k > 0 else carry0)
+                    for k in range(len(outs))
+                }
+            else:
+                seg_start_carry = {}
+            outs = None  # drop the per-segment carry references
+            self._last_tables, self._last_carry = bt, final_carry
+        pulse.phase("fetch", sp_fetch.total)
 
         progress = getattr(self, "_progress", None)
         reason_cache: Dict[Tuple[int, int, int], Dict[str, int]] = {}
@@ -1579,73 +1580,71 @@ class Simulator:
                 sid = set_cache[key] = xr.add_set(s)
             return sid
 
-        t_commit = time.perf_counter()
-        # Vectorized bulk commit (simulator/store.py): a columnar batch with
-        # the per-pod bookkeeping provably unneeded — no flight recorder, no
-        # armed preemption (which needs per-pod _sig_of rows), no
-        # gpu/local-storage ledgers (whose reserve() writes per-pod
-        # annotations) — applies the whole run's placements as array ops.
-        # Everything else takes the per-pod loop below, which materializes
-        # store rows transparently.
-        if (is_pod_store(to_schedule) and xb is None
-                and not self._preempt_armed
-                and not self.gpu_host.enabled
-                and not self.local_host.enabled):
-            failed.extend(self._commit_store_bulk(
-                to_schedule, bt, choices, P, seg_of, seg_carry_of,
-                final_carry, tables))
-        else:
-            if xb is not None:
-                # plain-int views once per batch: per-pod numpy-scalar casts
-                # on a 100k loop are a measurable slice of recording overhead
-                pg_l = bt.pod_group[:P].tolist()
-                fn_l = bt.forced_node[:P].tolist()
-                seg_l = seg_of.tolist()
-            for i, pod in enumerate(to_schedule):  # simonlint: ignore[per-pod-host-loop] -- store-less fallback; columnar batches ride _commit_store_bulk
-                if progress is not None:
-                    progress.advance(1)
-                node_i = int(choices[i])
+        with Span("commit") as sp_commit:
+            # Vectorized bulk commit (simulator/store.py): a columnar batch with
+            # the per-pod bookkeeping provably unneeded — no flight recorder, no
+            # armed preemption (which needs per-pod _sig_of rows), no
+            # gpu/local-storage ledgers (whose reserve() writes per-pod
+            # annotations) — applies the whole run's placements as array ops.
+            # Everything else takes the per-pod loop below, which materializes
+            # store rows transparently.
+            if (is_pod_store(to_schedule) and xb is None
+                    and not self._preempt_armed
+                    and not self.gpu_host.enabled
+                    and not self.local_host.enabled):
+                failed.extend(self._commit_store_bulk(
+                    to_schedule, bt, choices, P, seg_of, seg_carry_of,
+                    final_carry, tables))
+            else:
                 if xb is not None:
-                    key = (pg_l[i], fn_l[i], seg_l[i])
-                elif node_i < 0:
-                    key = (int(bt.pod_group[i]), int(bt.forced_node[i]),
-                           int(seg_of[i]))
-                else:
-                    key = None
-                if node_i >= 0:
-                    self._commit_pod(pod, node_i)
+                    # plain-int views once per batch: per-pod numpy-scalar casts
+                    # on a 100k loop are a measurable slice of recording overhead
+                    pg_l = bt.pod_group[:P].tolist()
+                    fn_l = bt.forced_node[:P].tolist()
+                    seg_l = seg_of.tolist()
+                for i, pod in enumerate(to_schedule):  # simonlint: ignore[per-pod-host-loop] -- store-less fallback; columnar batches ride _commit_store_bulk
+                    if progress is not None:
+                        progress.advance(1)
+                    node_i = int(choices[i])
                     if xb is not None:
-                        xb.add_pod(xray.pod_key(pod), xray.SCHEDULED, node_i,
-                                   key[2], xray_sid(key), group=key[0])
-                else:
-                    # Pods of one group share tolerations/requests, so the
-                    # per-stage failure counts are identical — diagnose once
-                    # per (group, forced, segment), against that segment's
-                    # end state.
-                    reasons = reason_cache.get(key)
-                    if reasons is None:
-                        reasons = reason_cache[key] = self._explain_reasons(
-                            pod, key[0], key[1], tables,
-                            seg_carry_of.get(int(seg_of[i]), final_carry)
-                        )
-                    pod.pop(SIG_MEMO_KEY, None)
-                    obs.record_filter_reasons(reasons)
-                    reason = self._format_reason(pod, reasons, self.na.N)
-                    if xb is not None:
-                        sid = xray_sid(key)
-                        xr.sets[sid][1].reasons = dict(reasons)
-                        xb.add_pod(xray.pod_key(pod), xray.UNSCHEDULABLE, -1,
-                                   key[2], sid, group=key[0], reason=reason)
-                    failed.append(UnscheduledPod(pod, reason))
-        dt_commit = time.perf_counter() - t_commit
-        obs.HOST_COMMIT_SECONDS.observe(dt_commit)
-        pulse.phase("commit", dt_commit)
-        placed_n = P - len(failed)
-        obs.SCHED_ATTEMPTS.labels(result="scheduled").inc(placed_n)
-        if failed:
-            obs.SCHED_ATTEMPTS.labels(result="unschedulable").inc(len(failed))
-        self._count_commits(placed_n)
-        span.step("commit")
+                        key = (pg_l[i], fn_l[i], seg_l[i])
+                    elif node_i < 0:
+                        key = (int(bt.pod_group[i]), int(bt.forced_node[i]),
+                               int(seg_of[i]))
+                    else:
+                        key = None
+                    if node_i >= 0:
+                        self._commit_pod(pod, node_i)
+                        if xb is not None:
+                            xb.add_pod(xray.pod_key(pod), xray.SCHEDULED, node_i,
+                                       key[2], xray_sid(key), group=key[0])
+                    else:
+                        # Pods of one group share tolerations/requests, so the
+                        # per-stage failure counts are identical — diagnose once
+                        # per (group, forced, segment), against that segment's
+                        # end state.
+                        reasons = reason_cache.get(key)
+                        if reasons is None:
+                            reasons = reason_cache[key] = self._explain_reasons(
+                                pod, key[0], key[1], tables,
+                                seg_carry_of.get(int(seg_of[i]), final_carry)
+                            )
+                        pod.pop(SIG_MEMO_KEY, None)
+                        obs.record_filter_reasons(reasons)
+                        reason = self._format_reason(pod, reasons, self.na.N)
+                        if xb is not None:
+                            sid = xray_sid(key)
+                            xr.sets[sid][1].reasons = dict(reasons)
+                            xb.add_pod(xray.pod_key(pod), xray.UNSCHEDULABLE, -1,
+                                       key[2], sid, group=key[0], reason=reason)
+                        failed.append(UnscheduledPod(pod, reason))
+            placed_n = P - len(failed)
+            obs.SCHED_ATTEMPTS.labels(result="scheduled").inc(placed_n)
+            if failed:
+                obs.SCHED_ATTEMPTS.labels(result="unschedulable").inc(len(failed))
+            self._count_commits(placed_n)
+        obs.HOST_COMMIT_SECONDS.observe(sp_commit.total)
+        pulse.phase("commit", sp_commit.total)
         if xb is not None:
             # the schedule_run span carries this batch's decision summary
             # into /debug/vars and the Chrome trace (obs/chrome.py args)

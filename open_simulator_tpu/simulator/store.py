@@ -49,6 +49,7 @@ from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core import constants as C
+from ..utils.trace import Span
 from .encode import SIG_MEMO_KEY
 
 __all__ = [
@@ -249,8 +250,10 @@ class PodStore(Sequence):
         return self._b.tmpl_of[self._lo:self._hi]
 
     def node_rows(self) -> np.ndarray:
-        """[P] i32 committed node per row of this view (-1 = uncommitted)."""
-        return self._b.node_of[self._lo:self._hi]
+        """[P] i32 committed node per row of this view (-1 = uncommitted):
+        the placements' read-back, the `readback` phase of a simulation."""
+        with Span("readback"):
+            return self._b.node_of[self._lo:self._hi]
 
     def priorities_present(self) -> List[int]:
         """Distinct spec.priority values across this view's templates."""

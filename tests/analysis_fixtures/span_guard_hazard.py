@@ -26,10 +26,10 @@ def scope_span_around_naked_dispatch():
     return counts
 
 
-def span_with_step_around_fanout():
+def span_with_annotation_around_fanout():
     # finding: nested statements inside the with-body are still covered
     with Span("probe") as span:
-        span.step("setup")
+        span.annotate("phase", "setup")
         out = kernels.probe_serial_fanout(tables, carry, active, pg, fn, vd)
     return out
 

@@ -135,9 +135,11 @@ def test_values_flat_view():
 
 def _make_span_tree():
     with Span("root", log_if_longer=99.0) as root:
-        root.step("prep")
-        with Span("child", log_if_longer=99.0) as child:
-            child.step("inner")
+        with Span("prep"):
+            pass
+        with Span("child", log_if_longer=99.0):
+            with Span("inner"):
+                pass
         try:
             with Span("boom", log_if_longer=99.0):
                 raise RuntimeError("x")
@@ -148,8 +150,8 @@ def _make_span_tree():
 
 def test_chrome_trace_roundtrips_through_json():
     root = _make_span_tree()
-    assert [c.name for c in root.children] == ["child", "boom"]
-    assert root.children[1].failed and not root.children[0].failed
+    assert [c.name for c in root.children] == ["prep", "child", "boom"]
+    assert root.children[2].failed and not root.children[1].failed
 
     doc = json.loads(json.dumps(chrome_trace([root], metrics={"m": 1})))
     evs = doc["traceEvents"]

@@ -12,22 +12,30 @@ from fixtures import make_node, make_pod
 
 def test_span_logs_only_over_threshold(caplog):
     with caplog.at_level(logging.WARNING, logger="open_simulator_tpu.trace"):
-        with Span("fast phase", log_if_longer=10.0) as sp:
-            sp.step("a")
+        with Span("fast phase", log_if_longer=10.0):
+            with Span("fast phase.a"):
+                pass
         assert not caplog.records
-        with Span("slow phase", log_if_longer=0.0) as sp:
-            sp.step("b")
-        assert any("slow phase" in r.getMessage() for r in caplog.records)
+        with Span("slow phase", log_if_longer=0.0):
+            # a child never logs, whatever its threshold; the root's line
+            # carries the child's time
+            with Span("slow phase.b", log_if_longer=0.0):
+                pass
+        msgs = [r.getMessage() for r in caplog.records]
+        assert len(msgs) == 1
+        assert "slow phase" in msgs[0] and "slow phase.b: " in msgs[0]
     spans = recent_spans()
     assert spans[0]["name"] == "slow phase" and spans[0]["logged"]
-    assert spans[0]["steps"][0]["name"] == "b"
+    assert spans[0]["children"][0]["name"] == "slow phase.b"
+    assert not spans[0]["children"][0]["logged"]
     assert spans[1]["name"] == "fast phase" and not spans[1]["logged"]
 
 
 def test_span_nesting_attaches_children_to_parent():
     with Span("outer", log_if_longer=99.0) as outer:
-        with Span("inner", log_if_longer=99.0) as inner:
-            inner.step("work")
+        with Span("inner", log_if_longer=99.0):
+            with Span("inner.work"):
+                pass
         with Span("inner2", log_if_longer=99.0):
             pass
     assert [c.name for c in outer.children] == ["inner", "inner2"]
@@ -35,8 +43,12 @@ def test_span_nesting_attaches_children_to_parent():
     # only the ROOT registers in the ring; children nest under it
     assert spans[0]["name"] == "outer"
     assert [c["name"] for c in spans[0]["children"]] == ["inner", "inner2"]
-    assert spans[0]["children"][0]["steps"][0]["name"] == "work"
+    assert spans[0]["children"][0]["children"][0]["name"] == "inner.work"
     assert all(s["name"] != "inner" for s in spans)
+    # a child lies inside its parent on the shared clock
+    inner = outer.children[0]
+    assert outer.t0 <= inner.t0
+    assert inner.t0 + inner.total <= outer.t0 + outer.total
 
 
 def test_span_exception_safety_records_partial_and_failed():
@@ -45,15 +57,18 @@ def test_span_exception_safety_records_partial_and_failed():
     with pytest.raises(RuntimeError):
         with Span("outer", log_if_longer=99.0):
             with pytest.raises(RuntimeError):
-                with Span("dies", log_if_longer=99.0) as sp:
-                    sp.step("before")
+                with Span("dies", log_if_longer=99.0):
+                    with Span("dies.before"):
+                        pass
                     raise RuntimeError("boom")
             raise RuntimeError("outer dies too")
     spans = recent_spans()
     assert spans[0]["name"] == "outer" and spans[0]["failed"]
     child = spans[0]["children"][0]
     assert child["name"] == "dies" and child["failed"]
-    assert child["steps"][0]["name"] == "before"  # partial steps survive
+    # partial children survive
+    assert [c["name"] for c in child["children"]] == ["dies.before"]
+    assert not child["children"][0]["failed"]
     # the active-span stack unwound: a fresh span is a root again
     with Span("clean", log_if_longer=99.0):
         pass
@@ -88,9 +103,38 @@ def test_simulate_emits_span():
     names = [s["name"] for s in recent_spans()]
     assert "Simulate" in names
     sim_span = next(s for s in recent_spans() if s["name"] == "Simulate")
-    step_names = [st["name"] for st in sim_span["steps"]]
-    assert "expand cluster workloads" in step_names
-    assert "sync cluster" in step_names
+    phase_names = [c["name"] for c in sim_span["children"]]
+    assert "Simulate.expand_workloads" in phase_names
+    assert "Simulate.sync_cluster" in phase_names
+    # the Simulator's construction is a phase of Simulate too
+    assert "init" in phase_names
+
+
+def test_span_without_jax_stays_jax_free():
+    """utils/trace never imports jax: a Span in a process that has not loaded
+    it is host-clock only, and still nests."""
+    import os
+    import subprocess
+    import sys
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__))), "open_simulator_tpu", "utils", "trace.py")
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('trace_alone', {path!r})\n"
+        "m = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(m)\n"
+        "with m.Span('root', log_if_longer=99.0) as root:\n"
+        "    with m.Span('root.child'):\n"
+        "        pass\n"
+        "assert [c.name for c in root.children] == ['root.child']\n"
+        "assert root.total > 0\n"
+        "assert 'jax' not in sys.modules, 'Span imported jax'\n"
+        "print('ok')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "ok"
 
 
 def test_progress_renders_and_closes():

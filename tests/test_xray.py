@@ -415,19 +415,25 @@ def test_schedule_run_span_carries_decision_records(tmp_path, recorder):
     sim = Simulator(copy.deepcopy(nodes))
     sim.schedule_pods(copy.deepcopy(pods))
     spans = stop_collection()
-    runs = [s for s in spans if s.name == "schedule_run"]
+    # schedule_run is a phase of the schedule_pods root span
+    runs = [c for s in spans if s.name == "schedule_pods"
+            for c in s.children if c.name == "schedule_run"]
     assert runs and "xray" in runs[0].meta
     meta = runs[0].meta["xray"]
     assert meta["pods"] == len(pods)
     assert meta["segments"][0]["kind"] == "affinity"
     assert "stats" in meta["segments"][0]  # epoch attribution rides along
-    # the Chrome export carries it as event args + the affinity step events
+    # the Chrome export carries it as event args, and each affinity
+    # segment's dispatch span carries its epoch counters
     doc = chrome_trace(spans)
     ev = next(e for e in doc["traceEvents"]
               if e["name"] == "schedule_run" and e["args"].get("xray"))
     assert ev["args"]["xray"]["decision_sets"] >= 1
-    assert any(e["name"].startswith("affinity[")
-               for e in doc["traceEvents"] if e["cat"] == "step")
+    aff = [e["args"]["affinity"] for e in doc["traceEvents"]
+           if e["name"] == "dispatch.affinity"]
+    assert aff and all(a["epochs"] >= 1 for a in aff)
+    assert aff[0] == {"group": meta["segments"][0]["group"],
+                      **meta["segments"][0]["stats"]}
 
 
 # -------------------------------------------------------------- metrics diff --
