@@ -57,6 +57,13 @@ from ..utils.objutil import (
 _UNSCHED_TAINT = {"key": C.TaintNodeUnschedulable, "effect": "NoSchedule"}
 
 
+def _taints_of(node: dict) -> Tuple[tuple, ...]:
+    """A node's spec.taints as (key, value, effect) tuples."""
+    return tuple((t.get("key", ""), t.get("value", "") or "",
+                  t.get("effect", ""))
+                 for t in (node.get("spec") or {}).get("taints") or [])
+
+
 class NodeArrays:
     """Vectorized view of the node list: per-label-key interned value columns, taints,
     allocatable matrix, zone/domain interning."""
@@ -115,12 +122,20 @@ class NodeArrays:
         self._dom_cache: Dict[str, np.ndarray] = {}
 
     def _init_from_store(self, store, axis: ResourceAxis) -> None:
-        """Build every column from a NodeStore's block recipes. Content is
-        bit-identical to parsing the materialized dicts (the store parity
-        suite holds BatchTables to byte equality); internal string-table ids
-        may differ numerically, which no table ever observes — only equality
-        and first-appearance order matter, and both are preserved because
-        blocks are visited in node order."""
+        """Build every column from a NodeStore's block recipes. What a
+        block's constant part decides is parsed once per distinct template
+        (allocatable row, unschedulable, taints) or once per node KIND, a
+        distinct (template, constant labels) pair (the labels' value ids),
+        and gathered to the nodes: a zoned cluster split into thousands of
+        same-zone blocks is a handful of kinds. Zones are read off the
+        finished label columns.
+
+        Content equals parsing the materialized dicts (the store parity suite
+        holds the columns and BatchTables to equality), with materialize()'s
+        label precedence: constants, then hostname, index labels, zone cycle.
+        Value-interner ids may differ numerically, which no table observes
+        (only equality matters); zone ids keep the dict parse's
+        first-appearance order, which `node_zone` does observe."""
         from .store import LazyNodeSeq
 
         self.axis = axis
@@ -129,79 +144,93 @@ class NodeArrays:
         self.names = store.gen_names()
         self.index = {nm: i for i, nm in enumerate(self.names)}
         self.values = StringTable()
-        self.label_vals = {}
-        self.taints = []
-        self.unschedulable = np.zeros(N, bool)
-        alloc_rows: List[np.ndarray] = []
-        zid = np.zeros(N, np.int32)
-        self.zones = StringTable()
         intern = self.values.intern
+        self.name_ids = np.array([intern(nm) for nm in self.names], np.int32)
+
+        blocks = store.blocks
+        counts = [blk.count for blk in blocks]
+        tmpl_of = np.repeat(np.array([blk.tmpl for blk in blocks], int), counts)
+        kinds: Dict[tuple, int] = {}  # (template index, constant labels) -> kind
+        kind_of = np.repeat(np.array(
+            [kinds.setdefault((blk.tmpl, blk.labels), len(kinds))
+             for blk in blocks], int), counts)
+        self.n_kinds = len(kinds)
+        kind_ids: Dict[str, np.ndarray] = {}  # label key -> value id per kind
+        for (_, labels), k in kinds.items():
+            for key, v in labels:
+                if key not in kind_ids:
+                    kind_ids[key] = np.zeros(len(kinds), np.int32)
+                kind_ids[key][k] = intern(str(v))
+        self.label_vals = {key: ids[kind_of] for key, ids in kind_ids.items()}
+        if N:
+            self.label_vals[HOSTNAME] = self.name_ids.copy()
+
+        tmpls = store.templates
+        self.alloc = (np.stack([axis.node_vector(t) for t in tmpls])[tmpl_of]
+                      if N else np.zeros((0, axis.R)))
+        self.unschedulable = np.array(
+            [bool((t.get("spec") or {}).get("unschedulable")) for t in tmpls],
+            bool)[tmpl_of]
+        tmpl_taints = [_taints_of(t) for t in tmpls]
+        self.taints = [tmpl_taints[t] for t in tmpl_of.tolist()]
+
+        def col(key: str) -> np.ndarray:
+            c = self.label_vals.get(key)
+            if c is None:
+                c = self.label_vals[key] = np.zeros(N, np.int32)
+            return c
+
         off = 0
-        for blk in store.blocks:
-            cnt = blk.count
-            end = off + cnt
-            # per-node labels first, in the same per-node visitation order a
-            # dict parse would use (hostname before index labels before
-            # constants matters only for interner id assignment, which is
-            # unobservable — see docstring)
-            host_col = self.label_vals.get(HOSTNAME)
-            if host_col is None:
-                host_col = self.label_vals[HOSTNAME] = np.zeros(N, np.int32)
-            for i in range(off, end):
-                host_col[i] = intern(self.names[i])
+        for blk in blocks:
+            end = off + blk.count
             for k in blk.index_labels:
-                col = self.label_vals.get(k)
-                if col is None:
-                    col = self.label_vals[k] = np.zeros(N, np.int32)
-                for i in range(off, end):
-                    col[i] = intern(str(i))
-            for k, v in blk.labels:
-                col = self.label_vals.get(k)
-                if col is None:
-                    col = self.label_vals[k] = np.zeros(N, np.int32)
-                col[off:end] = intern(str(v))
+                col(k)[off:end] = [intern(str(i)) for i in range(off, end)]
             if blk.zone_cycle is not None:
-                key, fmt, mod = blk.zone_cycle
-                col = self.label_vals.get(key)
-                if col is None:
-                    col = self.label_vals[key] = np.zeros(N, np.int32)
+                k, fmt, mod = blk.zone_cycle
                 ids = np.array([intern(fmt.format(j)) for j in range(mod)],
                                np.int32)
-                col[off:end] = ids[np.arange(off, end) % mod]
-            lbl = dict(blk.labels)
-            region = (lbl.get(C.LabelTopologyRegion)
-                      or lbl.get("failure-domain.beta.kubernetes.io/region")
-                      or "")
-            zone_keys = (C.LabelTopologyZone, C.LabelTopologyZoneBeta)
-            if blk.zone_cycle is not None and blk.zone_cycle[0] in zone_keys:
-                key, fmt, mod = blk.zone_cycle
-                zids = np.array(
-                    [self.zones.intern((region, fmt.format(j)))
-                     for j in range(mod)], np.int32)
-                zid[off:end] = zids[np.arange(off, end) % mod]
-            else:
-                zone = next((str(lbl[k]) for k in zone_keys if k in lbl), "")
-                if region or zone:
-                    zid[off:end] = self.zones.intern((region, zone))
+                col(k)[off:end] = ids[np.arange(off, end) % mod]
             if blk.taint is not None:
                 t, every = blk.taint
-                self.taints.extend(
-                    ((t,) if i % every == 0 else ())
-                    for i in range(off, end))
-            else:
-                self.taints.extend(() for _ in range(cnt))
-            self.unschedulable[off:end] = bool(
-                (blk.template.get("spec") or {}).get("unschedulable"))
-            alloc_rows.append(np.repeat(
-                axis.node_vector(blk.template)[None, :], cnt, axis=0))
+                for i in range(off + (-off) % every, end, every):
+                    self.taints[i] = (t,)
             off = end
-        self.name_ids = self.label_vals[HOSTNAME].copy() if N else np.zeros(
-            0, np.int32)
-        self.alloc = (np.concatenate(alloc_rows) if alloc_rows
-                      else np.zeros((0, axis.R)))
-        self.zone_id = zid
+        self.zones = StringTable()
+        self.zone_id = self._zone_ids_of_labels()  # 0 = no zone
         self.domains = StringTable()
         self._dom_cache = {}
+
+    def _zone_ids_of_labels(self) -> np.ndarray:
+        """utilnode.GetZoneKey's (region, zone) id per node, read off the
+        label columns; a pair is interned at the first node that has it, as
+        the dict parse does."""
+        blank = self.values.lookup("")  # an empty value counts as unset
+
+        def first_set(*keys: str) -> np.ndarray:
+            out = np.zeros(self.N, np.int32)
+            for key in reversed(keys):
+                c = self.label_vals.get(key)
+                if c is not None:
+                    out = np.where((c != 0) & (c != blank), c, out)
+            return out
+
+        region = first_set(C.LabelTopologyRegion,
+                           "failure-domain.beta.kubernetes.io/region")
+        zone = first_set(C.LabelTopologyZone, C.LabelTopologyZoneBeta)
+        # simonlint: ignore[dtype-drift] -- a host-side sort key, never staged
+        pair = (region.astype(np.int64) << 32) | zone
+        has = np.flatnonzero(pair)
+        _, first, inv = np.unique(pair[has], return_index=True,
+                                  return_inverse=True)
+        ids = np.zeros(len(first), np.int32)
+        value = self.values.value
+        for u in np.argsort(first).tolist():
+            i = has[first[u]]
+            ids[u] = self.zones.intern((value(region[i]) or "",
+                                        value(zone[i]) or ""))
+        zid = np.zeros(self.N, np.int32)
+        zid[has] = ids[inv.ravel()]
+        return zid
 
     def extend(self, nodes: List[dict]) -> None:
         """Append nodes IN PLACE — the serving image's delta-ingest path
@@ -238,11 +267,7 @@ class NodeArrays:
         self.name_ids = np.concatenate(
             [self.name_ids,
              np.array([self.values.intern(nm) for nm in new_names], np.int32)])
-        self.taints.extend(
-            tuple((t.get("key", ""), t.get("value", "") or "",
-                   t.get("effect", ""))
-                  for t in (n.get("spec") or {}).get("taints") or [])
-            for n in nodes)
+        self.taints.extend(map(_taints_of, nodes))
         self.unschedulable = np.concatenate(
             [self.unschedulable,
              np.array([bool((n.get("spec") or {}).get("unschedulable"))

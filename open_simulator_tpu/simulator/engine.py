@@ -208,8 +208,12 @@ class Simulator:
             else:
                 self.axis.discover(nodes, [])
             self.model = ClusterModel()
-            with Span("init.nodes"):
+            with Span("init.nodes") as sp:
                 self.na = NodeArrays(nodes, self.axis)
+                if isinstance(nodes, NodeStore):
+                    # blocks parsed from a kind already seen: 1 - kinds/blocks
+                    sp.annotate("blocks", len(nodes.blocks))
+                    sp.annotate("kinds", self.na.n_kinds)
             with Span("init.encoder"):
                 self.encoder = Encoder(self.na, self.axis, self.model)
             self.encoder.filter_disabled = self.sched_config.disabled_encoder_filters

@@ -43,6 +43,7 @@ can never observe a dict/column split-brain.
 
 from __future__ import annotations
 
+import itertools
 import pickle
 from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -348,15 +349,23 @@ class _NodeBlock(NamedTuple):
     zone_cycle: Optional[Tuple[str, str, int]]  # (label key, fmt, modulus)
     index_labels: Tuple[str, ...]         # label keys valued str(global index)
     taint: Optional[Tuple[tuple, int]]    # ((key, value, effect), every)
+    tmpl: int                             # index in NodeStore.templates
 
 
 class NodeStore(Sequence):
     """Columnar node set: blocks of identical nodes up to indexed labels.
     NodeArrays adopts the columns directly (no per-node dict parsing); the
-    `nodes` list every dict consumer sees becomes a LazyNodeSeq."""
+    `nodes` list every dict consumer sees becomes a LazyNodeSeq.
+
+    Blocks that share a template object share its entry in `templates`
+    (keyed by identity: templates are immutable by contract), so every
+    per-template fact is derived once per distinct template, however many
+    blocks a zoned cluster splits into."""
 
     def __init__(self) -> None:
         self.blocks: List[_NodeBlock] = []
+        self.templates: List[dict] = []
+        self._tmpl_ix: Dict[int, int] = {}  # id(template) -> index
         self._n = 0
 
     def add_block(self, template: dict, count: int, name_fmt: str,
@@ -371,10 +380,15 @@ class NodeStore(Sequence):
             td, every = taint
             t = ((td.get("key", ""), td.get("value", "") or "",
                   td.get("effect", "")), int(every))
+        ti = self._tmpl_ix.get(id(template))
+        if ti is None or self.templates[ti] is not template:
+            # (the identity check also covers an unpickled store's stale ids)
+            ti = self._tmpl_ix[id(template)] = len(self.templates)
+            self.templates.append(template)
         self.blocks.append(_NodeBlock(
             template, int(count), name_fmt,
             tuple(sorted((labels or {}).items())), zone_cycle,
-            tuple(index_labels), t))
+            tuple(index_labels), t, ti))
         self._n += int(count)
         return self
 
@@ -415,11 +429,10 @@ class NodeStore(Sequence):
 
     def gen_names(self) -> List[str]:
         out: List[str] = []
-        i = 0
-        for blk in self.blocks:
-            fmt = blk.name_fmt
-            out.extend(fmt.format(j) for j in range(i, i + blk.count))
-            i += blk.count
+        # one pass per run of blocks sharing a name_fmt
+        for fmt, run in itertools.groupby(self.blocks, lambda b: b.name_fmt):
+            i = len(out)
+            out.extend(map(fmt.format, range(i, i + sum(b.count for b in run))))
         return out
 
     def materialize(self, i: int) -> dict:
@@ -446,18 +459,16 @@ class NodeStore(Sequence):
         return node
 
     # capability flags (plugin hosts and the image-locality scan consult
-    # these instead of walking N dicts) ----------------------------------
+    # these instead of walking N dicts), one look per distinct template ---
 
     def _any_status(self, pred) -> bool:
-        return any(pred((blk.template.get("status") or {}))
-                   for blk in self.blocks)
+        return any(pred((t.get("status") or {})) for t in self.templates)
 
     @property
     def may_have_gpu(self) -> bool:
         from ..plugins.gpushare import node_total_gpu_memory
 
-        return any(node_total_gpu_memory(blk.template) > 0
-                   for blk in self.blocks)
+        return any(node_total_gpu_memory(t) > 0 for t in self.templates)
 
     @property
     def may_have_local_storage(self) -> bool:
@@ -468,19 +479,18 @@ class NodeStore(Sequence):
         return self._any_status(lambda st: bool(st.get("images")))
 
     def any_annotation(self, key: str) -> bool:
-        return any(key in ((blk.template.get("metadata") or {})
-                           .get("annotations") or {})
-                   for blk in self.blocks)
+        return any(key in ((t.get("metadata") or {}).get("annotations") or {})
+                   for t in self.templates)
 
     def resource_names(self) -> List[str]:
         from ..utils.objutil import node_allocatable
 
         out: List[str] = []
         seen = set()
-        for blk in self.blocks:
+        for t in self.templates:
             # node_allocatable, not raw status.allocatable: the axis must see
             # the same capacity fallback node_vector will read later
-            for k in node_allocatable(blk.template):
+            for k in node_allocatable(t):
                 if k not in seen:
                     seen.add(k)
                     out.append(k)

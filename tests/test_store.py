@@ -553,3 +553,200 @@ def test_nodestore_capacity_only_resources():
     failed = sim.schedule_pods(PodStore().add_block(p, 8,
                                                     name_fmt="pod-{0:06d}"))
     assert not failed  # 2 widgets x 4 nodes covers 8 one-widget pods
+
+
+# ------------------------------------------------ many-block node stores --
+
+ZONE = "topology.kubernetes.io/zone"
+REGION = "topology.kubernetes.io/region"
+DEDICATED = {"key": "synth/dedicated", "value": "batch",
+             "effect": "NoSchedule"}
+
+
+def node_tmpl(cpu_milli=32000, unschedulable=False, taints=None):
+    t = synth_node(0, cpu_milli=cpu_milli)
+    t["metadata"] = {}
+    if unschedulable:
+        t["spec"]["unschedulable"] = True
+    if taints:
+        t["spec"]["taints"] = taints
+    return t
+
+
+def store_and_dicts(blocks):
+    """A NodeStore from (template, count, add_block options) recipes, and
+    the same nodes written out as dicts node by node."""
+    ns = NodeStore()
+    nodes = []
+    for tmpl, count, kw in blocks:
+        ns.add_block(tmpl, count, name_fmt="node-{0:05d}", **kw)
+        for _ in range(count):
+            i = len(nodes)
+            name = f"node-{i:05d}"
+            labels = dict(kw.get("labels") or {})
+            labels["kubernetes.io/hostname"] = name
+            for k in kw.get("index_labels", ()):
+                labels[k] = str(i)
+            if kw.get("zone_cycle"):
+                key, fmt, mod = kw["zone_cycle"]
+                labels[key] = fmt.format(i % mod)
+            n = copy.deepcopy(tmpl)
+            n["metadata"] = {"name": name, "labels": labels}
+            taint = kw.get("taint")
+            if taint and i % taint[1] == 0:
+                n["spec"]["taints"] = [dict(taint[0])]
+            nodes.append(n)
+    return ns, nodes
+
+
+def decoded_columns(na):
+    """Every NodeArrays column with interned ids decoded to their values."""
+    def dec(table, ids):
+        return [table.value(int(v)) if v else None for v in ids]
+
+    return {
+        "names": na.names,
+        "name_ids": dec(na.values, na.name_ids),
+        "labels": {k: dec(na.values, col) for k, col in na.label_vals.items()},
+        "alloc": (na.alloc.dtype, na.alloc.tolist()),
+        "zones": dec(na.zones, na.zone_id),
+        "zone_ids": na.zone_id.tolist(),
+        "taints": na.taints,
+        "unschedulable": na.unschedulable.tolist(),
+    }
+
+
+def _zone_runs(seed, n):
+    rng = np.random.default_rng(seed)
+    t = node_tmpl()
+    out, made = [], 0
+    while made < n:
+        c = int(min(rng.integers(1, 4), n - made))
+        out.append((t, c, {"labels": {ZONE: f"moon-{rng.integers(1, 4)}"}}))
+        made += c
+    return out
+
+
+def _two_templates():
+    a, b = node_tmpl(), node_tmpl(cpu_milli=16000, unschedulable=True)
+    return [(a if k % 2 == 0 else b, 3 + k % 4,
+             {"labels": {ZONE: f"zone-{k % 3}"}}) for k in range(24)]
+
+
+def _regions():
+    t = node_tmpl()
+    return [(t, 4, {"labels": {REGION: f"r{k % 2}", ZONE: f"z{k % 3}"}})
+            for k in range(12)] + [
+        (t, 5, {"labels": {"failure-domain.beta.kubernetes.io/zone": "old"}}),
+        (t, 3, {"labels": {REGION: "r0", ZONE: ""}}),
+        (t, 4, {})]
+
+
+def _blank_labels():
+    t = node_tmpl()
+    beta_region = "failure-domain.beta.kubernetes.io/region"
+    beta_zone = "failure-domain.beta.kubernetes.io/zone"
+    # an empty value falls through to the beta label; a constant hostname
+    # label gives way to the node's name
+    return [(t, 3, {"labels": {REGION: "", beta_region: "rb", ZONE: "",
+                               beta_zone: "zb"}}),
+            (t, 2, {"labels": {ZONE: ""}}),
+            (t, 4, {"labels": {"kubernetes.io/hostname": "pinned",
+                               ZONE: "z1"}}),
+            (t, 3, {"labels": {beta_zone: "zb", beta_region: "rb"}})]
+
+
+def _zone_cycle():
+    t = node_tmpl()
+    # the cycle starts mid-period and a short one covers part of it: zone
+    # ids must follow the nodes' first appearances
+    return [(t, 5, {"labels": {ZONE: "zone-a"}}),
+            (t, 13, {"zone_cycle": (ZONE, "zone-{0}", 4)}),
+            (t, 7, {"labels": {ZONE: "zone-1"}}),
+            (t, 3, {"zone_cycle": (ZONE, "zone-{0}", 8),
+                    "labels": {"pool": "x"}}),
+            (t, 9, {"labels": {ZONE: "zone-a"}}),
+            (t, 6, {"zone_cycle": (REGION, "r{0}", 2),
+                    "labels": {ZONE: "zone-1"}})]
+
+
+def _index_labels():
+    t = node_tmpl()
+    return [(t, 10, {"index_labels": ("node-index",),
+                     "labels": {ZONE: "zone-0"}}),
+            (t, 6, {"labels": {ZONE: "zone-1"}}),
+            (t, 10, {"index_labels": ("node-index", "rack"),
+                     "labels": {ZONE: "zone-0"}}),
+            (t, 4, {"index_labels": (ZONE,)})]
+
+
+def _taints():
+    t = node_tmpl()
+    prefer = node_tmpl(taints=[{"key": "soft", "value": "",
+                                "effect": "PreferNoSchedule"}])
+    return [(t, 7, {"taint": (DEDICATED, 3), "labels": {ZONE: "zone-0"}}),
+            (prefer, 5, {"labels": {ZONE: "zone-1"}}),
+            (t, 11, {"taint": (DEDICATED, 3), "labels": {ZONE: "zone-1"}}),
+            (prefer, 8, {"taint": (DEDICATED, 3), "labels": {ZONE: "zone-2"}})]
+
+
+def _one_block():
+    return [(node_tmpl(), 40, {"index_labels": ("node-index",),
+                               "zone_cycle": (ZONE, "zone-{0}", 8),
+                               "taint": (DEDICATED, 10)})]
+
+
+@pytest.mark.parametrize("blocks,kinds", [
+    pytest.param(lambda: _zone_runs(7, 600), 3, id="zone_runs"),
+    pytest.param(_two_templates, 6, id="two_templates"),
+    pytest.param(_regions, 9, id="regions"),
+    pytest.param(_blank_labels, 4, id="blank_labels"),
+    pytest.param(_zone_cycle, 4, id="zone_cycle"),
+    pytest.param(_index_labels, 3, id="index_labels"),
+    pytest.param(_taints, 4, id="taints"),
+    pytest.param(_one_block, 1, id="one_block"),
+])
+def test_parity_many_block_store(blocks, kinds):
+    ns, nodes = store_and_dicts(blocks())
+    _, pods = synth_cluster(len(nodes), 150, hard_predicates=True)
+    _, ps = synth_cluster_store(len(nodes), 150, hard_predicates=True)
+    simd, sims = run_both(nodes, pods, ns, ps)
+    assert decoded_columns(sims.na) == decoded_columns(simd.na)
+    assert sims.na.n_kinds == kinds
+
+
+def test_node_store_work_counts(monkeypatch):
+    from open_simulator_tpu.ops import resources
+    from open_simulator_tpu.utils import trace
+
+    calls = []
+    real = resources.node_allocatable
+
+    def counted(node):
+        calls.append(id(node))
+        return real(node)
+
+    monkeypatch.setattr(resources, "node_allocatable", counted)
+    t = node_tmpl()
+    ns = NodeStore()
+    for k in range(3000):
+        ns.add_block(t, 1 + k % 2, name_fmt="node-{0:05d}",
+                     labels={ZONE: f"moon-{k % 3}"})
+    trace.start_collection()
+    Simulator(ns, use_mesh=False)
+    roots = trace.stop_collection()
+    assert calls == [id(t)]
+    init = [s for s in roots if s.name == "init"][-1]
+    nodes_span = next(c for c in init.children if c.name == "init.nodes")
+    assert nodes_span.meta == {"blocks": 3000, "kinds": 3}
+
+
+def test_store_node_arrays_extend_matches_dicts():
+    ns, nodes = store_and_dicts(_taints())
+    added = synth_node(len(nodes), n_zones=3, taint_every=1)
+    sims = Simulator(ns, use_mesh=False)
+    simd = Simulator(nodes, use_mesh=False)
+    sims.na.extend([copy.deepcopy(added)])
+    simd.na.extend([copy.deepcopy(added)])
+    assert decoded_columns(sims.na) == decoded_columns(simd.na)
+    assert sims.na.nodes[len(nodes)] == simd.na.nodes[len(nodes)]
