@@ -563,9 +563,9 @@ def audit_wave_chain(bucket_key: str, shards: int) -> dict:
         return raw(tb, cry, g, m, cap1, *statics)
 
     def chain(tb, cry, g, m, cap1):
-        c1, j1, p1 = raw(tb, cry, g, m, cap1, *statics)
-        c2, j2, p2 = raw(tb, c1, g, m, cap1, *statics)
-        return c2, j1 + j2, p1 + p2
+        c1, j1, p1, _ = raw(tb, cry, g, m, cap1, *statics)
+        c2, j2, p2, o2 = raw(tb, c1, g, m, cap1, *statics)
+        return c2, j1 + j2, p1 + p2, o2
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -573,7 +573,7 @@ def audit_wave_chain(bucket_key: str, shards: int) -> dict:
     rep = NamedSharding(mesh, P())
     node_sh = NamedSharding(mesh, P("nodes"))
     kw = dict(in_shardings=(ts, cs, rep, rep, rep),
-              out_shardings=(cs, node_sh, rep), donate_argnums=(1,))
+              out_shardings=(cs, node_sh, rep, rep), donate_argnums=(1,))
     args = head_abs + dyn_abs
     t1 = jax.jit(single, **kw).lower(*args).compile().as_text()
     low2 = jax.jit(chain, **kw).lower(*args)

@@ -791,13 +791,14 @@ explain_jit = jax.jit(
 #     s[n, k], k < B, computable without placing anything.
 #   * Serial scheduling of this group is greedy selection over per-node "heads":
 #     repeatedly take max_n s[n, j_n] under the deterministic tie-break (lowest
-#     node index — _step's first-max argmax). When each node's score column is
-#     non-increasing in k, the greedy's first m picks are EXACTLY the m largest
-#     table entries under the key (score desc, node index asc), each node
-#     consuming a prefix of its column — i.e. one stable sort of the flattened
-#     table schedules up to N·B pods at once. Non-monotone columns (possible:
-#     BalancedAllocation can rise as usage evens out) are masked past the first
-#     violation and simply defer to the next iteration.
+#     node index — _step's first-max argmax). A node's k-th copy can only follow
+#     its earlier ones, so give entry (n, k) the key min(s[n, 0..k]), its
+#     column's running minimum: the greedy's picks are EXACTLY the table
+#     entries in (key desc, node index asc, k asc) order, each node consuming
+#     a prefix of its column — i.e. one sort of the flattened keys schedules up
+#     to N·B pods at once, in serial order. A rising column (BalancedAllocation
+#     can rise as usage evens out) is no exception: its higher entries follow
+#     its lower one at once, which is what the greedy does.
 #   * Normalizers stay valid only while the feasible set F is unchanged, and F
 #     changes exactly when a node exhausts its capacity. A node's capacity-
 #     exhausting entry may therefore be taken only as the LAST pick of a wave —
@@ -1054,38 +1055,26 @@ def _mesh_axis_shards(mesh):
 
 def _wave_candidates_from(table_ext, avail, F, B: int, iota_n, kmax: int):
     """Shared wave-iteration candidate half, given the epoch's [N, B+1]
-    score table: the usable-entry mask (capacity, monotone prefix,
-    hidden-continuation guard — see schedule_wave's body comments for the
-    exactness argument) and the top-kmax candidates in serial's exact pick
-    order (score desc, node asc, copy asc — lax.top_k breaks ties by
-    ascending flat index, which IS that order on the n-major table). Entries
-    beyond kmax rank strictly worse than every visible candidate, so
-    truncation only caps one iteration's take — the next iteration (or the
-    head fallback) sees them with identical state. Runs on full arrays in
-    both the unsharded and the sharded epoch path (post-gather). Returns
-    (table, idx_srt, ex_srt, vals) with the last three [kmax]-wide."""
-    N = table_ext.shape[0]
+    score table: the usable-entry mask (capacity, hidden-continuation guard
+    — see the wave-kernel notes above schedule_wave for the exactness
+    argument) and the top-kmax candidates in serial's exact pick order
+    (running-minimum key desc, node asc, copy asc). Entries beyond kmax
+    rank strictly worse than every visible candidate, so truncation only
+    caps one iteration's take — the next iteration (or the head fallback)
+    sees them with identical state. Runs on full arrays in both the
+    unsharded and the sharded epoch path (post-gather). Returns (table,
+    idx_srt, ex_srt, vals) with the last three [kmax]-wide."""
     table = table_ext[:, :B]
+    key_ext = jax.lax.cummin(table_ext, axis=1)  # serial's rank of (n, k)
+    key = key_ext[:, :B]
     ks = jnp.arange(B, dtype=jnp.int32)[None, :]
-    in_cap = ks < avail[:, None]
-    mono = jnp.cumprod(
-        jnp.concatenate(
-            [jnp.ones((N, 1), jnp.int32),
-             (table[:, 1:] <= table[:, :-1]).astype(jnp.int32)], axis=1),
-        axis=1) > 0
-    usable = in_cap & mono & F[:, None]
+    usable = (ks < avail[:, None]) & F[:, None]
 
     # hidden-continuation guard: an entry is takeable only if its key
-    # (score desc, index asc) strictly beats every OTHER node's first hidden
-    # entry (beyond depth B or past a monotonicity break)
-    first_bad = jnp.min(jnp.where(mono, B, ks), axis=1)
-    k_hid = jnp.minimum(first_bad, B)
-    has_hidden = (k_hid < avail) & F
-    bound = jnp.where(
-        has_hidden,
-        jnp.take_along_axis(table_ext, k_hid[:, None], axis=1)[:, 0],
-        -jnp.inf,
-    )
+    # (key desc, index asc) strictly beats every OTHER node's first hidden
+    # entry, its copy at depth B
+    has_hidden = (B < avail) & F
+    bound = jnp.where(has_hidden, key_ext[:, B], -jnp.inf)
     b1 = jnp.max(bound)
     i1 = jnp.argmax(bound)  # first max = lowest index among score ties
     bound2 = bound.at[i1].set(-jnp.inf)
@@ -1093,28 +1082,46 @@ def _wave_candidates_from(table_ext, avail, F, B: int, iota_n, kmax: int):
     i2 = jnp.argmax(bound2)
     cut_s = jnp.where(iota_n == i1, b2, b1)
     cut_i = jnp.where(iota_n == i1, i2, i1).astype(jnp.int32)
-    beats = (table > cut_s[:, None]) | (
-        (table == cut_s[:, None]) & (iota_n[:, None] < cut_i[:, None])
+    beats = (key > cut_s[:, None]) | (
+        (key == cut_s[:, None]) & (iota_n[:, None] < cut_i[:, None])
     )
     usable &= beats
 
-    flat_s = jnp.where(usable, table, -jnp.inf).reshape(-1)
+    flat_s = jnp.where(usable, key, -jnp.inf).reshape(-1)
     exhaust = (ks == (avail[:, None] - 1)) & usable        # entry that empties n
+    # top_k puts equal keys lowest index first (its documented contract;
+    # unbatched, the TPU compiles it to one stable sort of (key, iota), which
+    # tests/test_tpu_compile.py asserts): on the n-major table that is node
+    # asc, copy asc — serial's pick order, with no second sort to compile
     vals, flat_pos = jax.lax.top_k(flat_s, kmax)
     idx_srt = (flat_pos // B).astype(jnp.int32)
     ex_srt = exhaust.reshape(-1)[flat_pos].astype(jnp.int32)
     return table, idx_srt, ex_srt, vals
 
 
+def wave_order_len(m: int) -> int:
+    """Static length of schedule_wave's `order` output for an m-pod wave: a
+    pow2 >= m (floor 256), so order lengths bucket like wave_kmax."""
+    k = 256
+    while k < m:
+        k *= 2
+    return k
+
+
 @partial(jax.jit, static_argnames=("gpu_live", "w", "filters", "block", "kmax",
-                                   "mesh"))
+                                   "order_len", "mesh"))
 @shaped(g="[] i32", m="[] i32", cap1="[] bool")
 def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
                   w: ScoreWeights = DEFAULT_WEIGHTS,
                   filters: FilterFlags = DEFAULT_FILTERS,
-                  block: int = WAVE_BLOCK, kmax: int = 0, mesh=None):
+                  block: int = WAVE_BLOCK, kmax: int = 0, order_len: int = 0,
+                  mesh=None):
     """Place up to m pods of wave-eligible group g, exactly reproducing m serial
-    _step placements. Returns (new carry, per-node counts [N] i32, placed i32).
+    _step placements. Returns (new carry, per-node counts [N] i32, placed i32,
+    order [order_len] i32): order[k] is the node of the k-th pod in serial
+    order (-1 past `placed`), so pods that are interchangeable only to the
+    kernel (one class of several templates) still land pod by pod where the
+    serial scan puts them.
 
     cap1: the group carries hostname-topology required anti-affinity matching
     itself, so every node takes at most one pod of this segment (the tensor
@@ -1130,7 +1137,10 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
     hidden-continuation guard defers to later iterations — only the
     table/sort size vs iteration-count trade-off does. kmax (static, 0 =
     full table): top-k truncation width (wave_kmax); also purely a
-    performance knob (tail entries defer to later iterations).
+    performance knob (tail entries defer to later iterations). order_len
+    (static, wave_order_len(m); 0 = no order, for callers that need only
+    counts): each epoch writes its taken candidates, already ranked in
+    serial order, at positions placed.. of `order`.
 
     mesh (static): a 1-D node mesh routes the epoch loop through an explicit
     shard_map region with exactly ONE all-gather per epoch (the score-table
@@ -1158,7 +1168,7 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
     if gpu_live:
         capacity = _gpu_capacity(tb, cry, g, capacity)
 
-    def body_tail(j, placed, m_, norms, table_ext, F, avail, st_full):
+    def body_tail(j, placed, m_, norms, table_ext, F, avail, st_full, order):
         """Selection back half of one epoch, on full-width arrays (the
         sharded path enters here post-gather, replicated on every shard).
         avail may arrive clamped to B+1: every comparison against it in this
@@ -1184,23 +1194,33 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
 
         counts = jnp.zeros(N, jnp.int32).at[idx_srt].add((pos < m_take).astype(jnp.int32))
 
-        # Guaranteed progress: the hidden-continuation guard can mask every
-        # entry (e.g. a rising column whose bound dominates the whole table).
-        # Serial's next pick is always the best HEAD (each node's k=0 entry),
-        # so placing exactly that one pod is unconditionally serial-correct.
+        # Guaranteed progress: should the guard ever mask every entry, place
+        # serial's next pick, always the best HEAD (each node's k=0 entry):
+        # one pod that is unconditionally serial-correct.
         heads = jnp.where(F, table[:, 0], -jnp.inf)
         any_head = jnp.any(F)
-        head_pick = jnp.zeros(N, jnp.int32).at[jnp.argmax(heads)].set(1)
+        head_i = jnp.argmax(heads)
+        head_pick = jnp.zeros(N, jnp.int32).at[head_i].set(1)
         use_head = (m_take == 0) & any_head & (m_rem > 0)
         counts = jnp.where(use_head, head_pick, counts)
+        if order_len:
+            # the taken prefix is serial's next m_take picks, in order: write
+            # all K candidates from `placed` (one contiguous copy, not a
+            # scatter; the buffer has K slots of slack, and what lands past
+            # the take is overwritten by later epochs or masked at the end)
+            order = jax.lax.dynamic_update_slice(order, idx_srt, (placed,))
+            first = jnp.where(use_head, head_i.astype(jnp.int32), idx_srt[0])
+            order = jax.lax.dynamic_update_slice(order, first[None],
+                                                 (placed,))
         m_take = jnp.where(use_head, jnp.int32(1), m_take)
-        return (j + counts, placed + m_take, m_take)
+        return (j + counts, placed + m_take, m_take, order)
 
     def cond(state):
-        _, placed, last_w = state
+        _, placed, last_w, _ = state
         return (last_w > 0) & (placed < m)
 
     j0 = jnp.zeros(N, jnp.int32)
+    order0 = jnp.full(order_len + K if order_len else 0, -1, jnp.int32)
     ax, shards = _mesh_axis_shards(mesh)
     if (ax is not None and shards > 1 and N % shards == 0
             and N <= _EPOCH_AMORTIZE_MAX_N):
@@ -1213,7 +1233,7 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
 
         def loop_sharded(cap_l, feas_l, alloc_l, nz_l, st_l, st_f, grp_nz, m_):
             def body(state):
-                j, placed, _ = state
+                j, placed, _, order = state
                 shard = jax.lax.axis_index(ax)
                 j_l = jax.lax.dynamic_slice_in_dim(j, shard * NL, NL)
                 avail_l = cap_l - j_l
@@ -1249,38 +1269,40 @@ def schedule_wave(tb: Tables, cry: Carry, g, m, cap1, gpu_live: bool = False,
                 F = full[B + 1] > 0
                 avail = full[B + 2].astype(jnp.int32)
                 return body_tail(j, placed, m_, norms, table_ext, F, avail,
-                                 st_f)
+                                 st_f, order)
 
             def cond_s(state):
-                _, placed, last_w = state
+                _, placed, last_w, _ = state
                 return (last_w > 0) & (placed < m_)
 
             return jax.lax.while_loop(
-                cond_s, body, (j0, jnp.int32(0), jnp.int32(1)))
+                cond_s, body, (j0, jnp.int32(0), jnp.int32(1), order0))
 
         Pn = PartitionSpec(ax)
-        j, placed, _ = jax.shard_map(
+        j, placed, _, order = jax.shard_map(
             loop_sharded, mesh=mesh,
             in_specs=(Pn, Pn, PartitionSpec(ax, None), PartitionSpec(ax, None),
                       {k: (PartitionSpec(None, ax) if v.ndim == 2 else Pn)
                        for k, v in st.items()},
                       {k: PartitionSpec() for k in st_norm},
                       PartitionSpec(), PartitionSpec()),
-            out_specs=(PartitionSpec(),) * 3, check_vma=False,
+            out_specs=(PartitionSpec(),) * 4, check_vma=False,
         )(capacity, base_feas, alloc_cm, cry.nonzero, st, st_norm,
           tb.grp_nonzero[g], m)
     else:
         def body(state):
-            j, placed, _ = state
+            j, placed, _, order = state
             avail = capacity - j                           # copies left per node
             F = base_feas & (avail > 0)
             norms = _wave_norms(st, F)
             table_ext = _wave_score_table(tb, cry, st, norms, g, j, w, B)
-            return body_tail(j, placed, m, norms, table_ext, F, avail, st)
+            return body_tail(j, placed, m, norms, table_ext, F, avail, st,
+                             order)
 
-        j, placed, _ = jax.lax.while_loop(
-            cond, body, (j0, jnp.int32(0), jnp.int32(1)))
-    return _aggregate_commit(tb, cry, g, j, gpu_live), j, placed
+        j, placed, _, order = jax.lax.while_loop(
+            cond, body, (j0, jnp.int32(0), jnp.int32(1), order0))
+    order = jnp.where(jnp.arange(order_len) < placed, order[:order_len], -1)
+    return _aggregate_commit(tb, cry, g, j, gpu_live), j, placed, order
 
 
 class AffinityWaveState(NamedTuple):
@@ -2308,7 +2330,7 @@ def probe_wave_fanout(tb: Tables, cry_s: Carry, active_s, g, m, cap1,
     (carry_s, placed_s [S] i32)."""
 
     def one(cry: Carry, active):
-        c2, _, placed = schedule_wave(
+        c2, _, placed, _ = schedule_wave(
             _mask_active(tb, active), cry, g, m, cap1,
             gpu_live=gpu_live, w=w, filters=filters, block=block, kmax=kmax)
         return c2, placed
@@ -2422,7 +2444,7 @@ def serve_wave_fanout(tb: Tables, cry_s: Carry, active_s, g_s, m_s, cap1_s,
     placed_s [S] i32)."""
 
     def one(cry: Carry, active, g, m, cap1):
-        c2, _, placed = schedule_wave(
+        c2, _, placed, _ = schedule_wave(
             _mask_active(tb, active), cry, g, m, cap1,
             gpu_live=False, w=w, filters=filters, block=block, kmax=kmax)
         return c2, placed
@@ -2434,7 +2456,7 @@ def _sweep_wave_step(tb: Tables, cry: Carry, xs, w: ScoreWeights,
                      filters: FilterFlags, block: int, kmax: int):
     """One wave segment of a sweep lane's chain: (carry, j[N] counts)."""
     g, m, cap1 = xs
-    c2, j, _ = schedule_wave(
+    c2, j, _, _ = schedule_wave(
         tb, cry, g, m, cap1,
         gpu_live=False, w=w, filters=filters, block=block, kmax=kmax)
     return c2, j
@@ -2539,8 +2561,9 @@ class HotKernelSpec(NamedTuple):
 
 HOT_KERNELS = {
     "schedule_wave": HotKernelSpec(
-        ("g", "m", "cap1"), ("carry", "node", "rep"),
-        lambda nz: (False, DEFAULT_WEIGHTS, DEFAULT_FILTERS, WAVE_BLOCK, 0)),
+        ("g", "m", "cap1"), ("carry", "node", "rep", "rep"),
+        lambda nz: (False, DEFAULT_WEIGHTS, DEFAULT_FILTERS, WAVE_BLOCK, 0,
+                    wave_order_len(0))),
     "schedule_affinity_wave": HotKernelSpec(
         ("g", "m", "cap1"), ("carry", "node", "rep"),
         lambda nz: (False, DEFAULT_WEIGHTS, DEFAULT_FILTERS, WAVE_BLOCK, nz,

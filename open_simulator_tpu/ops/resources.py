@@ -81,21 +81,23 @@ class ResourceAxis:
 
 @shaped(ret="[2] f64")
 def pod_nonzero_cpu_mem(pod: dict) -> np.ndarray:
-    """Scoring-side request: per-container max(request, default) summed, init containers
-    taken as a per-resource max — the NonZeroRequested accumulation of the vendored
-    scheduler (framework/types.go calculateResource + non_zero.go)."""
+    """Scoring-side request: each container's request where it is set (an
+    explicit 0 included) and the default where it is unset, summed; init
+    containers taken as a per-resource max — the NonZeroRequested
+    accumulation of kube-scheduler v1.20 (framework/types.go
+    calculateResource + util/non_zero.go GetNonzeroRequestForResource)."""
     from ..utils.quantity import parse_milli, parse_quantity
 
     spec = pod.get("spec") or {}
     cpu = mem = 0.0
     for c in spec.get("containers") or []:
         req = (c.get("resources") or {}).get("requests") or {}
-        cpu += max(parse_milli(req["cpu"]), DEFAULT_MILLI_CPU) if "cpu" in req else DEFAULT_MILLI_CPU
-        mem += max(parse_quantity(req["memory"]), DEFAULT_MEMORY) if "memory" in req else DEFAULT_MEMORY
+        cpu += parse_milli(req["cpu"]) if "cpu" in req else DEFAULT_MILLI_CPU
+        mem += parse_quantity(req["memory"]) if "memory" in req else DEFAULT_MEMORY
     for c in spec.get("initContainers") or []:
         req = (c.get("resources") or {}).get("requests") or {}
-        icpu = max(parse_milli(req["cpu"]), DEFAULT_MILLI_CPU) if "cpu" in req else DEFAULT_MILLI_CPU
-        imem = max(parse_quantity(req["memory"]), DEFAULT_MEMORY) if "memory" in req else DEFAULT_MEMORY
+        icpu = parse_milli(req["cpu"]) if "cpu" in req else DEFAULT_MILLI_CPU
+        imem = parse_quantity(req["memory"]) if "memory" in req else DEFAULT_MEMORY
         cpu = max(cpu, icpu)
         mem = max(mem, imem)
     return np.array([cpu, mem], np.float64)  # simonlint: ignore[dtype-drift] -- host staging, see device-boundary note

@@ -493,10 +493,10 @@ class ShardedKernels:
 
     def schedule_wave(self, tb, cry, g, m, cap1, *, gpu_live=False,
                       w=kernels.DEFAULT_WEIGHTS, filters=kernels.DEFAULT_FILTERS,
-                      block=kernels.WAVE_BLOCK, kmax=0):
+                      block=kernels.WAVE_BLOCK, kmax=0, order_len=0):
         fn = self._kernel_jit("schedule_wave")
         return fn(tb, cry, g, m, cap1, gpu_live, w, filters, block, kmax,
-                  self._wave_mesh())
+                  order_len, self._wave_mesh())
 
     def schedule_affinity_wave(self, tb, cry, g, m, cap1, *, ss_live=False,
                                w=kernels.DEFAULT_WEIGHTS,

@@ -590,6 +590,63 @@ def scheduling_signature(pod: dict):
     )
 
 
+# ------------------------------------------------------------ scheduling classes ----
+#
+# Templates that differ only in labels no selector reads (and in namespace,
+# where no selector is in play at all) are one pod to every filter and score.
+# The engine encodes and dispatches such templates as one class per call;
+# commits and the placed census keep each pod's own template.
+
+
+def selector_keys(sel) -> frozenset:
+    """The label keys a LabelSelector reads."""
+    if not isinstance(sel, dict):
+        return frozenset()
+    keys = set(sel.get("matchLabels") or ())
+    keys.update(e.get("key") for e in sel.get("matchExpressions") or ()
+                if isinstance(e, dict))
+    keys.discard(None)
+    return frozenset(keys)
+
+
+def template_selectors(pod: dict) -> list:
+    """The LabelSelectors of a pod's own terms: required and preferred pod
+    (anti-)affinity, and topology spread constraints of either kind."""
+    spec = pod.get("spec") or {}
+    if not (spec.get("affinity") or spec.get("topologySpreadConstraints")):
+        return []
+    req_aff, req_anti, pref = _affinity_terms(pod)
+    out = [t.get("labelSelector") for t in req_aff + req_anti]
+    out += [t.get("labelSelector") for _, t in pref]
+    out += [c.get("labelSelector")
+            for c in spec.get("topologySpreadConstraints") or []]
+    return out
+
+
+_AVOID_ANNO = "scheduler.alpha.kubernetes.io/preferAvoidPods"
+
+
+def avoid_controller(pod: dict) -> Optional[dict]:
+    """The RC/RS controller reference NodePreferAvoidPods matches a node's
+    preferAvoidPods annotation against (its uid is outside the signature)."""
+    owners = (pod.get("metadata") or {}).get("ownerReferences") or []
+    return next((o for o in owners if o.get("controller") and o.get("kind") in
+                 ("ReplicationController", "ReplicaSet")), None)
+
+
+def class_template(pod: dict, keys: frozenset, keep_ns: bool) -> dict:
+    """The pod as every selector in play sees it: labels whose key is not in
+    `keys` dropped, and the namespace too unless `keep_ns`."""
+    md = dict(pod.get("metadata") or {})
+    md["labels"] = {k: v for k, v in (md.get("labels") or {}).items()
+                    if k in keys}
+    if not keep_ns:
+        md.pop("namespace", None)
+    out = {k: v for k, v in pod.items() if k != SIG_MEMO_KEY}
+    out["metadata"] = md
+    return out
+
+
 def strip_daemon_pin(pod: dict) -> Tuple[dict, Optional[str]]:
     """Detect the DaemonSet pin pattern — every required term carries matchFields
     metadata.name In [x] for one node x — and return (pod-sans-pin, node name) or
@@ -921,24 +978,25 @@ class Encoder:
                 raw += w * node_selector_term_vec(self.na, p.get("preference") or {}).astype(np.float32)
         return raw
 
+    def prefer_avoid_any(self) -> bool:
+        """Whether any node carries a preferAvoidPods annotation (a store
+        answers per block, without materializing N node dicts)."""
+        from .store import LazyNodeSeq
+
+        nodes = self.na.nodes
+        if isinstance(nodes, LazyNodeSeq) and not nodes._extra:
+            return nodes.store.any_annotation(_AVOID_ANNO)
+        return any(annotations_of(n).get(_AVOID_ANNO) for n in nodes)
+
     def _avoid_raw(self, pod: dict) -> np.ndarray:
         """NodePreferAvoidPods (plugin nodepreferavoidpods): 100 unless the node's
         preferAvoidPods annotation targets the pod's RC/RS controller."""
         raw = np.full(self.na.N, 100.0, np.float32)
-        owners = (pod.get("metadata") or {}).get("ownerReferences") or []
-        ctrl = next((o for o in owners if o.get("controller") and o.get("kind") in
-                     ("ReplicationController", "ReplicaSet")), None)
-        if ctrl is None:
+        ctrl = avoid_controller(pod)
+        if ctrl is None or not self.prefer_avoid_any():
             return raw
-        from .store import LazyNodeSeq
-
-        if (isinstance(self.na.nodes, LazyNodeSeq)
-                and not self.na.nodes.store.any_annotation(
-                    "scheduler.alpha.kubernetes.io/preferAvoidPods")
-                and not self.na.nodes._extra):
-            return raw  # no block carries the annotation: skip the N-scan
         for i, node in enumerate(self.na.nodes):
-            anno = annotations_of(node).get("scheduler.alpha.kubernetes.io/preferAvoidPods")
+            anno = annotations_of(node).get(_AVOID_ANNO)
             if not anno:
                 continue
             try:

@@ -27,6 +27,7 @@ import contextlib
 import copy
 import dataclasses
 import functools
+import json
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -61,11 +62,15 @@ from .encode import (
     bucket_capped,
     build_node_axis_tables,
     build_pod_axis_tables,
+    avoid_controller,
     carried_specs_of_pod,
+    class_template,
     pad_batch_tables,
     pad_encoder_axes,
     scheduling_signature,
+    selector_keys,
     strip_daemon_pin,
+    template_selectors,
 )
 from .store import EncodedRows, NodeStore, PodStore, PodsOnNode, is_pod_store
 
@@ -381,24 +386,35 @@ class Simulator:
     # preemption.restore resets the columns instead of walking pod dicts.
     _BULK_LOG = "__bulk__"
 
-    def _placed_group_for_template(self, b, ti: int) -> PlacedGroup:
+    def _placed_group_for_template(self, b, ti: int,
+                                   like: Optional[PlacedGroup] = None
+                                   ) -> PlacedGroup:
         """The PlacedGroup for one store template (same record _commit_pod
         would create from the first committed replica — selector matching
         reads template fields only, so the shared template is an exact
-        representative)."""
+        representative). `like`: the record of a template in the same
+        encoder group, whose request, port and carried-term rows are this
+        template's too (a group's templates differ in nothing those read)."""
         sig = b.sigs[ti]
         pg = self.placed.get(sig)
         if pg is None:
             tmpl = b.templates[ti]
-            pg = self.placed[sig] = PlacedGroup(
-                pod=tmpl,
-                sig=sig,
-                req_vec=self.axis.pod_vector(tmpl).astype(np.float32),
-                nonzero=pod_nonzero_cpu_mem(tmpl).astype(np.float32),
-                port_ids=self.encoder.port_ids(pod_host_ports(tmpl)),
-                carrier_ids=[self.encoder.carrier_id(cs)
-                             for cs in carried_specs_of_pod(tmpl)],
-            )
+            if like is not None:
+                pg = PlacedGroup(pod=tmpl, sig=sig, req_vec=like.req_vec,
+                                 nonzero=like.nonzero,
+                                 port_ids=like.port_ids,
+                                 carrier_ids=like.carrier_ids)
+            else:
+                pg = PlacedGroup(
+                    pod=tmpl,
+                    sig=sig,
+                    req_vec=self.axis.pod_vector(tmpl).astype(np.float32),
+                    nonzero=pod_nonzero_cpu_mem(tmpl).astype(np.float32),
+                    port_ids=self.encoder.port_ids(pod_host_ports(tmpl)),
+                    carrier_ids=[self.encoder.carrier_id(cs)
+                                 for cs in carried_specs_of_pod(tmpl)],
+                )
+            self.placed[sig] = pg
         return pg
 
     def _sig_rec(self, pod: dict) -> Optional[tuple]:
@@ -478,23 +494,33 @@ class Simulator:
             key = tids * span + nodes
             uniq, first, counts = np.unique(
                 key, return_index=True, return_counts=True)
-            for j in np.argsort(first, kind="stable").tolist():
-                k = int(uniq[j])
-                pg = self._placed_group_for_template(b, k // span)
-                node_i = k % span
-                pg.node_counts[node_i] = (
-                    pg.node_counts.get(node_i, 0) + int(counts[j]))
-            # per-node spans, rows in pod order within each node
-            order = np.argsort(nodes, kind="stable")
-            sn = nodes[order]
-            sr = rows_abs[order]
-            bounds = np.flatnonzero(np.diff(sn)) + 1
-            starts = np.concatenate([[0], bounds])
-            root = PodStore(b)
-            pon = self.pods_on_node
-            for nid, rows_chunk in zip(
-                    sn[starts].tolist(), np.split(sr, bounds)):
-                pon[int(nid)].add_span(root, rows_chunk)
+            # one record per template, made in first-appearance order (the
+            # templates of one encoder group share their rows), taking its
+            # (node, count) pairs in first-appearance order: a record with
+            # no counts yet takes them in one dict.update
+            grp = np.asarray(bt.pod_group[:P])[mask]
+            t_u, t_first = np.unique(tids, return_index=True)
+            o = np.argsort(first, kind="stable")
+            o = o[np.argsort(uniq[o] // span, kind="stable")]
+            n_l = (uniq[o] % span).tolist()
+            c_l = counts[o].tolist()
+            cuts = np.searchsorted(uniq[o] // span, t_u).tolist() + [len(o)]
+            t_l = t_u.tolist()
+            g_l = grp[t_first].tolist()
+            like: Dict[int, PlacedGroup] = {}  # encoder group -> a record
+            for u in np.argsort(t_first, kind="stable").tolist():
+                gi = g_l[u]
+                pg = like[gi] = self._placed_group_for_template(
+                    b, t_l[u], like.get(gi))
+                nc, lo, hi = pg.node_counts, cuts[u], cuts[u + 1]
+                if not nc:
+                    nc.update(zip(n_l[lo:hi], c_l[lo:hi]))
+                    continue
+                for node_i, c in zip(n_l[lo:hi], c_l[lo:hi]):
+                    nc[node_i] = nc.get(node_i, 0) + c
+            # per-node spans, rows in pod order within each node, split
+            # when a node's list is first read
+            self.pods_on_node.add_bulk(PodStore(b), nodes, rows_abs)
         if n < P:
             reason_cache: Dict[Tuple[int, int, int], Dict[str, int]] = {}
             for i in np.flatnonzero(~mask).tolist():
@@ -894,10 +920,15 @@ class Simulator:
             self.gpu_host.flush()
         return failed
 
-    def encode_batch(self, to_schedule: List[dict]) -> BatchTables:
+    def encode_batch(self, to_schedule: List[dict],
+                     classes: bool = False) -> BatchTables:
         """Encode a pod batch into device-ready tables (no scheduling). Exposed for
-        the bench/graft harnesses and the parallel (mesh-sharded) path."""
-        bt = self.encode_batch_raw(to_schedule)
+        the bench/graft harnesses and the parallel (mesh-sharded) path.
+        `classes`: fold signature groups into scheduling classes
+        (_unit_groups) — schedule_pods' own encode, whose commit keeps each
+        pod's template; callers that read results per group (serve, sweep,
+        probe) keep one group per signature."""
+        bt = self.encode_batch_raw(to_schedule, classes)
         with Span("encode.pads"):
             # Pad encoder-derived axes (G/T/Tc/D/ports/term slots) to pow2 buckets:
             # the encoder interns cumulatively across apps, so without this every
@@ -920,7 +951,8 @@ class Simulator:
                 target += (-target) % shards
             return pad_batch_tables(bt, target)
 
-    def encode_batch_raw(self, to_schedule: List[dict]) -> BatchTables:
+    def encode_batch_raw(self, to_schedule: List[dict],
+                         classes: bool = False) -> BatchTables:
         """encode_batch WITHOUT the encoder-axis/node-axis padding: the exact
         per-group/per-counter table content at this simulator's real axis sizes.
         The incremental capacity prober (simulator/probe.py) holds this form so
@@ -928,7 +960,7 @@ class Simulator:
         bucketed pads are applied."""
         faults.maybe_fail("encode")
         with Span("encode.ids"):
-            batch = self.encode_batch_ids(to_schedule)
+            batch = self.encode_batch_ids(to_schedule, classes)
         # Pad the scan length to bound compile-cache churn: powers of two up to 2048,
         # then multiples of 2048 (a 10k batch scans 10240 steps, not 16384).
         pad = bucket_capped(len(batch), 2048)
@@ -944,7 +976,8 @@ class Simulator:
         pulse.phase("table_build", sp.total)
         return BatchTables(**pod_side, **node_side)
 
-    def encode_batch_ids(self, to_schedule: List[dict]) -> List[Tuple[int, int]]:
+    def encode_batch_ids(self, to_schedule: List[dict],
+                         classes: bool = False) -> List[Tuple[int, int]]:
         """The pod-axis half of an encode: (group_id, forced_node) per pod, in
         order, interning new groups into the shared encoder. The serving
         image's micro-batcher (serve/batch.py) calls this alone on its warm
@@ -952,8 +985,12 @@ class Simulator:
         dict lookup per pod and the resident node-side tables are reused
         untouched."""
         if is_pod_store(to_schedule):
-            return self._encode_store_ids(to_schedule)
-        batch: List[Tuple[int, int]] = []
+            return self._encode_store_ids(to_schedule, classes)
+        # parallel lists, not a tuple per pod: each tuple would be one more
+        # object for the cyclic collector to promote (see _unit_groups)
+        tmpls: List[dict] = []
+        sigs: List[object] = []
+        forced_of: List[int] = []
         for pod in to_schedule:  # simonlint: ignore[per-pod-host-loop] -- dict-batch encode; PodStore batches take _encode_store_ids
             # strip_daemon_pin can only fire on pods with node affinity; the
             # inline guard keeps the (common) affinity-less pod off the call
@@ -962,58 +999,195 @@ class Simulator:
             else:
                 stripped, target = pod, None
             if target is None:
-                forced, enc_pod = -1, pod
-                if SIG_MEMO_KEY not in pod:
-                    # memoize so _commit_pod (and repeated encodes) never
-                    # recompute; pinned pods keep per-pod signatures below
-                    pod[SIG_MEMO_KEY] = scheduling_signature(pod)
+                # memoize so _commit_pod (and repeated encodes) never
+                # recompute; pinned pods keep per-pod signatures below
+                sig = pod.get(SIG_MEMO_KEY)
+                if sig is None:
+                    sig = pod[SIG_MEMO_KEY] = scheduling_signature(pod)
+                tmpls.append(pod)
+                sigs.append(sig)
+                forced_of.append(-1)
             elif target in self.na.index:
-                forced, enc_pod = self.na.index[target], stripped
+                tmpls.append(stripped)
+                sigs.append(None)
+                forced_of.append(self.na.index[target])
             else:
                 # pin to a node this simulator doesn't know: the memo (stamped
                 # from the UNPINNED template) must not merge this pod into the
                 # unconstrained group — its required matchFields affinity is
                 # unsatisfiable and the pinned signature keeps it that way
-                forced, enc_pod = -1, pod
                 pod.pop(SIG_MEMO_KEY, None)
-            batch.append((self.encoder.group_of(enc_pod), forced))
-        return batch
+                tmpls.append(pod)
+                sigs.append(None)
+                forced_of.append(-1)
+        return list(zip(self._unit_groups(tmpls, sigs, classes), forced_of))
 
-    def _encode_store_ids(self, store: PodStore) -> EncodedRows:
+    def _encode_store_ids(self, store: PodStore,
+                          classes: bool = False) -> EncodedRows:
         """encode_batch_ids for a columnar store view: one group interning +
         daemon-pin decision per TEMPLATE (not per pod), then a vectorized
-        gather maps the decisions over the rows. Byte-identical to the
-        per-pod path: replicas of one template are scheduling-identical, so
-        the per-template (group, forced) pair IS each row's pair."""
+        gather maps the decisions over the rows. Replicas of one template
+        are scheduling-identical, so the per-template (group, forced) pair
+        IS each row's pair, the pair the per-pod path gives each row."""
         b = store.base
         tmpl_rows = store.tmpl_rows()
         n_t = len(b.templates)
         tg = np.zeros(n_t, np.int32)
         tf = np.full(n_t, -1, np.int32)
-        for ti in np.unique(tmpl_rows).tolist():
+        used = np.flatnonzero(np.bincount(tmpl_rows, minlength=n_t)).tolist()
+        tmpls: List[dict] = []
+        sigs: List[object] = []
+        for ti in used:
             tmpl = b.templates[ti]
             if ((tmpl.get("spec") or {}).get("affinity")) is not None:
                 stripped, target = strip_daemon_pin(tmpl)
             else:
                 stripped, target = tmpl, None
             if target is None:
-                # transient memo: group_of must not recompute the signature,
-                # and the shared template must not keep the marker (lazy
-                # blobs would otherwise bake it into materialized pods)
-                tmpl[SIG_MEMO_KEY] = b.sigs[ti]
-                try:
-                    tg[ti] = self.encoder.group_of(tmpl)
-                finally:
-                    tmpl.pop(SIG_MEMO_KEY, None)
+                tmpls.append(tmpl)
+                sigs.append(b.sigs[ti])
             elif target in self.na.index:
                 tf[ti] = self.na.index[target]
-                tg[ti] = self.encoder.group_of(stripped)
+                tmpls.append(stripped)
+                sigs.append(None)
             else:
                 # pinned to an unknown node: the RAW template signature keeps
                 # the unsatisfiable matchFields pin (engine parity — see the
                 # per-pod path's memo handling)
-                tg[ti] = self.encoder.group_of(tmpl)
+                tmpls.append(tmpl)
+                sigs.append(None)
+        tg[used] = self._unit_groups(tmpls, sigs, classes)
         return EncodedRows(tg[tmpl_rows], tf[tmpl_rows])
+
+    def _unit_groups(self, tmpls: List[dict], sigs: List[object],
+                     fold: bool) -> List[int]:
+        """The group id of each unit — a pod, or a store template, with its
+        signature — in order, interned in first-appearance order. A unit
+        without a signature (a daemon pin) interns its own template. With
+        `fold`, unpinned signature groups that differ only where no
+        selector looks share one scheduling class (_classes): its template
+        is interned as a group of its own and, where that group routes to
+        the plain wave (the kernel whose `order` is the serial order),
+        every member takes it. Each pod keeps its own template and
+        signature for the commit and the placed census.
+
+        Per-unit state is kept in lists of ints and of existing objects:
+        a tuple per unit, alive for the whole encode, is an object the
+        cyclic collector promotes, and enough of them set off a full
+        collection of the caller's heap."""
+        slot: Dict[object, int] = {}  # signature -> signature group
+        slot_of: List[int] = []
+        rep: List[int] = []  # each signature group's first unit
+        for i, sig in enumerate(sigs):
+            if sig is None:
+                slot_of.append(-1)
+                continue
+            s = slot.get(sig)
+            if s is None:
+                s = slot[sig] = len(rep)
+                rep.append(i)
+            slot_of.append(s)
+        if fold:
+            with Span("encode.classes") as sp:
+                cls_of, cls_tmpl = self._classes([tmpls[i] for i in rep])
+                sp.annotate("groups", len(rep))
+                sp.annotate("classes", len(cls_tmpl))
+        else:
+            cls_of, cls_tmpl = list(range(len(rep))), [None] * len(rep)
+        enc = self.encoder
+        slot_gid = [-1] * len(rep)
+        cls_gid: Dict[int, int] = {}  # class -> its group, -1 = not folded
+        out: List[int] = []
+        for i, s in enumerate(slot_of):
+            if s < 0 or enc.extra_plugins:
+                # out-of-tree plugins widen the group key past the signature
+                out.append(self._group_with_sig(tmpls[i], sigs[i])
+                           if s >= 0 else enc.group_of(tmpls[i]))
+                continue
+            g = slot_gid[s]
+            if g < 0:
+                c = cls_of[s]
+                if cls_tmpl[c] is not None and c not in cls_gid:
+                    gi = self._group_with_sig(*cls_tmpl[c])
+                    route = self._wave_eligibility(gi)
+                    cls_gid[c] = (gi if route.kind == "wave"
+                                  and not route.gpu_live else -1)
+                g = cls_gid.get(c, -1)
+                if g < 0:
+                    g = self._group_with_sig(tmpls[rep[s]], sigs[rep[s]])
+                slot_gid[s] = g
+            out.append(g)
+        return out
+
+    def _group_with_sig(self, tmpl: dict, sig) -> int:
+        """encoder.group_of with the signature already known. A template
+        without the memo gets it for the call only: the shared template of
+        a store must not keep the marker (lazy blobs would bake it into
+        materialized pods)."""
+        if SIG_MEMO_KEY in tmpl:
+            return self.encoder.group_of(tmpl)
+        tmpl[SIG_MEMO_KEY] = sig
+        try:
+            return self.encoder.group_of(tmpl)
+        finally:
+            tmpl.pop(SIG_MEMO_KEY, None)
+
+    def _classes(self, reps: List[dict]
+                 ) -> Tuple[List[int], List[Optional[tuple]]]:
+        """Partition signature groups (`reps`: each group's template) into
+        scheduling classes: (the class of each group, and per class its
+        (template, signature), or None where it holds one group). Two
+        groups share a class where their templates are equal once
+        class_template drops what no selector in play reads — then every
+        filter, score and counter sees one pod. The class template is
+        never interned under a member's signature, so a later call whose
+        selectors read more splits the class again. Without waves, or with
+        out-of-tree plugins, each signature group is its own class."""
+        if (len(reps) < 2 or not self.use_waves
+                or self.encoder.extra_plugins):
+            return list(range(len(reps))), [None] * len(reps)
+        keys, keep_ns = self._selector_reads(reps)
+        # NodePreferAvoidPods reads an RC/RS controller's uid, which no
+        # signature holds: where a node names one, such templates stay alone
+        avoid = self.encoder.prefer_avoid_any()
+        by_class: Dict[object, int] = {}
+        cls_of: List[int] = []
+        cls: List[list] = []  # [class template, its signature, members]
+        for s, tmpl in enumerate(reps):
+            ct = class_template(tmpl, keys, keep_ns)
+            sig = scheduling_signature(ct)
+            key = (sig, s) if avoid and avoid_controller(tmpl) else sig
+            c = by_class.get(key)
+            if c is None:
+                c = by_class[key] = len(cls)
+                cls.append([ct, sig, 0])
+            cls[c][2] += 1
+            cls_of.append(c)
+        return cls_of, [(ct, sig) if n > 1 else None for ct, sig, n in cls]
+
+    def _selector_reads(self, templates: List[dict]) -> Tuple[frozenset, bool]:
+        """(every label key a selector in play reads, whether any selector is
+        in play): the interned counters and carriers (earlier batches and
+        the terms bound pods carry), SelectorSpread's Services, RCs, RSs and
+        StatefulSets, and `templates`' own terms. Every such selector is
+        scoped to a namespace."""
+        enc, m = self.encoder, self.model
+        sels = [c.selector() for c in enc.counter_list]
+        sels += [json.loads(c.selector_canon) for c in enc.carrier_list]
+        for t in templates:
+            sels += template_selectors(t)
+        for obj in m.replica_sets + m.stateful_sets:
+            sel = (obj.get("spec") or {}).get("selector")
+            if sel:
+                sels.append(sel)
+        keys = set()
+        for sel in sels:
+            keys |= selector_keys(sel)
+        maps = [sel for obj in m.services + m.replication_controllers
+                if (sel := (obj.get("spec") or {}).get("selector"))]
+        for sel in maps:
+            keys.update(sel)
+        return frozenset(keys), bool(sels or maps)
 
     def _kernel_ns(self, donate: bool = True):
         """The dispatch namespace for this simulator: the plain `kernels`
@@ -1346,7 +1520,7 @@ class Simulator:
         # --trace-out and the profiler).
         with pulse.run_window(len(to_schedule)), Span("schedule_run") as span:
             with Span("encode") as sp:
-                bt = self.encode_batch(to_schedule)
+                bt = self.encode_batch(to_schedule, classes=True)
             obs.ENCODE_SECONDS.observe(sp.total)
             obs.ENCODE_BYTES.inc(batch_tables_nbytes(bt))
             obs.BATCH_PODS.observe(len(to_schedule))
@@ -1480,18 +1654,20 @@ class Simulator:
                         _, start, length, g, cap1, gpu_live = seg
                         block = kernels.wave_block_for(length, self.na.N)
                         kmax = kernels.wave_kmax(length, self.na.N, block)
+                        order_len = kernels.wave_order_len(length)
                         obs.record_dispatch("schedule_wave", block=block, k=kmax,
+                                            order=order_len,
                                             gpu_live=gpu_live, **dims)
                         call = functools.partial(
                             kns.schedule_wave,
                             tables, carry, np.int32(g), np.int32(length),
                             np.bool_(cap1), gpu_live=gpu_live,
                             w=self.score_w, filters=self.filter_flags,
-                            block=block, kmax=kmax,
+                            block=block, kmax=kmax, order_len=order_len,
                         )
-                        carry, counts, _ = guard.supervised(
+                        carry, _, _, order = guard.supervised(
                             call, site="dispatch", pods=length)
-                        outs.append((seg, counts, carry))
+                        outs.append((seg, order, carry))
                     if sharded:
                         self._audit_reshard(kns, carry)
         pulse.phase("dispatch", sp_dispatch.total)
@@ -1513,7 +1689,9 @@ class Simulator:
                     off += a.shape[0]
                     start, length = seg[1], seg[2]
                     seg_of[start:start + length] = k
-                    if seg[0] == "serial":
+                    if seg[0] in ("serial", "wave"):
+                        # per-pod nodes: the serial scan's choices, or the
+                        # wave's serial-order `order` (-1 past its placed)
                         choices[start:start + length] = part[:length]
                     else:
                         counts = part
@@ -1822,7 +2000,7 @@ class Simulator:
                     w=self.score_w, filters=self.filter_flags,
                     block=block, kmax=kmax,
                 )
-                carry, _, placed = guard.supervised(
+                carry, _, placed, _ = guard.supervised(
                     call, site="dispatch", pods=length)
                 placed_parts.append(placed)
         if sharded:

@@ -90,13 +90,15 @@ def is_pod_store(obj) -> bool:
 
 
 class _PodBase:
-    """Shared state behind every view of one pod batch."""
+    """Shared state behind every view of one pod batch. The row columns
+    (`blk_start`, `tmpl_of`, `node_of`) are built once from the blocks
+    appended since they were last read, not copied on every add_block."""
 
     __slots__ = (
         "templates", "blobs", "sigs", "tmpl_priority", "tmpl_bound",
-        "blk_tmpl", "blk_fmt", "blk_names", "blk_start", "blk_name_base",
-        "tmpl_of", "node_of", "commit_seq", "cache", "row_by_id",
-        "node_names", "frozen",
+        "blk_tmpl", "blk_fmt", "blk_names", "_blk_start", "blk_name_base",
+        "_tmpl_of", "_node_of", "_pending", "n_rows", "commit_seq", "cache",
+        "row_by_id", "node_names", "frozen",
     )
 
     def __init__(self) -> None:
@@ -108,15 +110,46 @@ class _PodBase:
         self.blk_tmpl: List[int] = []
         self.blk_fmt: List[Optional[str]] = []
         self.blk_names: List[Optional[List[str]]] = []
-        self.blk_start = np.zeros(1, np.int64)  # simonlint: ignore[dtype-drift] -- host-side row offsets, never shipped to device
+        self._blk_start = np.zeros(1, np.int64)  # simonlint: ignore[dtype-drift] -- host-side row offsets, never shipped to device
         self.blk_name_base: List[Optional[int]] = []
-        self.tmpl_of = np.zeros(0, np.int32)
-        self.node_of = np.zeros(0, np.int32)
+        self._tmpl_of = np.zeros(0, np.int32)
+        self._node_of = np.zeros(0, np.int32)
+        self._pending: List[Tuple[int, int]] = []  # (template, count) blocks
+        self.n_rows = 0
         self.commit_seq: Optional[np.ndarray] = None  # lazy [P] i64
         self.cache: Dict[int, dict] = {}
         self.row_by_id: Dict[int, int] = {}
         self.node_names: Optional[Sequence[str]] = None
         self.frozen = False
+
+    def _columns(self) -> None:
+        """Append the pending blocks' rows to the row columns, once."""
+        if not self._pending:
+            return
+        tis, counts = zip(*self._pending)
+        self._pending = []
+        counts = np.array(counts, np.int64)  # simonlint: ignore[dtype-drift] -- host-side row offsets
+        self._blk_start = np.concatenate(
+            [self._blk_start, self._blk_start[-1] + np.cumsum(counts)])
+        self._tmpl_of = np.concatenate(
+            [self._tmpl_of, np.repeat(np.array(tis, np.int32), counts)])
+        self._node_of = np.concatenate(
+            [self._node_of, np.full(int(counts.sum()), -1, np.int32)])
+
+    @property
+    def blk_start(self) -> np.ndarray:
+        self._columns()
+        return self._blk_start
+
+    @property
+    def tmpl_of(self) -> np.ndarray:
+        self._columns()
+        return self._tmpl_of
+
+    @property
+    def node_of(self) -> np.ndarray:
+        self._columns()
+        return self._node_of
 
 
 class PodStore(Sequence):
@@ -133,7 +166,7 @@ class PodStore(Sequence):
                  _lo: int = 0, _hi: Optional[int] = None) -> None:
         self._b = _base if _base is not None else _PodBase()
         self._lo = _lo
-        self._hi = _hi if _hi is not None else int(self._b.blk_start[-1])
+        self._hi = _hi if _hi is not None else self._b.n_rows
 
     # ------------------------------------------------------------ building --
 
@@ -146,7 +179,7 @@ class PodStore(Sequence):
         global row index, or with `name_start` + the block-local index when
         name_start is given), or the template's own metadata.name. The
         template is held by reference and must not be mutated afterwards."""
-        if self._lo != 0 or self._hi != len(self._b.tmpl_of):
+        if self._lo != 0 or self._hi != self._b.n_rows:
             raise ValueError("add_block on a view; build on the root store")
         b = self._b
         if b.frozen:
@@ -167,17 +200,13 @@ class PodStore(Sequence):
         except (TypeError, ValueError):
             b.tmpl_priority.append(0)
         b.tmpl_bound.append(bool(spec.get("nodeName")))
-        start = int(b.blk_start[-1])
         b.blk_tmpl.append(ti)
         b.blk_fmt.append(name_fmt)
         b.blk_names.append(list(names) if names is not None else None)
         b.blk_name_base.append(name_start)  # None = global row numbering
-        b.blk_start = np.append(b.blk_start, start + count)
-        b.tmpl_of = np.concatenate(
-            [b.tmpl_of, np.full(count, ti, np.int32)])
-        b.node_of = np.concatenate(
-            [b.node_of, np.full(count, -1, np.int32)])
-        self._hi = start + count
+        b._pending.append((ti, count))
+        b.n_rows += count
+        self._hi = b.n_rows
         return self
 
     def add_pod(self, pod: dict) -> "PodStore":
@@ -185,7 +214,7 @@ class PodStore(Sequence):
         the dict): exceptional pods — pre-bound, hand-built — ride the store
         without losing their identity; they materialize to the same object."""
         self.add_block(pod, 1)
-        row = int(self._b.blk_start[-1]) - 1
+        row = self._b.n_rows - 1
         self._b.cache[row] = pod
         self._b.row_by_id[id(pod)] = row
         return self
@@ -223,10 +252,11 @@ class PodStore(Sequence):
         nb.blk_fmt = list(b.blk_fmt)
         nb.blk_names = [list(n) if n is not None else None
                         for n in b.blk_names]
-        nb.blk_start = b.blk_start.copy()
+        nb._blk_start = b.blk_start.copy()
+        nb.n_rows = b.n_rows
         nb.blk_name_base = list(b.blk_name_base)
-        nb.tmpl_of = b.tmpl_of.copy()
-        nb.node_of = b.node_of.copy()
+        nb._tmpl_of = b.tmpl_of.copy()
+        nb._node_of = b.node_of.copy()
         nb.commit_seq = (b.commit_seq.copy()
                          if b.commit_seq is not None else None)
         nb.node_names = b.node_names
@@ -593,18 +623,41 @@ class NodePodList:
 
 class PodsOnNode:
     """The engine's `pods_on_node`, backed by a dict of non-empty nodes so
-    the per-transaction snapshot is O(touched nodes), never O(N)."""
+    the per-transaction snapshot is O(touched nodes), never O(N). A bulk
+    commit registers its rows once (add_bulk) and is split into per-node
+    spans only when a node's list is first read: a simulation whose
+    placements are read from the store never builds N lists."""
 
-    __slots__ = ("_n", "_lists")
+    __slots__ = ("_n", "_lists", "_bulk")
 
     def __init__(self, n: int) -> None:
         self._n = n
         self._lists: Dict[int, NodePodList] = {}
+        self._bulk: list = []  # (store, nodes, rows) per unsplit commit
 
     def __len__(self) -> int:
         return self._n
 
+    def add_bulk(self, store: PodStore, nodes: np.ndarray,
+                 rows: np.ndarray) -> None:
+        """Place store rows `rows` (absolute, in commit order) on `nodes`."""
+        self._bulk.append((store, nodes, rows))
+
+    def _split(self) -> None:
+        """Append each pending bulk commit to its nodes' lists, in commit
+        order, rows in commit order within each node."""
+        bulk, self._bulk = self._bulk, []
+        for store, nodes, rows in bulk:
+            order = np.argsort(nodes, kind="stable")
+            sn = nodes[order]
+            bounds = np.flatnonzero(np.diff(sn)) + 1
+            heads = sn[np.concatenate([[0], bounds])].tolist()
+            for nid, chunk in zip(heads, np.split(rows[order], bounds)):
+                self[int(nid)].add_span(store, chunk)
+
     def __getitem__(self, i: int) -> NodePodList:
+        if self._bulk:
+            self._split()
         # hot path first: _commit_pod indexes this once per placed pod, so
         # the existing-list case must stay a bare dict hit (the checked slow
         # path below only runs on first touch / slices / negative indexes)
@@ -632,15 +685,20 @@ class PodsOnNode:
     def extend(self, iterable) -> None:
         """Grow the node axis (serve delta node-add): each yielded entry must
         be an empty list placeholder."""
+        if self._bulk:
+            self._split()
         for entry in iterable:
             assert not entry, "extend only grows empty node slots"
             self._n += 1
 
     def total(self) -> int:
         """Total placed pods, without materializing anything."""
-        return sum(len(l) for l in self._lists.values())
+        return (sum(len(l) for l in self._lists.values())
+                + sum(len(rows) for _, _, rows in self._bulk))
 
     def nonempty(self):
+        if self._bulk:
+            self._split()
         return self._lists.items()
 
     def snapshot(self) -> dict:
@@ -650,6 +708,8 @@ class PodsOnNode:
         # entries. In-repo call sites never hold an EMPTY list across a
         # snapshot boundary (commit/evict grab-and-mutate atomically), so
         # dropping them keeps snapshot O(touched) without losing state.
+        if self._bulk:
+            self._split()
         live = {i: l for i, l in self._lists.items() if l._items}
         if len(live) != len(self._lists):
             self._lists = dict(live)
@@ -657,6 +717,7 @@ class PodsOnNode:
                 "lists": {i: l.copy_items() for i, l in live.items()}}
 
     def restore(self, snap: dict) -> None:
+        self._bulk = []
         self._n = snap["n"]
         self._lists = {i: NodePodList(list(items))
                        for i, items in snap["lists"].items()}

@@ -195,7 +195,7 @@ def test_chained_dispatches_zero_reshard():
     declared = carry_shardings(mesh)
     c = carry
     for _ in range(2):  # chain two dispatches through the same executable
-        c, _j, _p = sk.schedule_wave(
+        c, _j, _p, _o = sk.schedule_wave(
             tables, c, np.int32(0), np.int32(8), np.bool_(False))
         for leaf, want in zip(c, declared):
             assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
@@ -230,11 +230,12 @@ def test_chained_hlo_adds_no_boundary_collectives():
     raw = kernels.schedule_wave.__wrapped__
 
     def single(tb, cry, g, m, cap1):
-        return raw(tb, cry, g, m, cap1)
+        c, j, p, _ = raw(tb, cry, g, m, cap1)
+        return c, j, p
 
     def chain(tb, cry, g, m, cap1):
-        c1, j1, p1 = raw(tb, cry, g, m, cap1)
-        c2, j2, p2 = raw(tb, c1, g, m, cap1)
+        c1, j1, p1, _ = raw(tb, cry, g, m, cap1)
+        c2, j2, p2, _ = raw(tb, c1, g, m, cap1)
         return c2, j1 + j2, p1 + p2
 
     args = (tables, carry, np.int32(0), np.int32(8), np.bool_(False))

@@ -233,3 +233,48 @@ def test_planted_off_by_one_would_fail():
     got = plugin_scores(nodes, seeds, make_pod("p", cpu="1", memory="1Gi"),
                         iso(least=1.0))
     assert got.tolist() != [56.0, 96.0]
+
+
+def _nz_pod(containers, init=()):
+    def ctr(req):
+        c = {"name": "c", "image": "busybox"}
+        if req is not None:
+            c["resources"] = {"requests": dict(req)}
+        return c
+
+    spec = {"containers": [ctr(r) for r in containers]}
+    if init:
+        spec["initContainers"] = [ctr(r) for r in init]
+    return {"metadata": {"name": "p"}, "spec": spec}
+
+
+MI = 1024 * 1024
+
+
+@pytest.mark.parametrize("containers,init,want", [
+    # a set request counts as written, even below the default
+    ([{"cpu": "10m", "memory": "10M"}], (), (10, 10_000_000)),
+    # an explicit 0 counts as 0 (kube overrides only an unset request)
+    ([{"cpu": "0", "memory": "0"}], (), (0, 0)),
+    # unset: the defaults, 100m and 200Mi
+    ([None], (), (100, 200 * MI)),
+    ([{"cpu": "250m"}], (), (250, 200 * MI)),
+    # per container, then summed
+    ([{"cpu": "50m", "memory": "64Mi"}, None], (), (150, 264 * MI)),
+    # init containers: a per-resource max against the sum
+    ([{"cpu": "10m", "memory": "10M"}], [{"cpu": "500m", "memory": "1Gi"}],
+     (500, 1024 * MI)),
+    ([{"cpu": "300m", "memory": "1Gi"}], [{"cpu": "100m", "memory": "1Mi"}],
+     (300, 1024 * MI)),
+    # an init container with no request takes the defaults into the max
+    ([{"cpu": "10m", "memory": "10M"}], [None], (100, 200 * MI)),
+])
+def test_pod_nonzero_cpu_mem_is_kube_rule(containers, init, want):
+    """pkg/scheduler/util/non_zero.go GetNonzeroRequestForResource (v1.20):
+    the default only where the request is unset; framework/types.go
+    calculateResource sums containers and takes the max with each init
+    container."""
+    from open_simulator_tpu.ops.resources import pod_nonzero_cpu_mem
+
+    got = pod_nonzero_cpu_mem(_nz_pod(containers, init))
+    assert tuple(got.tolist()) == want

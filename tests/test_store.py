@@ -306,6 +306,28 @@ def test_lazy_readback_boundary():
     assert sim.pods_on_node[0][0] is pod
 
 
+def test_bulk_commit_splits_per_node_on_first_read():
+    """A bulk commit builds no per-node list until a node is read; then
+    every node lists its pods in commit order, as the per-pod commit of
+    the same batch does, with a later per-pod commit after them."""
+    ns, ps = synth_cluster_store(16, 120)
+    sim = Simulator(ns, use_mesh=False)
+    sim.schedule_pods(ps)
+    assert sim.pods_on_node._lists == {}
+    assert sim.pods_on_node.total() == 120
+    late = synth_pod(10_000)
+    sim.schedule_pods([late])
+    nodes, pods = synth_cluster(16, 120)
+    simd = Simulator(nodes, use_mesh=False)
+    simd.schedule_pods(pods)
+    simd.schedule_pods([synth_pod(10_000)])
+    names = [[p["metadata"]["name"] for p in pods]
+             for pods in sim.pods_on_node]
+    assert names == [[p["metadata"]["name"] for p in pods]
+                     for pods in simd.pods_on_node]
+    assert late["metadata"]["name"] in sum(names, [])
+
+
 def test_materialized_before_commit_is_patched():
     ns, ps = synth_cluster_store(16, 50)
     early = ps[3]  # materialized BEFORE scheduling

@@ -103,9 +103,18 @@ def check_compiled(compiled):
     return text
 
 
+def check_wave_sorts(kernel: str, text: str) -> None:
+    """The wave's serial pick order rests on top_k putting equal keys lowest
+    index first: on the chip every sort of schedule_wave must be stable."""
+    if kernel == "schedule_wave":
+        sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+        assert sorts and all("is_stable=true" in ln for ln in sorts), sorts
+
+
 @pytest.mark.parametrize("kernel,shape", ONE_CHIP)
 def test_compiles_for_one_v5e_chip(topo, no_cache, kernel, shape):
-    check_compiled(compile_for(topo.devices[:1], kernel, shape))
+    text = check_compiled(compile_for(topo.devices[:1], kernel, shape))
+    check_wave_sorts(kernel, text)
 
 
 @pytest.mark.parametrize("kernel,shape", FOUR_CHIPS)
@@ -113,6 +122,8 @@ def test_compiles_sharded_over_four_v5e_chips(topo, no_cache, kernel, shape):
     text = check_compiled(compile_for(list(topo.devices), kernel, shape))
     # the node axis really is split: the program exchanges across chips
     assert hlo.collective_census(text), "no collective in a 4-chip program"
+    # the sharded wave ranks inside shard_map, after the gather
+    check_wave_sorts(kernel, text)
 
 
 def test_described_chip_is_a_v5e(topo):

@@ -216,6 +216,87 @@ def test_wave_two_flat_columns_tie():
     assert wc == sc and wf == sf
 
 
+def _hog(i, mem_gi):
+    # a pre-bound pod holding memory but almost no cpu: the node's
+    # BalancedAllocation column rises as cpu-heavy copies land on it
+    return make_pod(f"hog-{i}", cpu="100m", memory=f"{mem_gi}Gi",
+                    labels={"app": "hog"}, node_name=f"r{i}")
+
+
+def _store_of(pods):
+    from open_simulator_tpu.simulator.store import PodStore
+
+    ps = PodStore()
+    for p in pods:
+        ps.add_block(p, 1)
+    return ps
+
+
+def _labelled_fold(n):
+    # three templates that differ only in a label no selector reads
+    return [make_pod(f"{app}-{i}", cpu="200m", memory="256Mi",
+                     labels={"app": app})
+            for app in ("a", "b", "c") for i in range(n)]
+
+
+ORDER_CASES = {
+    # a flat column past the table depth (the depth-truncation shape)
+    "flat_past_depth": (
+        [make_node("huge", cpu="2000", memory="4000Gi", pods="5000")]
+        + [make_node(f"small{i}", cpu="2", memory="2Gi") for i in range(4)],
+        [], lambda: replicas("tiny", 400, cpu="10m", memory="16Mi"), False),
+    # two equal flat columns: ties go to the lower node index
+    "two_flat_columns": (
+        [make_node(f"huge{i}", cpu="1000", memory="2000Gi", pods="4000")
+         for i in range(2)],
+        [], lambda: replicas("tiny", 500, cpu="10m", memory="16Mi"), False),
+    # rising BalancedAllocation columns on memory-heavy nodes
+    "rising_balanced": (
+        [make_node(f"r{i}", cpu="16", memory="32Gi") for i in range(6)],
+        [_hog(i, 4 * i + 4) for i in range(6)],
+        lambda: replicas("cpu", 60, cpu="1", memory="64Mi"), False),
+    # a capacity-1 wave (hostname self anti-affinity)
+    "cap1": (
+        [make_node(f"n{i}") for i in range(10)], [],
+        lambda: replicas("spread", 14, cpu="100m", memory="128Mi",
+                         affinity=anti_affinity("spread")), False),
+    # three templates folded into one class, dispatched as one wave
+    "fold_three_templates": (
+        [make_node(f"n{i}", cpu="4", memory="8Gi") for i in range(7)], [],
+        lambda: _labelled_fold(20), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_wave_order_matches_serial_per_pod(case):
+    """schedule_wave's `order` gives each pod the node the serial scan gives
+    that pod, not just the same per-node counts."""
+    nodes, seeded, make, store = ORDER_CASES[case]
+    got = {}
+    for waves in (True, False):
+        sim = Simulator(copy.deepcopy(nodes))
+        sim.use_waves = waves
+        if seeded:
+            sim.schedule_pods(copy.deepcopy(seeded))
+        pods = make()
+        batch = _store_of(pods) if store else pods
+        failed = sim.schedule_pods(batch)
+        if store:
+            rows = batch.node_rows()
+            got[waves] = [sim.na.names[int(r)] if r >= 0 else None
+                          for r in rows]
+        else:
+            got[waves] = [(p.get("spec") or {}).get("nodeName")
+                          for p in batch]
+            for up in failed:
+                got[waves][batch.index(up.pod)] = None
+        if waves:
+            kinds = [s[0] for s in sim._segments(sim._last_tables,
+                                                 len(pods))]
+            assert kinds == ["wave"], kinds
+    assert got[True] == got[False]
+
+
 def test_wave_segments_split():
     # direct check of the segmentation: eligible big run + tiny run + forced pod
     nodes = [make_node(f"n{i}") for i in range(4)]
