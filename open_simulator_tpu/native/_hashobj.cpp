@@ -48,6 +48,21 @@ struct H128 {
 
 int hash_obj(PyObject* o, H128& h);  // fwd
 
+// hash the dict `d` as hash_obj does, with `keys` (a list of d's keys in
+// ascending order) naming the entries to hash
+int hash_dict_entries(PyObject* d, PyObject* keys, H128& h) {
+    h.tag('D');
+    Py_ssize_t n = PyList_GET_SIZE(keys);
+    for (Py_ssize_t i = 0; i < n; i++) {
+        PyObject* k = PyList_GET_ITEM(keys, i);
+        PyObject* v = PyDict_GetItemWithError(d, k);
+        if (!v) return -1;
+        if (hash_obj(k, h) < 0 || (h.tag(':'), hash_obj(v, h)) < 0) return -1;
+        h.tag(';');
+    }
+    return 0;
+}
+
 int hash_scalar_number(PyObject* o, H128& h) {
     // Python tuple equality treats 1 == 1.0 == True; we key booleans separately
     // ONLY when they appear as dict values/list items where _freeze kept the bool
@@ -132,29 +147,11 @@ int hash_obj(PyObject* o, H128& h) {
         return 0;
     }
     if (PyDict_Check(o)) {
-        h.tag('D');
         PyObject* keys = PyDict_Keys(o);
         if (!keys) return -1;
-        if (PyList_Sort(keys) < 0) {
-            Py_DECREF(keys);
-            return -1;
-        }
-        Py_ssize_t n = PyList_GET_SIZE(keys);
-        for (Py_ssize_t i = 0; i < n; i++) {
-            PyObject* k = PyList_GET_ITEM(keys, i);
-            PyObject* v = PyDict_GetItemWithError(o, k);
-            if (!v) {
-                Py_DECREF(keys);
-                return -1;
-            }
-            if (hash_obj(k, h) < 0 || (h.tag(':'), hash_obj(v, h)) < 0) {
-                Py_DECREF(keys);
-                return -1;
-            }
-            h.tag(';');
-        }
+        int rc = PyList_Sort(keys) < 0 ? -1 : hash_dict_entries(o, keys, h);
         Py_DECREF(keys);
-        return 0;
+        return rc;
     }
     PyErr_Format(PyExc_TypeError, "canon_hash: unsupported type %s",
                  Py_TYPE(o)->tp_name);
@@ -250,61 +247,102 @@ Interned& interned() {
     return s;
 }
 
-PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
-    PyObject* pod;
-    PyObject* anno_keys;  // sequence of annotation-key strings
-    if (!PyArg_ParseTuple(args, "OO", &pod, &anno_keys)) return nullptr;
+// `or {}` semantics for a sub-dict: falsy (None/""/[]) → missing; a truthy
+// non-dict is a malformed pod the Python extraction would have errored on —
+// raise, so the caller's computed-tuple fallback surfaces the object loudly.
+// Returns 0 and sets *out (borrowed, or nullptr), or -1 with an error set.
+int sub_dict(PyObject* o, const char* what, PyObject** out) {
+    *out = nullptr;
+    if (!o) return PyErr_Occurred() ? -1 : 0;
+    if (PyDict_Check(o)) {
+        *out = o;
+        return 0;
+    }
+    int t = PyObject_IsTrue(o);
+    if (t < 0) return -1;
+    if (t) {
+        PyErr_Format(PyExc_TypeError, "pod_sig: %s is not a dict", what);
+        return -1;
+    }
+    return 0;
+}
+
+// What class_sigs changes about a pod before hashing it, as
+// simulator/encode.py class_template does: only the labels whose key is in
+// `label_keys` are kept (and hashed as a dict, even when the pod has none),
+// and the namespace is hashed as "default" unless `keep_ns`.
+struct ClassView {
+    PyObject* label_keys;  // any container; nullptr when it is empty
+    bool keep_ns;
+};
+
+// labels filtered to cv.label_keys, hashed as the dict class_template builds
+int hash_class_labels(PyObject* labels, const ClassView& cv, H128& h) {
+    if (sub_dict(labels, "labels", &labels) < 0) return -1;
+    PyObject* kept = PyList_New(0);
+    if (!kept) return -1;
+    if (labels && cv.label_keys) {
+        Py_ssize_t pos = 0;
+        PyObject *k, *v;
+        while (PyDict_Next(labels, &pos, &k, &v)) {
+            Py_INCREF(k);
+            int in = PySequence_Contains(cv.label_keys, k);
+            int rc = in < 0 ? -1 : in ? PyList_Append(kept, k) : 0;
+            Py_DECREF(k);
+            if (rc < 0) {
+                Py_DECREF(kept);
+                return -1;
+            }
+        }
+    }
+    int rc = PyList_Sort(kept) < 0 ? -1 : hash_dict_entries(labels, kept, h);
+    Py_DECREF(kept);
+    if (rc < 0) return -1;
+    h.tag(',');
+    return 0;
+}
+
+// The digest pod_sig gives `pod`, or with `cv` the one it gives the pod's
+// class template. `anno_keys` is a PySequence_Fast of annotation keys.
+int sig_core(PyObject* pod, PyObject* anno_keys, const ClassView* cv, H128& h) {
     Interned& I = interned();
-    if (!I.ok) return PyErr_NoMemory();
+    if (!I.ok) {
+        PyErr_NoMemory();
+        return -1;
+    }
     if (!PyDict_Check(pod)) {
         PyErr_SetString(PyExc_TypeError, "pod_sig: pod must be a dict");
-        return nullptr;
+        return -1;
     }
 
-    PyObject* md = dget(pod, I.metadata);
-    PyObject* spec = dget(pod, I.spec);
-    if (PyErr_Occurred()) return nullptr;
-    // `or {}` semantics: falsy (None/""/[]) → missing; a truthy non-dict is a
-    // malformed pod the Python extraction would have errored on — raise, so
-    // the caller's computed-tuple fallback surfaces the object loudly
-    if (md && !PyDict_Check(md)) {
-        int t = PyObject_IsTrue(md);
-        if (t < 0) return nullptr;
-        if (t) {
-            PyErr_SetString(PyExc_TypeError, "pod_sig: metadata is not a dict");
-            return nullptr;
-        }
-        md = nullptr;
-    }
-    if (spec && !PyDict_Check(spec)) {
-        int t = PyObject_IsTrue(spec);
-        if (t < 0) return nullptr;
-        if (t) {
-            PyErr_SetString(PyExc_TypeError, "pod_sig: spec is not a dict");
-            return nullptr;
-        }
-        spec = nullptr;
-    }
+    PyObject *md, *spec;
+    if (sub_dict(dget(pod, I.metadata), "metadata", &md) < 0 ||
+        sub_dict(dget(pod, I.spec), "spec", &spec) < 0)
+        return -1;
 
-    H128 h;
     h.tag('L');  // the outer tuple
 
     // 1. namespace_of: metadata.namespace if truthy, else "default"
-    PyObject* ns = dget(md, I.nmspace);
-    if (PyErr_Occurred()) return nullptr;
+    PyObject* ns = (cv && !cv->keep_ns) ? nullptr : dget(md, I.nmspace);
+    if (PyErr_Occurred()) return -1;
     int truthy = ns ? PyObject_IsTrue(ns) : 0;
-    if (truthy < 0) return nullptr;
+    if (truthy < 0) return -1;
     if (!truthy) {
         h.tag('S');
         h.feed("default", 7);
         h.tag(',');
     } else if (hash_elem(ns, h) < 0) {
-        return nullptr;
+        return -1;
     }
 
-    // 2-11. raw subtrees, in the exact tuple order
-    PyObject* fields[10] = {
-        dget(md, I.labels),
+    // 2. labels
+    PyObject* labels = dget(md, I.labels);
+    if (PyErr_Occurred()) return -1;
+    if ((cv ? hash_class_labels(labels, *cv, h) : hash_elem(labels, h)) < 0)
+        return -1;
+
+    // 3-11. raw subtrees, in the exact tuple order
+    PyObject* fields[9] = {
         dget(spec, I.nodeSelector),
         dget(spec, I.affinity),
         dget(spec, I.tolerations),
@@ -315,19 +353,19 @@ PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
         dget(spec, I.initContainers),
         dget(spec, I.overhead),
     };
-    if (PyErr_Occurred()) return nullptr;
+    if (PyErr_Occurred()) return -1;
     for (PyObject* f : fields) {
-        if (hash_elem(f, h) < 0) return nullptr;
+        if (hash_elem(f, h) < 0) return -1;
     }
 
     // 12. sorted unique owner-reference kinds (UTF-8 byte order == code-point
     // order, so std::string sorting matches Python's str sorting)
     PyObject* owners = dget(md, I.ownerReferences);
-    if (PyErr_Occurred()) return nullptr;
+    if (PyErr_Occurred()) return -1;
     h.tag('L');
     if (owners && owners != Py_None) {
         PyObject* seq = PySequence_Fast(owners, "ownerReferences");
-        if (!seq) return nullptr;
+        if (!seq) return -1;
         Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
         std::vector<std::string> kinds;
         kinds.reserve(static_cast<size_t>(n));
@@ -337,10 +375,10 @@ PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
                 Py_DECREF(seq);
                 PyErr_SetString(PyExc_TypeError,
                                 "pod_sig: ownerReferences item is not a dict");
-                return nullptr;
+                return -1;
             }
             PyObject* kind = dget(ref, I.kind);
-            if (PyErr_Occurred()) { Py_DECREF(seq); return nullptr; }
+            if (PyErr_Occurred()) { Py_DECREF(seq); return -1; }
             if (kind == nullptr || kind == Py_None) {
                 // r.get("kind", "") — missing defaults to ""; an explicit None
                 // would make Python's sorted() raise TypeError, so do the same
@@ -348,13 +386,13 @@ PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
                     Py_DECREF(seq);
                     PyErr_SetString(PyExc_TypeError,
                                     "pod_sig: ownerReference kind is None");
-                    return nullptr;
+                    return -1;
                 }
                 kinds.emplace_back();
             } else {
                 Py_ssize_t sn;
                 const char* sb = PyUnicode_AsUTF8AndSize(kind, &sn);
-                if (!sb) { Py_DECREF(seq); return nullptr; }
+                if (!sb) { Py_DECREF(seq); return -1; }
                 kinds.emplace_back(sb, static_cast<size_t>(sn));
             }
         }
@@ -370,30 +408,67 @@ PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
     h.tag(',');
 
     // 13. [annotations.get(k) for k in anno_keys]
-    PyObject* anns = dget(md, I.annotations);
-    if (PyErr_Occurred()) return nullptr;
-    if (anns && !PyDict_Check(anns)) {
-        int t = PyObject_IsTrue(anns);
-        if (t < 0) return nullptr;
-        if (t) {
-            PyErr_SetString(PyExc_TypeError, "pod_sig: annotations is not a dict");
-            return nullptr;
-        }
-        anns = nullptr;
-    }
-    PyObject* keys = PySequence_Fast(anno_keys, "anno_keys");
-    if (!keys) return nullptr;
-    Py_ssize_t nk = PySequence_Fast_GET_SIZE(keys);
+    PyObject* anns;
+    if (sub_dict(dget(md, I.annotations), "annotations", &anns) < 0) return -1;
+    Py_ssize_t nk = PySequence_Fast_GET_SIZE(anno_keys);
     h.tag('L');
     for (Py_ssize_t k = 0; k < nk; k++) {
-        PyObject* v = dget(anns, PySequence_Fast_GET_ITEM(keys, k));
-        if (PyErr_Occurred()) { Py_DECREF(keys); return nullptr; }
-        if (hash_elem(v, h) < 0) { Py_DECREF(keys); return nullptr; }
+        PyObject* v = dget(anns, PySequence_Fast_GET_ITEM(anno_keys, k));
+        if (PyErr_Occurred() || hash_elem(v, h) < 0) return -1;
+    }
+    h.tag(',');
+    return 0;
+}
+
+PyObject* pod_sig(PyObject* /*self*/, PyObject* args) {
+    PyObject* pod;
+    PyObject* anno_keys;  // sequence of annotation-key strings
+    if (!PyArg_ParseTuple(args, "OO", &pod, &anno_keys)) return nullptr;
+    PyObject* keys = PySequence_Fast(anno_keys, "anno_keys");
+    if (!keys) return nullptr;
+    H128 h;
+    int rc = sig_core(pod, keys, nullptr, h);
+    Py_DECREF(keys);
+    return rc < 0 ? nullptr : compose_digest(h);
+}
+
+// ---------------------------------------------------------------------------
+// class_sigs(templates, anno_keys, label_keys, keep_ns): for each template,
+// pod_sig(class_template(t, label_keys, keep_ns), anno_keys) — the key of its
+// scheduling class (simulator/engine.py Simulator._classes) — without building
+// the class template. A template pod_sig would refuse raises TypeError.
+PyObject* class_sigs(PyObject* /*self*/, PyObject* args) {
+    PyObject *templates, *anno_keys, *label_keys;
+    int keep_ns;
+    if (!PyArg_ParseTuple(args, "OOOp", &templates, &anno_keys, &label_keys,
+                          &keep_ns))
+        return nullptr;
+    Py_ssize_t n_keys = PyObject_Length(label_keys);
+    if (n_keys < 0) return nullptr;
+    const ClassView cv{n_keys ? label_keys : nullptr, keep_ns != 0};
+    PyObject* tmpls = PySequence_Fast(templates, "templates");
+    if (!tmpls) return nullptr;
+    PyObject* keys = PySequence_Fast(anno_keys, "anno_keys");
+    if (!keys) {
+        Py_DECREF(tmpls);
+        return nullptr;
+    }
+    Py_ssize_t n = PySequence_Fast_GET_SIZE(tmpls);
+    PyObject* out = PyList_New(n);
+    for (Py_ssize_t i = 0; out && i < n; i++) {
+        H128 h;
+        PyObject* d = nullptr;
+        if (sig_core(PySequence_Fast_GET_ITEM(tmpls, i), keys, &cv, h) == 0)
+            d = compose_digest(h);
+        if (!d) {
+            Py_CLEAR(out);
+            break;
+        }
+        PyList_SET_ITEM(out, i, d);
     }
     Py_DECREF(keys);
-    h.tag(',');
-
-    return compose_digest(h);
+    Py_DECREF(tmpls);
+    return out;
 }
 
 PyMethodDef methods[] = {
@@ -402,6 +477,9 @@ PyMethodDef methods[] = {
     {"pod_sig", pod_sig, METH_VARARGS,
      "pod_sig(pod, anno_keys): scheduling-signature digest of a pod dict — "
      "hash-identical to canon_hash over the extracted signature tuple."},
+    {"class_sigs", class_sigs, METH_VARARGS,
+     "class_sigs(templates, anno_keys, label_keys, keep_ns): per template, "
+     "pod_sig of its scheduling-class template, without building it."},
     {nullptr, nullptr, 0, nullptr},
 };
 

@@ -34,6 +34,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..core import constants as C
+from ..native import class_sigs_fn
 from ..ops.resources import (
     PODS_I,
     ResourceAxis,
@@ -645,6 +646,23 @@ def class_template(pod: dict, keys: frozenset, keep_ns: bool) -> dict:
     out = {k: v for k, v in pod.items() if k != SIG_MEMO_KEY}
     out["metadata"] = md
     return out
+
+
+def class_signatures(pods: List[dict], keys: frozenset,
+                     keep_ns: bool) -> Tuple[list, bool]:
+    """scheduling_signature(class_template(p, keys, keep_ns)) of every pod,
+    and whether the native pass gave them: one native call that builds no
+    class template (native/_hashobj.cpp class_sigs) where the extension is
+    built and takes every pod, else a class template and a signature per
+    pod."""
+    fn = class_sigs_fn()
+    if fn is not None:
+        try:
+            return fn(pods, _SIG_ANNO_KEYS, keys, keep_ns), True
+        except TypeError:
+            pass  # an exotic object in some pod → the loop below
+    return [scheduling_signature(class_template(p, keys, keep_ns))
+            for p in pods], False
 
 
 def strip_daemon_pin(pod: dict) -> Tuple[dict, Optional[str]]:

@@ -64,6 +64,7 @@ from .encode import (
     build_pod_axis_tables,
     avoid_controller,
     carried_specs_of_pod,
+    class_signatures,
     class_template,
     pad_batch_tables,
     pad_encoder_axes,
@@ -1089,9 +1090,11 @@ class Simulator:
             slot_of.append(s)
         if fold:
             with Span("encode.classes") as sp:
-                cls_of, cls_tmpl = self._classes([tmpls[i] for i in rep])
+                cls_of, cls_tmpl, keyed = self._classes(
+                    [tmpls[i] for i in rep])
                 sp.annotate("groups", len(rep))
                 sp.annotate("classes", len(cls_tmpl))
+                sp.annotate("keyed_native", keyed)
         else:
             cls_of, cls_tmpl = list(range(len(rep))), [None] * len(rep)
         enc = self.encoder
@@ -1133,37 +1136,42 @@ class Simulator:
             tmpl.pop(SIG_MEMO_KEY, None)
 
     def _classes(self, reps: List[dict]
-                 ) -> Tuple[List[int], List[Optional[tuple]]]:
+                 ) -> Tuple[List[int], List[Optional[tuple]], int]:
         """Partition signature groups (`reps`: each group's template) into
-        scheduling classes: (the class of each group, and per class its
-        (template, signature), or None where it holds one group). Two
-        groups share a class where their templates are equal once
-        class_template drops what no selector in play reads — then every
-        filter, score and counter sees one pod. The class template is
-        never interned under a member's signature, so a later call whose
+        scheduling classes: (the class of each group, per class its
+        (template, signature) or None where it holds one group, and how
+        many groups the native pass keyed). Two groups share a class where
+        their templates are equal once class_template drops what no
+        selector in play reads — then every filter, score and counter sees
+        one pod. A class's template is its first member's class template,
+        built only for a class of more than one group, and is never
+        interned under a member's signature, so a later call whose
         selectors read more splits the class again. Without waves, or with
         out-of-tree plugins, each signature group is its own class."""
         if (len(reps) < 2 or not self.use_waves
                 or self.encoder.extra_plugins):
-            return list(range(len(reps))), [None] * len(reps)
+            return list(range(len(reps))), [None] * len(reps), 0
         keys, keep_ns = self._selector_reads(reps)
+        sigs, native = class_signatures(reps, keys, keep_ns)
         # NodePreferAvoidPods reads an RC/RS controller's uid, which no
         # signature holds: where a node names one, such templates stay alone
         avoid = self.encoder.prefer_avoid_any()
         by_class: Dict[object, int] = {}
         cls_of: List[int] = []
-        cls: List[list] = []  # [class template, its signature, members]
-        for s, tmpl in enumerate(reps):
-            ct = class_template(tmpl, keys, keep_ns)
-            sig = scheduling_signature(ct)
-            key = (sig, s) if avoid and avoid_controller(tmpl) else sig
+        first: List[int] = []  # each class's first member
+        size: List[int] = []
+        for s, sig in enumerate(sigs):
+            key = (sig, s) if avoid and avoid_controller(reps[s]) else sig
             c = by_class.get(key)
             if c is None:
-                c = by_class[key] = len(cls)
-                cls.append([ct, sig, 0])
-            cls[c][2] += 1
+                c = by_class[key] = len(first)
+                first.append(s)
+                size.append(0)
+            size[c] += 1
             cls_of.append(c)
-        return cls_of, [(ct, sig) if n > 1 else None for ct, sig, n in cls]
+        cls_tmpl = [(class_template(reps[s], keys, keep_ns), sigs[s])
+                    if n > 1 else None for s, n in zip(first, size)]
+        return cls_of, cls_tmpl, len(reps) if native else 0
 
     def _selector_reads(self, templates: List[dict]) -> Tuple[frozenset, bool]:
         """(every label key a selector in play reads, whether any selector is

@@ -18,6 +18,7 @@ import sys
 import numpy as np
 import pytest
 
+from open_simulator_tpu import native
 from open_simulator_tpu.core.types import AppResource, ResourceTypes
 from open_simulator_tpu.models import workloads
 from open_simulator_tpu.simulator.engine import Simulator
@@ -76,6 +77,12 @@ def _store(units) -> PodStore:
         ps.add_block(u.template, u.count, name_fmt=u.name + "-{0}",
                      name_start=0)
     return ps
+
+
+def _keyed(groups: int) -> int:
+    """encode.classes' `keyed_native` for a call that folds `groups`
+    signature groups: all of them where the extension is built."""
+    return groups if native.class_sigs_fn() is not None else 0
 
 
 def _spans(roots, name: str) -> list:
@@ -166,9 +173,11 @@ def test_cl2_classes_match_serial_and_reference(case):
     n_units = len(c.units)
     if spec.get("service"):
         # SelectorSpread reads `name`, so no two Deployments share a class
-        assert payloads[0] == {"groups": n_units, "classes": n_units}
+        assert payloads[0] == {"groups": n_units, "classes": n_units,
+                               "keyed_native": _keyed(n_units)}
     else:
-        assert payloads[0] == {"groups": n_units, "classes": 1}
+        assert payloads[0] == {"groups": n_units, "classes": 1,
+                               "keyed_native": _keyed(n_units)}
     if spec.get("later"):
         # the anti-affinity batch never lands beside the selected Deployment
         offs = np.cumsum([0] + [u.count for u in c.units])
@@ -208,38 +217,110 @@ def test_class_is_not_stored_under_member_signatures():
     assert {pg.sig for pg in sim.placed.values()} == set(b.sigs)
 
 
-def test_prefer_avoid_controller_stays_out_of_the_class():
-    """NodePreferAvoidPods reads a pod's ReplicaSet uid, which no signature
-    holds: where a node names one, Deployments that differ only in labels
-    and owner still land pod by pod where the serial scan puts them."""
+def _avoid_nodes() -> list:
+    """Six nodes, the first three of which ask to avoid ReplicaSet rs-b."""
     import json
 
-    from fixtures import make_node, make_pod
+    from fixtures import make_node
 
     avoid = json.dumps({"preferAvoidPods": [{"podSignature": {
         "podController": {"kind": "ReplicaSet", "uid": "rs-b",
                           "controller": True}}}]})
-    nodes = [make_node(f"n{i}", cpu="4", memory="8Gi",
-                       annotations={
-                           "scheduler.alpha.kubernetes.io/preferAvoidPods":
-                               avoid} if i < 3 else None)
-             for i in range(6)]
+    return [make_node(f"n{i}", cpu="4", memory="8Gi",
+                      annotations={
+                          "scheduler.alpha.kubernetes.io/preferAvoidPods":
+                              avoid} if i < 3 else None)
+            for i in range(6)]
 
-    def tmpl(app):
-        p = make_pod(f"{app}-0", cpu="100m", memory="128Mi",
-                     labels={"name": app})
-        p["metadata"]["ownerReferences"] = [{
-            "kind": "ReplicaSet", "name": f"{app}-rs", "uid": f"rs-{app}",
-            "controller": True}]
-        return p
 
+def _owned_template(app: str) -> dict:
+    """A pod of app `app` whose controller is ReplicaSet rs-<app>."""
+    from fixtures import make_pod
+
+    p = make_pod(f"{app}-0", cpu="100m", memory="128Mi",
+                 labels={"name": app})
+    p["metadata"]["ownerReferences"] = [{
+        "kind": "ReplicaSet", "name": f"{app}-rs", "uid": f"rs-{app}",
+        "controller": True}]
+    return p
+
+
+def _partition_case(case: str):
+    """(a builder of the case's nodes, its units, the classes expected)."""
+    if case == "avoid_owner":
+        units = [cluster.Unit(app, _owned_template(app), 10)
+                 for app in ("a", "b", "c")]
+        return _avoid_nodes, units, 3
+    c = cluster.generate(cl2_small(), 20_262_026, 0)
+
+    def nodes():
+        return cluster.program_inputs(c)[0]
+
+    if case == "cl2":
+        return nodes, c.units, 1
+    # a selector reads `tier`, which a third of the Deployments set to
+    # front and a third to back, and scopes to a namespace: three classes
+    # in each of the two namespaces, and the selector's own
+    units = [cluster.Unit(u.name, copy.deepcopy(u.template), u.count)
+             for u in c.units]
+    for i, u in enumerate(units):
+        if i % 3 < 2:
+            u.template["metadata"]["labels"]["tier"] = ("front", "back")[i % 3]
+    late = _anti_unit(c, "unused", 5)
+    late.template["spec"]["affinity"]["podAntiAffinity"][
+        "requiredDuringSchedulingIgnoredDuringExecution"][0][
+        "labelSelector"] = {"matchLabels": {"tier": "front"}}
+    return nodes, units + [late], 7
+
+
+@pytest.mark.parametrize("case", ["cl2", "selector_splits", "avoid_owner"])
+def test_partition_is_the_same_with_and_without_the_native_pass(
+        case, monkeypatch):
+    """Simulator._classes keys every group in one native call where the
+    extension is built, and class_template + scheduling_signature per
+    group where it is not: both give each group the same class, and each
+    class the same template and signature to intern under. The
+    encode.classes payload counts the groups the native pass keyed."""
+    if native.class_sigs_fn() is None:
+        pytest.skip("native extension unavailable (no compiler?)")
+    nodes, units, n_classes = _partition_case(case)
+    reps = [u.template for u in units]
+    got, nodes_of, payloads = {}, {}, {}
+    for on in (True, False):
+        if not on:
+            monkeypatch.setattr(native, "_class_sigs", None)
+        got[on] = Simulator(nodes())._classes(reps)
+        sim = Simulator(nodes())
+        ps = _store(units)
+        trace.start_collection()
+        try:
+            sim.schedule_pods(ps)
+        finally:
+            roots = trace.stop_collection()
+        nodes_of[on] = np.asarray(ps.node_rows()).tolist()
+        payloads[on] = _spans(roots, "encode.classes")
+    cls_of, cls_tmpl, keyed = got[True]
+    assert (cls_of, cls_tmpl) == got[False][:2]
+    assert len(cls_tmpl) == n_classes
+    assert (keyed, got[False][2]) == (len(reps), 0)
+    assert nodes_of[True] == nodes_of[False]
+    for on in (True, False):
+        assert payloads[on] == [{"groups": len(reps), "classes": n_classes,
+                                 "keyed_native": len(reps) if on else 0}]
+
+
+def test_prefer_avoid_controller_stays_out_of_the_class():
+    """NodePreferAvoidPods reads a pod's ReplicaSet uid, which no signature
+    holds: where a node names one, Deployments that differ only in labels
+    and owner still land pod by pod where the serial scan puts them."""
     got = {}
     for waves in (True, False):
-        sim = Simulator(copy.deepcopy(nodes))
+        sim = Simulator(_avoid_nodes())
         sim.use_waves = waves
         ps = PodStore()
         for app in ("a", "b", "c"):
-            ps.add_block(tmpl(app), 10, name_fmt=app + "-{0}", name_start=0)
+            ps.add_block(_owned_template(app), 10, name_fmt=app + "-{0}",
+                         name_start=0)
         sim.schedule_pods(ps)
         got[waves] = np.array(ps.node_rows()).tolist()
     assert got[True] == got[False]
@@ -313,7 +394,8 @@ def test_cl2_dict_entries_fold_and_match_serial_and_reference(entry):
     assert np.array_equal(got, want), (
         f"{int((got != want).sum())} of {len(want)} pods differ from the "
         f"serial scan")
-    assert payloads == [{"groups": len(c.units), "classes": 1}]
+    assert payloads == [{"groups": len(c.units), "classes": 1,
+                         "keyed_native": _keyed(len(c.units))}]
     assert kinds == ["dispatch.wave"]
     ref = np.concatenate(reference.Reference(c).schedule_all())
     assert np.array_equal(got, ref), (

@@ -1,5 +1,7 @@
 """Native canon_hash extension + signature memoization."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -175,3 +177,97 @@ def test_memo_stripped_from_results():
             assert SIG_MEMO_KEY not in p
     for up in res.unscheduled_pods:
         assert SIG_MEMO_KEY not in up.pod
+
+
+# ------------------------------------------------------------------ class_sigs ------
+
+
+def _class_pod(md, spec=None):
+    pod = {"metadata": md,
+           "spec": spec if spec is not None else {
+               "containers": [{"image": "nginx", "resources": {
+                   "requests": {"cpu": "10m", "memory": "10M"}}}]}}
+    return pod
+
+
+CLASS_CASES = {
+    "labels_kept": ([_class_pod({"namespace": "a", "labels": {"x": "1"}})],
+                    {"x"}, True),
+    "labels_dropped": ([_class_pod({"namespace": "a", "labels": {"x": "1"}}),
+                        _class_pod({"namespace": "a", "labels": {"x": "2"}})],
+                       set(), True),
+    "labels_partly_kept": ([_class_pod({"labels": {"x": "1", "y": "2",
+                                                   "z": "3"}})],
+                           {"x", "z", "absent"}, False),
+    "labels_absent": ([_class_pod({"namespace": "a"}),
+                       _class_pod({"labels": None}),
+                       _class_pod({"labels": {}})], {"x"}, True),
+    "namespace_kept": ([_class_pod({"namespace": "a"})], set(), True),
+    "namespace_dropped": ([_class_pod({"namespace": "a"})], set(), False),
+    "namespace_empty": ([_class_pod({"namespace": ""})], set(), True),
+    "namespace_missing": ([_class_pod({"labels": {"x": "1"}})], {"x"}, True),
+    "metadata_none": ([_class_pod(None), {"metadata": None, "spec": None}],
+                      {"x"}, True),
+    "owners_and_annotations": ([_class_pod({
+        "namespace": "a", "labels": {"x": "1", "y": "2"},
+        "ownerReferences": [{"kind": "ReplicaSet", "controller": True},
+                            {"kind": "Job"}],
+        "annotations": {"simon/gpu-mem": "4Gi", "simon/local-storage": "x",
+                        "other": "1"}})], {"y"}, False),
+    "non_dict_metadata": ([_class_pod("not-a-dict")], set(), True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLASS_CASES))
+def test_class_sigs_match_pod_sig_of_class_template(pod_sig, case):
+    """class_sigs keys each template as pod_sig keys its class template,
+    without building it, and refuses what pod_sig refuses."""
+    from open_simulator_tpu.native import class_sigs_fn
+    from open_simulator_tpu.simulator.encode import class_template
+
+    class_sigs = class_sigs_fn()
+    pods, keys, keep_ns = CLASS_CASES[case]
+    keys = frozenset(keys)
+    if case == "non_dict_metadata":
+        with pytest.raises(TypeError):
+            pod_sig(pods[0], ANNO_KEYS)
+        with pytest.raises(TypeError):
+            class_sigs(pods, ANNO_KEYS, keys, keep_ns)
+        return
+    want = [pod_sig(class_template(p, keys, keep_ns), ANNO_KEYS) for p in pods]
+    assert class_sigs(pods, ANNO_KEYS, keys, keep_ns) == want
+    # what the class drops is all it drops: keeping every label and the
+    # namespace keys the template as pod_sig does, wherever it has labels
+    every = frozenset(k for p in pods
+                      for k in ((p.get("metadata") or {}).get("labels") or ()))
+    for p, sig in zip(pods, class_sigs(pods, ANNO_KEYS, every, True)):
+        if (p.get("metadata") or {}).get("labels"):
+            assert sig == pod_sig(p, ANNO_KEYS)
+
+
+# ------------------------------------------------------------------ the build -------
+
+
+@pytest.mark.parametrize("rc", [0, 1])
+def test_build_replaces_the_binary_whole(tmp_path, monkeypatch, rc):
+    """The compiler writes a temporary file that replaces the binary in one
+    rename: a failed build leaves neither the binary nor a partial file,
+    and a good one leaves the binary alone."""
+    from open_simulator_tpu import native
+
+    cxx = tmp_path / "cxx"
+    # a compiler that writes part of its output, then exits with `rc`
+    cxx.write_text('#!/bin/sh\nwhile [ "$1" != "-o" ]; do shift; done\n'
+                   f'printf partial > "$2"\nexit {rc}\n')
+    cxx.chmod(0o755)
+    out = tmp_path / "out"
+    out.mkdir()
+    so = out / "_hashobj.so"
+    monkeypatch.setenv("CXX", str(cxx))
+    monkeypatch.setattr(native, "_SO", str(so))
+    assert native._build() is (rc == 0)
+    if rc:
+        assert sorted(os.listdir(out)) == []
+    else:
+        assert sorted(os.listdir(out)) == [so.name]
+        assert so.read_text() == "partial"
