@@ -3,7 +3,7 @@
 Headline (stdout, ONE JSON line): the north-star shape — 100,000 pods onto
 10,000 nodes, end-to-end through the engine (host encode + wave/serial device
 scheduling + commit bookkeeping), warm compile cache. Baseline for
-`vs_baseline` is BASELINE.json's target: 100k pods in <2s ⇒ 50,000 pods/s.
+`vs_baseline` is BASELINE.md's target: 100k pods in <2s ⇒ 50,000 pods/s.
 
 The remaining configs print as JSON lines on stderr and are also written to
 BENCH_DETAIL.json:

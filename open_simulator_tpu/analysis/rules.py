@@ -1208,9 +1208,9 @@ def rule_suppression_reason(ctx: ModuleContext) -> List[Finding]:
 # store exists to remove (the 1M-pod row spent ~60% of wall in exactly two
 # such loops before the rewrite). Applicability is structural — the module
 # imports `.store` / `..simulator.store` — so adopting the store opts a
-# module into the fence, and fallback loops that must remain (dict batches,
-# armed preemption, gpu/storage ledgers) carry reasoned waivers naming the
-# columnar path that replaces them.
+# module into the fence, and loops that must remain (the per-pod commit for
+# the flight recorder, armed preemption and the gpu/storage ledgers) carry
+# reasoned waivers naming the columnar path that covers the hot case.
 _POD_BATCH_NAMES = {"pods", "to_schedule", "batch", "request_pods"}
 
 

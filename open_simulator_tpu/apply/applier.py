@@ -31,6 +31,7 @@ from ..models.fakenode import new_fake_nodes
 from ..resilience import guard
 from ..resilience.policy import Deadline, check_deadline
 from ..simulator.core import simulate
+from ..simulator.store import PodStore
 from ..utils.objutil import annotations_of, labels_of, name_of, namespace_of, pod_resource_requests
 from ..utils.quantity import format_quantity, parse_milli, parse_quantity
 from ..utils.storage import NodeStorage
@@ -82,13 +83,15 @@ class CapacityPlanner:
     `try_build` returns None otherwise and the Applier keeps the original
     loop."""
 
-    def __init__(self, base_nodes: List[dict], new_node: dict, pods: List[dict],
+    def __init__(self, base_nodes: List[dict], new_node: dict, pods,
                  cluster_objects: Optional[ResourceTypes] = None,
                  app_objects: Optional[List[ResourceTypes]] = None,
                  sched_config=None) -> None:
         self.base_nodes = base_nodes
         self.new_node = new_node
-        self.pods = pods
+        # converted once: every probe of the search passes this one store,
+        # so no probe signs a pod again
+        self.pods = PodStore.of(pods)
         self.cluster_objects = cluster_objects
         self.app_objects = app_objects or []
         self.sched_config = sched_config
@@ -125,13 +128,9 @@ class CapacityPlanner:
             for patch in patch_funcs or []:
                 patch(app_pods)
             pods.extend(app_pods)
-        seen_unbound = False
-        for p in pods:
-            if (p.get("spec") or {}).get("nodeName"):
-                if seen_unbound:
-                    return None  # bound-after-unbound: probe order-inequivalent
-            else:
-                seen_unbound = True
+        pods = PodStore.of(pods)
+        if any(bound and len(run) for run, bound in pods.split_bound()):
+            return None  # bound-after-unbound: probe order-inequivalent
         return cls(cluster.nodes, new_node, pods,
                    cluster_objects=cluster, app_objects=[a.resource for a in apps],
                    sched_config=sched_config)

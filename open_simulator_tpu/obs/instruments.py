@@ -54,8 +54,8 @@ ENCODE_BYTES = counter(
 STREAM_CHUNKS = counter(
     "simon_stream_chunks_total",
     "Scheduling runs dispatched as streaming chunks "
-    "(OPEN_SIMULATOR_STREAM_PODS): host encode of chunk k+1 overlaps the "
-    "device dispatch of chunk k.")
+    "(OPEN_SIMULATOR_STREAM_PODS): runs longer than the chunk schedule "
+    "chunk by chunk, bounding the host working set.")
 BATCH_PODS = histogram(
     "simon_batch_pods",
     "Pods per contiguous unbound scheduling run handed to the device.",

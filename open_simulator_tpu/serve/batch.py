@@ -132,11 +132,11 @@ class WhatIfService:
         if len(pods) > MAX_BATCHED_PODS or guard.default_quarantined():
             return self._fresh(pods, drains, tm)
         session = self.image.session(pods, drains)
-        gate = self.image.eligible(session.batch, pods)
+        gate = self.image.eligible(session.batch, session.pods)
         if gate is not None:
             if tm is not None:
                 tm["gate"] = gate
-            return self._fresh(pods, drains, tm)
+            return self._fresh(session.pods, drains, tm)
         item = _Pending(session, tm)
         t_enq = time.monotonic()
         with self._cv:
@@ -159,7 +159,7 @@ class WhatIfService:
         obs.SERVE_REQUESTS.labels(path=item.response["path"]).inc()
         return item.response
 
-    def _fresh(self, pods: List[dict], drains: Sequence[str],
+    def _fresh(self, pods, drains: Sequence[str],
                tm: Optional[dict] = None) -> dict:
         obs.SERVE_REQUESTS.labels(path="fresh").inc()
         if tm is None:

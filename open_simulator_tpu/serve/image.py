@@ -67,6 +67,7 @@ from ..simulator.encode import (
     pad_batch_tables,
     pad_encoder_axes,
 )
+from ..simulator.store import EncodedRows, PodStore
 
 _jnp = None
 
@@ -100,15 +101,11 @@ class WhatIfSession:
     def __init__(self, image: "ResidentImage", pods,
                  drains: Sequence[str]) -> None:
         self.image = image
-        # a columnar PodStore rides whole (its encode is one gather per
-        # template); dict batches are snapshotted as before
-        from ..simulator.store import is_pod_store
-
-        self.pods = pods if is_pod_store(pods) else list(pods)
+        self.pods = PodStore.of(pods)  # re-encodes read the store
         self.drains = tuple(drains)
         self.generation = image.generation
         self.seq = image.seq
-        self.batch = image.encode_request(pods)
+        self.batch = image.encode_request(self.pods)
 
     def ensure_current(self) -> None:
         """Re-encode after a generation move (group ids are only meaningful
@@ -236,11 +233,7 @@ class ResidentImage:
         None = initial build (uncounted)."""
         faults.maybe_fail("to_device")
         sim = self._sim
-        bt_raw = BatchTables(
-            **build_pod_axis_tables(sim.encoder, [], pad_to=8),
-            **build_node_axis_tables(sim.encoder, sim.placed,
-                                     sim.match_cache))
-        btp = pad_batch_tables(pad_encoder_axes(bt_raw),
+        btp = pad_batch_tables(pad_encoder_axes(self._unpadded_bt()),
                                bucket_capped(sim.na.N, 1024))
         self._bt = btp
         self._n_pad = btp.alloc.shape[0]
@@ -300,9 +293,11 @@ class ResidentImage:
         obs.SERVE_SEED_REFRESHES.inc()
 
     def _unpadded_bt(self) -> BatchTables:
+        """The cluster's tables with no pod rows (requests ride lanes)."""
         sim = self._sim
+        no_pods = EncodedRows(np.zeros(0, np.int32), np.zeros(0, np.int32))
         return BatchTables(
-            **build_pod_axis_tables(sim.encoder, [], pad_to=8),
+            **build_pod_axis_tables(sim.encoder, no_pods, pad_to=8),
             **build_node_axis_tables(sim.encoder, sim.placed,
                                      sim.match_cache))
 
@@ -551,11 +546,11 @@ class ResidentImage:
 
     # ----------------------------------------------------------- requests -----
 
-    def encode_request(self, pods: List[dict]) -> List[Tuple[int, int]]:
-        """Pod-axis encode of one request against the shared encoder:
-        (group_id, forced_node) per pod. Warm path (every signature already
-        interned) is a dict hit per pod; a fresh group triggers ensure_staged
-        at the next dispatch."""
+    def encode_request(self, pods) -> EncodedRows:
+        """Pod-axis encode of one request (a PodStore or pod dicts) against
+        the shared encoder: (group_id, forced_node) per pod. Warm path (every
+        signature already interned) is a dict hit per template; a fresh group
+        triggers ensure_staged at the next dispatch."""
         with self._lock:
             return self._sim.encode_batch_ids(pods)
 
@@ -563,23 +558,16 @@ class ResidentImage:
                 drains: Sequence[str] = ()) -> WhatIfSession:
         return WhatIfSession(self, pods, drains)
 
-    def eligible(self, batch: List[Tuple[int, int]],
-                 pods: List[dict]) -> Optional[str]:
+    def eligible(self, batch: EncodedRows, pods) -> Optional[str]:
         """None when the request can ride the resident micro-batched path;
         otherwise the gate name routing it to the fresh-simulation path.
         Census-dependent inputs (topology spread eligible-domain sets, live
         SelectorSpread) are computed over the node CENSUS at encode time, so
         a masked-inactive node is not equivalent to an absent one for them;
-        gpu/storage groups carry host-mirrored state the image declines."""
-        from ..simulator.store import is_pod_store
-
-        if is_pod_store(pods):
-            if pods.bound_mask() is not None:
-                return "pre-bound pod"
-        else:
-            for pod in pods:  # simonlint: ignore[per-pod-host-loop] -- dict-request gate scan; PodStore requests take the bound_mask branch above
-                if (pod.get("spec") or {}).get("nodeName"):
-                    return "pre-bound pod"
+        gpu/storage groups carry host-mirrored state the image declines.
+        `pods`: the request as a PodStore (a session's `pods`) or dicts."""
+        if PodStore.of(pods).bound_mask() is not None:
+            return "pre-bound pod"
         with self._lock:
             enc = self._sim.encoder
             for gi, _ in batch:  # simonlint: ignore[per-pod-host-loop] -- small request batches; the rows are already encoded ids
@@ -1081,15 +1069,16 @@ class ResidentImage:
         sim.register_cluster_objects(rt)
         return sim, bound, epoch
 
-    def fresh_probe(self, pods: List[dict],
-                    drains: Sequence[str] = ()) -> dict:
+    def fresh_probe(self, pods, drains: Sequence[str] = ()) -> dict:
         """The from-scratch oracle AND the fresh-path route: build a fresh
         Simulator over the current cluster state (minus request drains and
         those nodes' pods), replay the bound pods, probe the request. This
         is byte-for-byte what the resident path must reproduce — the parity
-        suite compares the two on every seeded trace."""
+        suite compares the two on every seeded trace. `pods`: the request
+        as a PodStore (a session's `pods`, whose signatures its copies
+        carry) or dicts."""
         sim, bound, epoch = self.fresh_simulator(drains)
-        request = [copy.deepcopy(p) for p in pods]
+        request = PodStore.of(pods).signed_copies()
         scheduled, total = sim.probe_pods(bound + request)
         return {
             "scheduled": scheduled - len(bound),

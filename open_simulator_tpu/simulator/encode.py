@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -52,6 +52,9 @@ from ..utils.objutil import (
     pod_resource_requests,
     toleration_tolerates_taint,
 )
+
+if TYPE_CHECKING:
+    from .store import EncodedRows
 
 # ----------------------------------------------------------------- node arrays --------
 
@@ -528,7 +531,7 @@ def _freeze(o):
     return o
 
 
-SIG_MEMO_KEY = "__sig_memo__"  # stamped by workload expansion; popped by the engine
+SIG_MEMO_KEY = "__sig_memo__"  # stamped by workload expansion; consumed by PodStore.of
 
 _native_hash = "unresolved"
 
@@ -1393,7 +1396,7 @@ def _pad_slots(rows: List[List], width: int, fill, dtype) -> np.ndarray:
 
 def build_pod_axis_tables(
     enc: Encoder,
-    batch: List[Tuple[int, int]],          # (group_id, forced_node) per pod, in order
+    batch: EncodedRows,                    # (group_id, forced_node) per pod, in order
     pad_to: Optional[int] = None,
 ) -> Dict[str, np.ndarray]:
     """The node-axis-INDEPENDENT half of BatchTables: per-group statics
@@ -1467,19 +1470,9 @@ def build_pod_axis_tables(
     pod_group = np.zeros(P_pad, np.int32)
     forced_node = np.full(P_pad, -1, np.int32)
     valid = np.zeros(P_pad, bool)
-    from .store import EncodedRows
-
-    if isinstance(batch, EncodedRows):
-        # columnar fast path (simulator/store.py): the store's encode is
-        # already two arrays — three vectorized copies, no per-pod loop
-        pod_group[:P] = batch.pod_group
-        forced_node[:P] = batch.forced_node
-        valid[:P] = True
-    else:
-        for i, (gi, fn) in enumerate(batch):  # simonlint: ignore[per-pod-host-loop] -- legacy list-of-tuples form; EncodedRows takes the vectorized branch
-            pod_group[i] = gi
-            forced_node[i] = fn
-            valid[i] = True
+    pod_group[:P] = batch.pod_group
+    forced_node[:P] = batch.forced_node
+    valid[:P] = True
 
     return dict(
         grp_requests=(
@@ -1726,7 +1719,7 @@ def build_node_axis_tables(
 
 def build_batch_tables(
     enc: Encoder,
-    batch: List[Tuple[int, int]],          # (group_id, forced_node) per pod, in order
+    batch: EncodedRows,                    # (group_id, forced_node) per pod, in order
     placed: Dict[object, PlacedGroup],
     match_cache: Dict[Tuple[int, str], bool],
     pad_to: Optional[int] = None,

@@ -28,7 +28,8 @@ import copy
 import dataclasses
 import functools
 import json
-from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import (Callable, Dict, Iterator, List, NamedTuple, Optional,
+                    Tuple)
 
 import numpy as np
 
@@ -73,7 +74,7 @@ from .encode import (
     strip_daemon_pin,
     template_selectors,
 )
-from .store import EncodedRows, NodeStore, PodStore, PodsOnNode, is_pod_store
+from .store import EncodedRows, NodeStore, PodStore, PodsOnNode
 
 _jnp = None  # lazy jax import so host-only paths (ingestion, reports) stay jax-free
 
@@ -300,16 +301,16 @@ class Simulator:
                     _os.environ.get("OPEN_SIMULATOR_SPREAD_WAVE_MIN_DOMAINS", "0"))
             except ValueError:  # pure-performance knob: fall back, don't crash
                 self._spread_wave_min_domains = 0
-            # Streaming segment encode (_schedule_run_streaming): runs longer
-            # than this many pods schedule as double-buffered chunks. 0 disables
-            # (monolithic runs); the default keeps every existing bench shape
-            # (<=100k-pod runs) on the single-dispatch path.
-            self._stream_explicit = "OPEN_SIMULATOR_STREAM_PODS" in _os.environ
+            # Streaming (_schedule_run_streaming): runs longer than this many
+            # pods schedule as chunks, which bounds the [P] working set at
+            # extreme sizes. 0 disables (monolithic runs). A run's encode is
+            # O(templates), so chunking only re-pays the node-axis table build
+            # per chunk: the default streams only the multi-million-pod rows.
             try:
                 self._stream_chunk = max(0, int(_os.environ.get(
-                    "OPEN_SIMULATOR_STREAM_PODS", "131072")))
+                    "OPEN_SIMULATOR_STREAM_PODS", "2097152")))
             except ValueError:  # pure-performance knob: fall back, don't crash
-                self._stream_chunk = 131072
+                self._stream_chunk = 2_097_152
             # simonxray (obs/xray.py): per-attempt staging for the flight
             # recorder. None unless recording is active — the off path costs one
             # None-check per schedule/probe call and nothing else (no extra
@@ -318,7 +319,10 @@ class Simulator:
 
     # ------------------------------------------------------------- state ----------
 
-    def _commit_pod(self, pod: dict, node_i: int, scheduled: bool = True) -> None:
+    def _commit_pod(self, pod: dict, node_i: int, scheduled: bool = True,
+                    sig=None) -> None:
+        """Commit one pod onto host state. `sig`: its store row's signature;
+        a pod handed over directly (serve's ingest) is signed here."""
         faults.maybe_fail("commit")
         self._commit_events += 1
         spec = pod.get("spec")
@@ -344,11 +348,9 @@ class Simulator:
         pod["status"] = {"phase": "Running"}
         # Snapshot the signature BEFORE reserve() writes gpu-index/assume-time
         # annotations, so identical pods keep one signature (match-cache key).
-        # Inline the memo hit (stamped by encode_batch/workload expansion) —
-        # this runs once per placed pod.
-        sig = pod.get(SIG_MEMO_KEY)
+        memo = pod.pop(SIG_MEMO_KEY, None)  # keep result objects clean
         if sig is None:
-            sig = scheduling_signature(pod)
+            sig = memo if memo is not None else scheduling_signature(pod)
         self._sig_of[id(pod)] = (sig, node_i, len(self._commits_prio))
         try:
             self._commits_prio.append(int((pod.get("spec") or {}).get("priority") or 0))
@@ -380,7 +382,6 @@ class Simulator:
             )
         nc = pg.node_counts
         nc[node_i] = nc.get(node_i, 0) + 1
-        pod.pop(SIG_MEMO_KEY, None)  # internal marker; keep result objects clean
         self.pods_on_node[node_i].append(pod)
 
     # Commit-log sentinel: a bulk entry is ("__bulk__", store_view, rows) —
@@ -439,8 +440,8 @@ class Simulator:
                            tables) -> List[UnscheduledPod]:
         """Apply a whole run's placements to host state as array ops — the
         columnar replacement for P calls to _commit_pod. Ordering contracts
-        that keep it bit-identical to the per-pod loop (the double-encode
-        parity suite's commit half):
+        that keep it bit-identical to the per-pod loop (tests/test_store.py
+        holds the two to the same census):
         - PlacedGroup.node_counts keys are inserted in first-appearance order
           of (template, node) over the pod sequence — exactly the order the
           per-pod loop would have inserted them, so the f32 seed accumulation
@@ -533,7 +534,6 @@ class Simulator:
                     reasons = reason_cache[key] = self._explain_reasons(
                         pod, key[0], key[1], tables,
                         seg_carry_of.get(key[2], final_carry))
-                pod.pop(SIG_MEMO_KEY, None)
                 obs.record_filter_reasons(reasons)
                 failed.append(UnscheduledPod(
                     pod, self._format_reason(pod, reasons, self.na.N)))
@@ -558,7 +558,7 @@ class Simulator:
 
     # --------------------------------------------------------- scheduling ---------
 
-    def schedule_pods(self, pods: List[dict]) -> List[UnscheduledPod]:
+    def schedule_pods(self, pods) -> List[UnscheduledPod]:
         """The schedulePods loop (simulator.go:309-348), batched while preserving the
         reference's strictly serial order: runs of unbound pods become one compiled
         scan; a pre-bound pod (spec.nodeName) flushes the run first, then commits
@@ -584,7 +584,10 @@ class Simulator:
         segments of the run) stay in place and only this batch replays, on
         CPU, to the identical placements (serial-order determinism). The
         failover is recorded on backend_path and
-        simon_guard_failovers_total{cause}; it is never silent."""
+        simon_guard_failovers_total{cause}; it is never silent.
+
+        `pods`: a PodStore, or pod dicts, which come back as the same objects
+        in pods_on_node and the failure records (PodStore.of)."""
         from ..obs import scope
 
         sc = scope.active()  # one None-check: the scope-off hot path pays
@@ -601,14 +604,16 @@ class Simulator:
             self._xray_run = None
             obs.E2E_SECONDS.observe(span.total)
 
-    def _schedule_pods_contained(self, pods: List[dict]) -> List[UnscheduledPod]:
+    def _schedule_pods_contained(self, pods) -> List[UnscheduledPod]:
+        pods = PodStore.of(pods)  # inside the schedule_pods span
+
         def attempt():
             # fresh xray staging per ATTEMPT: records of a failed attempt
             # die with its rolled-back transaction, so a failover replay
             # never leaves phantom rows (committed records then carry the
             # full backend_path including the failover)
             self._xray_run = xray.begin_run("schedule")
-            with self._transaction(memo_pods=pods):
+            with self._transaction():
                 if self._track_priorities(pods):
                     from .preemption import schedule_with_preemption
 
@@ -761,7 +766,7 @@ class Simulator:
         self._commits_counted += n
 
     @contextlib.contextmanager
-    def _transaction(self, memo_pods: Optional[List[dict]] = None):
+    def _transaction(self):
         """Crash consistency for one scheduling/probe call: snapshot host
         state, arm full commit logging, and on ANY failure (1) count the
         partial batch's commits that died before their batch-end COMMITS
@@ -769,12 +774,7 @@ class Simulator:
         rolled commits as simon_commit_rollbacks_total and re-materialized
         eviction victims as commits, so commits − rollbacks − victims is
         bit-identical to the pre-call value. Placements, census, pod dicts,
-        and the gpushare/open-local ledgers all return to the snapshot.
-
-        `memo_pods`: pods to strip SIG_MEMO_KEY from on rollback — a schedule
-        call never leaves the internal marker behind on any path, success or
-        failure. Probe calls pass None: their pods keep memos BY DESIGN
-        (repeated probes skip re-encoding), on success and failure alike."""
+        and the gpushare/open-local ledgers all return to the snapshot."""
         from .preemption import restore, snapshot
 
         with Span("schedule_pods.snapshot"):
@@ -792,12 +792,6 @@ class Simulator:
                 obs.COMMITS.inc(uncounted)
             with Span("schedule_pods.restore"):
                 restore(self, snap)
-            # store batches never carry per-pod memos (templates do,
-            # transiently) — iterating one here would materialize the whole
-            # batch as dicts mid-failover, the exact cost the store removes
-            if not is_pod_store(memo_pods):
-                for p in memo_pods or ():
-                    p.pop(SIG_MEMO_KEY, None)
             raise
         else:
             # rollback info is only reachable within this call's restores;
@@ -807,7 +801,7 @@ class Simulator:
         finally:
             self._txn_armed = prev
 
-    def _track_priorities(self, pods: List[dict]) -> bool:
+    def _track_priorities(self, pods: PodStore) -> bool:
         """Arm the PostFilter when >1 distinct priority has been seen across
         ALL schedule_pods calls (cluster pods and app pods schedule in separate
         calls, and a priority gap BETWEEN those sets is exactly where the
@@ -816,112 +810,63 @@ class Simulator:
             return False
         seen = self._priority_seen
         with Span("schedule_pods.priorities"):
-            if is_pod_store(pods):
-                seen.update(pods.priorities_present())
-            else:
-                seen.update((p.get("spec") or {}).get("priority") or 0
-                            for p in pods)
+            seen.update(pods.priorities_present())
         self._preempt_armed = len(seen) > 1
         return self._preempt_armed
 
-    def _schedule_pods_inner(self, pods: List[dict]) -> List[UnscheduledPod]:
-        if is_pod_store(pods):
-            return self._schedule_store_inner(pods)
+    def _schedule_pods_inner(self, pods: PodStore) -> List[UnscheduledPod]:
+        """The serial loop over one batch: each unbound run is one
+        _schedule_run, and a pre-bound pod, after the run before it, commits
+        directly (PodStore.split_bound)."""
         from ..utils.trace import Progress
 
         failed: List[UnscheduledPod] = []
-        run: List[dict] = []
-        # None when disabled so the per-pod loops skip the call entirely
-        # (100k no-op advance() calls are measurable on the headline bench)
         progress = Progress("Scheduling pods", len(pods),
                             enabled=not self.disable_progress)
-        self._progress = progress if progress.enabled else None
         xr = self._xray_run
         direct = None  # lazy xray batch for pre-bound/homeless direct commits
-        for pod in pods:  # simonlint: ignore[per-pod-host-loop] -- dict-batch run split; PodStore batches take _schedule_store_inner
-            node_name = (pod.get("spec") or {}).get("nodeName")
-            if not node_name:
-                run.append(pod)
-                continue
+        for run, bound in pods.split_bound():  # simonlint: ignore[per-pod-host-loop] -- O(runs of pre-bound rows): one (run view, row range) pair each
             failed.extend(self._schedule_run(run))
-            run = []
-            if self._progress is not None:
-                self._progress.advance(1)
-            ni = self.na.index.get(node_name)
-            if xr is not None and direct is None:
-                direct = xr.new_batch(self.na.names, self._cfg_digest(), [])
-            if ni is None:
-                # Parity: the reference's fakeclient accepts pods bound to unknown
-                # nodes and getClusterNodeStatus (simulator.go:277-301) silently drops
-                # them from every report; we keep them findable on self.homeless.
-                pod.pop(SIG_MEMO_KEY, None)
-                self.homeless.append(pod)
-                obs.SCHED_ATTEMPTS.labels(result="homeless").inc()
-                if direct is not None:
-                    direct.add_pod(xray.pod_key(pod), xray.HOMELESS, -1, -1, -1)
-            else:
-                self._commit_pod(pod, ni, scheduled=False)
-                obs.SCHED_ATTEMPTS.labels(result="bound").inc()
-                self._count_commits()
-                if direct is not None:
-                    direct.add_pod(xray.pod_key(pod), xray.BOUND, ni, -1, -1)
-        failed.extend(self._schedule_run(run))
+            for pod, ni in self._commit_bound(pods, bound):  # simonlint: ignore[per-pod-host-loop] -- pre-bound rows only: each commits on its own, as the reference's Create does
+                obs.SCHED_ATTEMPTS.labels(
+                    result="bound" if ni >= 0 else "homeless").inc()
+                if ni >= 0:
+                    self._count_commits()
+                if xr is not None:
+                    if direct is None:
+                        direct = xr.new_batch(self.na.names,
+                                              self._cfg_digest(), [])
+                    direct.add_pod(xray.pod_key(pod),
+                                   xray.BOUND if ni >= 0 else xray.HOMELESS,
+                                   ni, -1, -1)
+            progress.advance(len(run) + len(bound))
         progress.close()
         if self.gpu_host.enabled:
             self.gpu_host.flush()
         return failed
 
-    def _schedule_store_inner(self, pods: "PodStore") -> List[UnscheduledPod]:
-        """The inner loop for a columnar PodStore: the run split
-        (pre-bound pods flush the unbound run first — identical serial
-        semantics) comes from one vectorized mask instead of a per-pod scan.
-        Pre-bound rows materialize (they are read-back pods by definition:
-        the direct-commit path touches their dicts); unbound stretches ride
-        _schedule_run as store views."""
-        failed: List[UnscheduledPod] = []
-        self._progress = None  # columnar batches never render progress
-        bound = pods.bound_mask()
-        if bound is None:
-            failed.extend(self._schedule_run(pods))
-        else:
-            xr = self._xray_run
-            direct = None
-            n_rows = len(pods)
-            bound_idx = np.flatnonzero(bound)
-            prev = 0
-            # O(bound rows), not O(pods): each iteration is one pre-bound
-            # pod plus one store-view run over the unbound stretch before it
-            for bi in np.append(bound_idx, n_rows).tolist():
-                if bi > prev:
-                    failed.extend(self._schedule_run(pods[prev:bi]))
-                prev = bi + 1
-                if bi >= n_rows:
-                    break
-                pod = pods[bi]  # materializes: direct commits mutate dicts
-                node_name = (pod.get("spec") or {}).get("nodeName")
-                ni = self.na.index.get(node_name)
-                if xr is not None and direct is None:
-                    direct = xr.new_batch(self.na.names, self._cfg_digest(),
-                                          [])
-                if ni is None:
-                    pod.pop(SIG_MEMO_KEY, None)
-                    self.homeless.append(pod)
-                    obs.SCHED_ATTEMPTS.labels(result="homeless").inc()
-                    if direct is not None:
-                        direct.add_pod(xray.pod_key(pod), xray.HOMELESS,
-                                       -1, -1, -1)
-                else:
-                    self._commit_pod(pod, ni, scheduled=False)
-                    obs.SCHED_ATTEMPTS.labels(result="bound").inc()
-                    self._count_commits()
-                    if direct is not None:
-                        direct.add_pod(xray.pod_key(pod), xray.BOUND, ni,
-                                       -1, -1)
-        if self.gpu_host.enabled:
-            self.gpu_host.flush()
-        return failed
+    def _commit_bound(self, pods: PodStore,
+                      rows: range) -> Iterator[Tuple[dict, int]]:
+        """Commit pre-bound rows `rows` (view indices) of `pods` without a
+        scheduling cycle, like the reference's fakeclient Create
+        (simulator.go:326-331), yielding each row's pod and node, or -1 for
+        a node this simulator does not know. Parity: the reference accepts
+        such a pod and getClusterNodeStatus (simulator.go:277-301) silently
+        drops it from every report; it stays findable on self.homeless."""
+        sigs = pods.base.sigs
+        tis = pods.tmpl_rows()[rows.start:rows.stop].tolist()
+        index = self.na.index
+        for i, ti in zip(rows, tis):
+            # materialized: a direct commit mutates the dict
+            pod = pods.materialize(pods.lo + i)
+            ni = index.get(pod["spec"]["nodeName"], -1)
+            if ni < 0:
+                self.homeless.append(pod)
+            else:
+                self._commit_pod(pod, ni, scheduled=False, sig=sigs[ti])
+            yield pod, ni
 
-    def encode_batch(self, to_schedule: List[dict],
+    def encode_batch(self, to_schedule,
                      classes: bool = False) -> BatchTables:
         """Encode a pod batch into device-ready tables (no scheduling). Exposed for
         the bench/graft harnesses and the parallel (mesh-sharded) path.
@@ -929,7 +874,10 @@ class Simulator:
         (_unit_groups) — schedule_pods' own encode, whose commit keeps each
         pod's template; callers that read results per group (serve, sweep,
         probe) keep one group per signature."""
-        bt = self.encode_batch_raw(to_schedule, classes)
+        return self._padded(self.encode_batch_raw(to_schedule, classes))
+
+    def _padded(self, bt: BatchTables) -> BatchTables:
+        """encode_batch's pads over raw tables."""
         with Span("encode.pads"):
             # Pad encoder-derived axes (G/T/Tc/D/ports/term slots) to pow2 buckets:
             # the encoder interns cumulatively across apps, so without this every
@@ -952,7 +900,7 @@ class Simulator:
                 target += (-target) % shards
             return pad_batch_tables(bt, target)
 
-    def encode_batch_raw(self, to_schedule: List[dict],
+    def encode_batch_raw(self, to_schedule,
                          classes: bool = False) -> BatchTables:
         """encode_batch WITHOUT the encoder-axis/node-axis padding: the exact
         per-group/per-counter table content at this simulator's real axis sizes.
@@ -962,10 +910,14 @@ class Simulator:
         faults.maybe_fail("encode")
         with Span("encode.ids"):
             batch = self.encode_batch_ids(to_schedule, classes)
+        return self._tables(batch)
+
+    def _tables(self, batch: EncodedRows) -> BatchTables:
+        """The raw tables of encoded pod rows: the pod axis and the node
+        axis, each its own phase."""
         # Pad the scan length to bound compile-cache churn: powers of two up to 2048,
         # then multiples of 2048 (a 10k batch scans 10240 steps, not 16384).
         pad = bucket_capped(len(batch), 2048)
-        # build_batch_tables' two halves, each its own phase
         with Span("encode.pod_tables"):
             pod_side = build_pod_axis_tables(self.encoder, batch, pad_to=pad)
         # the ROADMAP-5 instrument: streaming chunks re-enter here once per
@@ -977,65 +929,33 @@ class Simulator:
         pulse.phase("table_build", sp.total)
         return BatchTables(**pod_side, **node_side)
 
-    def encode_batch_ids(self, to_schedule: List[dict],
-                         classes: bool = False) -> List[Tuple[int, int]]:
+    def encode_batch_ids(self, to_schedule,
+                         classes: bool = False) -> EncodedRows:
         """The pod-axis half of an encode: (group_id, forced_node) per pod, in
         order, interning new groups into the shared encoder. The serving
         image's micro-batcher (serve/batch.py) calls this alone on its warm
-        path — when every group is already interned, a request encode is a
-        dict lookup per pod and the resident node-side tables are reused
-        untouched."""
-        if is_pod_store(to_schedule):
-            return self._encode_store_ids(to_schedule, classes)
-        # parallel lists, not a tuple per pod: each tuple would be one more
-        # object for the cyclic collector to promote (see _unit_groups)
-        tmpls: List[dict] = []
-        sigs: List[object] = []
-        forced_of: List[int] = []
-        for pod in to_schedule:  # simonlint: ignore[per-pod-host-loop] -- dict-batch encode; PodStore batches take _encode_store_ids
-            # strip_daemon_pin can only fire on pods with node affinity; the
-            # inline guard keeps the (common) affinity-less pod off the call
-            if ((pod.get("spec") or {}).get("affinity")) is not None:
-                stripped, target = strip_daemon_pin(pod)
-            else:
-                stripped, target = pod, None
-            if target is None:
-                # memoize so _commit_pod (and repeated encodes) never
-                # recompute; pinned pods keep per-pod signatures below
-                sig = pod.get(SIG_MEMO_KEY)
-                if sig is None:
-                    sig = pod[SIG_MEMO_KEY] = scheduling_signature(pod)
-                tmpls.append(pod)
-                sigs.append(sig)
-                forced_of.append(-1)
-            elif target in self.na.index:
-                tmpls.append(stripped)
-                sigs.append(None)
-                forced_of.append(self.na.index[target])
-            else:
-                # pin to a node this simulator doesn't know: the memo (stamped
-                # from the UNPINNED template) must not merge this pod into the
-                # unconstrained group — its required matchFields affinity is
-                # unsatisfiable and the pinned signature keeps it that way
-                pod.pop(SIG_MEMO_KEY, None)
-                tmpls.append(pod)
-                sigs.append(None)
-                forced_of.append(-1)
-        return list(zip(self._unit_groups(tmpls, sigs, classes), forced_of))
+        path, where the resident node-side tables are reused untouched.
+        The entry of encode_batch and encode_batch_raw too: `to_schedule`
+        is a PodStore or pod dicts (PodStore.of).
 
-    def _encode_store_ids(self, store: PodStore,
-                          classes: bool = False) -> EncodedRows:
-        """encode_batch_ids for a columnar store view: one group interning +
-        daemon-pin decision per TEMPLATE (not per pod), then a vectorized
-        gather maps the decisions over the rows. Replicas of one template
-        are scheduling-identical, so the per-template (group, forced) pair
-        IS each row's pair, the pair the per-pod path gives each row."""
+        One group interning and daemon-pin decision per TEMPLATE, in the
+        order the view's rows first show each, then a vectorized gather maps
+        the decisions over the rows: replicas of one template are
+        scheduling-identical, so the template's (group, forced) pair is each
+        row's."""
+        store = PodStore.of(to_schedule)
         b = store.base
         tmpl_rows = store.tmpl_rows()
         n_t = len(b.templates)
         tg = np.zeros(n_t, np.int32)
         tf = np.full(n_t, -1, np.int32)
-        used = np.flatnonzero(np.bincount(tmpl_rows, minlength=n_t)).tolist()
+        if len(tmpl_rows) > 1 and (np.diff(tmpl_rows) < 0).any():
+            # a wrapped dict list revisits templates: first-appearance order
+            u, first = np.unique(tmpl_rows, return_index=True)
+            used = u[np.argsort(first)].tolist()
+        else:  # block rows never revisit one
+            used = np.flatnonzero(
+                np.bincount(tmpl_rows, minlength=n_t)).tolist()
         tmpls: List[dict] = []
         sigs: List[object] = []
         for ti in used:
@@ -1052,9 +972,9 @@ class Simulator:
                 tmpls.append(stripped)
                 sigs.append(None)
             else:
-                # pinned to an unknown node: the RAW template signature keeps
-                # the unsatisfiable matchFields pin (engine parity — see the
-                # per-pod path's memo handling)
+                # pinned to a node this simulator doesn't know: the template's
+                # own signature keeps the unsatisfiable matchFields pin, so the
+                # pod never merges into the unconstrained group
                 tmpls.append(tmpl)
                 sigs.append(None)
         tg[used] = self._unit_groups(tmpls, sigs, classes)
@@ -1062,8 +982,8 @@ class Simulator:
 
     def _unit_groups(self, tmpls: List[dict], sigs: List[object],
                      fold: bool) -> List[int]:
-        """The group id of each unit — a pod, or a store template, with its
-        signature — in order, interned in first-appearance order. A unit
+        """The group id of each unit — a store template, with its
+        signature — in order, interned in that order. A unit
         without a signature (a daemon pin) interns its own template. With
         `fold`, unpinned signature groups that differ only where no
         selector looks share one scheduling class (_classes): its template
@@ -1397,7 +1317,7 @@ class Simulator:
             segs.append(("serial", ser_start, P - ser_start))
         return segs
 
-    def _schedule_run(self, to_schedule: List[dict]) -> List[UnscheduledPod]:
+    def _schedule_run(self, to_schedule: PodStore) -> List[UnscheduledPod]:
         failed: List[UnscheduledPod] = []
         if not to_schedule:
             return failed
@@ -1415,15 +1335,6 @@ class Simulator:
                                -1, -1, reason=u.reason)
             return out
         chunk = self._stream_chunk
-        if chunk and is_pod_store(to_schedule) and not self._stream_explicit:
-            # Columnar batches have no per-pod host encode to overlap and
-            # their per-run buffers are already O(templates) + a few [P]
-            # arrays — chunking them only re-pays the node-axis table build
-            # per chunk. By default stream only to bound the [P] working set
-            # at extreme sizes (the 10M-pod row runs as a handful of
-            # chunks); an EXPLICIT OPEN_SIMULATOR_STREAM_PODS applies as-is
-            # (the bench-gate RSS workload pins a small chunk on purpose).
-            chunk = max(chunk, 2_097_152)
         if chunk and len(to_schedule) > chunk:
             return self._schedule_run_streaming(to_schedule, chunk)
         try:
@@ -1434,70 +1345,26 @@ class Simulator:
                 raise
             return self._bisect_oom(to_schedule, site, e)
 
-    def _schedule_run_streaming(self, to_schedule,
+    def _schedule_run_streaming(self, to_schedule: PodStore,
                                 chunk: int) -> List[UnscheduledPod]:
-        """Streaming segment encode: a run longer than
-        OPEN_SIMULATOR_STREAM_PODS schedules as fixed-size chunks, each an
-        ordinary _schedule_run — the OOM-bisection bit-identity argument
-        (tests/test_guard.py) makes the chunked run's placements provably
-        identical to the monolithic one, because chunk k's commits seed
-        chunk k+1's encode exactly as the serial loop would have.
-
-        Double buffering: while chunk k's dispatch is in flight, a worker
-        thread computes chunk k+1's scheduling signatures (the dominant
-        per-pod encode cost for dict batches; columnar stores need no
-        prefetch — their encode is already O(templates)). The worker touches
-        ONLY chunk k+1's pod dicts and only stamps the same memo the main
-        thread would compute, so interning order — and therefore every table
-        — is untouched. guard-compat: all device work stays on this thread
-        under the usual watchdog; a failure joins the worker, then the
-        transaction rolls the whole call back and failover replays it, so
-        crash/failover semantics are exactly the unstreamed ones. Memory:
-        per-chunk tables/choices cap the host working set instead of scaling
-        with the full run (the bench-gate RSS budget leans on this)."""
-        import threading
-
+        """Streaming: a run longer than OPEN_SIMULATOR_STREAM_PODS schedules
+        as fixed-size chunks, each an ordinary _schedule_run — the
+        OOM-bisection bit-identity argument (tests/test_guard.py) makes the
+        chunked run's placements provably identical to the monolithic one,
+        because chunk k's commits seed chunk k+1's encode exactly as the
+        serial loop would have. All of it is one transaction under the usual
+        watchdog, so crash and failover semantics are the unstreamed ones.
+        Memory: per-chunk tables/choices cap the host working set instead of
+        scaling with the full run."""
         P = len(to_schedule)
         failed: List[UnscheduledPod] = []
-        starts = list(range(0, P, chunk))
-        use_prefetch = not is_pod_store(to_schedule)
-        worker: Optional[threading.Thread] = None
-
-        def prefetch(pods_slice) -> None:
-            try:
-                for pod in pods_slice:
-                    # (iter name is chunk-local, not the whole batch: this is
-                    # the O(chunk) prefetch the streaming path exists for)
-                    # pin-carrying pods keep their main-thread treatment
-                    # (strip_daemon_pin decides their memo semantics)
-                    if ((pod.get("spec") or {}).get("affinity")) is not None:
-                        continue
-                    if SIG_MEMO_KEY not in pod:
-                        pod[SIG_MEMO_KEY] = scheduling_signature(pod)
-            except Exception:  # simonlint: ignore[swallowed-exception] -- pure precompute; the main thread recomputes and raises the real error
-                pass
-
-        try:
-            for k, off in enumerate(starts):
-                if worker is not None:
-                    worker.join()
-                    worker = None
-                if use_prefetch and k + 1 < len(starts):
-                    nxt = to_schedule[starts[k + 1]:
-                                      min(starts[k + 1] + chunk, P)]
-                    worker = threading.Thread(
-                        target=prefetch, args=(nxt,), daemon=True,
-                        name="simon-stream-prefetch")
-                    worker.start()
-                obs.STREAM_CHUNKS.inc()
-                failed.extend(
-                    self._schedule_run(to_schedule[off:min(off + chunk, P)]))
-        finally:
-            if worker is not None:
-                worker.join()
+        for off in range(0, P, chunk):
+            obs.STREAM_CHUNKS.inc()
+            failed.extend(
+                self._schedule_run(to_schedule[off:min(off + chunk, P)]))
         return failed
 
-    def _bisect_oom(self, to_schedule: List[dict], site: str,
+    def _bisect_oom(self, to_schedule: PodStore, site: str,
                     err: BaseException) -> List[UnscheduledPod]:
         """Contain a device OOM by scheduling the batch as two halves.
 
@@ -1519,7 +1386,7 @@ class Simulator:
         failed.extend(self._schedule_run(to_schedule[mid:]))
         return failed
 
-    def _schedule_run_once(self, to_schedule: List[dict]) -> List[UnscheduledPod]:
+    def _schedule_run_once(self, to_schedule: PodStore) -> List[UnscheduledPod]:
         # simonpulse run window: dispatch records inside carry this run's id;
         # the run record closes with the LIVE pod count (supervised sees
         # padded counts — useless for attempts reconciliation) and the
@@ -1540,7 +1407,7 @@ class Simulator:
                                                span)
         return failed
 
-    def _dispatch_and_commit(self, to_schedule: List[dict], bt: BatchTables,
+    def _dispatch_and_commit(self, to_schedule: PodStore, bt: BatchTables,
                              tables, carry, span) -> List[UnscheduledPod]:
         failed: List[UnscheduledPod] = []
         P = len(to_schedule)
@@ -1756,7 +1623,6 @@ class Simulator:
             self._last_tables, self._last_carry = bt, final_carry
         pulse.phase("fetch", sp_fetch.total)
 
-        progress = getattr(self, "_progress", None)
         reason_cache: Dict[Tuple[int, int, int], Dict[str, int]] = {}
         set_cache: Dict[Tuple[int, int, int], int] = {}  # key -> run-local sid
 
@@ -1771,15 +1637,13 @@ class Simulator:
             return sid
 
         with Span("commit") as sp_commit:
-            # Vectorized bulk commit (simulator/store.py): a columnar batch with
-            # the per-pod bookkeeping provably unneeded — no flight recorder, no
-            # armed preemption (which needs per-pod _sig_of rows), no
-            # gpu/local-storage ledgers (whose reserve() writes per-pod
-            # annotations) — applies the whole run's placements as array ops.
-            # Everything else takes the per-pod loop below, which materializes
-            # store rows transparently.
-            if (is_pod_store(to_schedule) and xb is None
-                    and not self._preempt_armed
+            # Vectorized bulk commit (simulator/store.py): the whole run's
+            # placements as array ops. Four things need a per-pod commit and
+            # take the loop below, which materializes store rows: the flight
+            # recorder (a decision record per pod), armed preemption (per-pod
+            # _sig_of rows and undo log), and the gpushare and open-local
+            # ledgers (whose reserve() writes per-pod annotations).
+            if (xb is None and not self._preempt_armed
                     and not self.gpu_host.enabled
                     and not self.local_host.enabled):
                 failed.extend(self._commit_store_bulk(
@@ -1792,9 +1656,9 @@ class Simulator:
                     pg_l = bt.pod_group[:P].tolist()
                     fn_l = bt.forced_node[:P].tolist()
                     seg_l = seg_of.tolist()
-                for i, pod in enumerate(to_schedule):  # simonlint: ignore[per-pod-host-loop] -- store-less fallback; columnar batches ride _commit_store_bulk
-                    if progress is not None:
-                        progress.advance(1)
+                sigs = to_schedule.base.sigs
+                tmpl_l = to_schedule.tmpl_rows().tolist()
+                for i, pod in enumerate(to_schedule):  # simonlint: ignore[per-pod-host-loop] -- per-pod commit for its four reasons above; every other run rides _commit_store_bulk
                     node_i = int(choices[i])
                     if xb is not None:
                         key = (pg_l[i], fn_l[i], seg_l[i])
@@ -1804,7 +1668,7 @@ class Simulator:
                     else:
                         key = None
                     if node_i >= 0:
-                        self._commit_pod(pod, node_i)
+                        self._commit_pod(pod, node_i, sig=sigs[tmpl_l[i]])
                         if xb is not None:
                             xb.add_pod(xray.pod_key(pod), xray.SCHEDULED, node_i,
                                        key[2], xray_sid(key), group=key[0])
@@ -1819,7 +1683,6 @@ class Simulator:
                                 pod, key[0], key[1], tables,
                                 seg_carry_of.get(int(seg_of[i]), final_carry)
                             )
-                        pod.pop(SIG_MEMO_KEY, None)
                         obs.record_filter_reasons(reasons)
                         reason = self._format_reason(pod, reasons, self.na.N)
                         if xb is not None:
@@ -1849,14 +1712,15 @@ class Simulator:
 
     # ------------------------------------------------------------- probing -------
 
-    def probe_pods(self, pods: List[dict]) -> Tuple[int, int]:
+    def probe_pods(self, pods) -> Tuple[int, int]:
         """Capacity-probe scheduling: how many of `pods` would schedule, without
         materializing placements. Pre-bound pods commit normally (they are
         cluster state the probe must account); every unbound pod joins ONE
         device run whose results are counted but never written back — no pod
-        mutation, no placed records, no failure diagnosis. Pods keep their
-        signature memos, so repeated probes over the same list skip the
-        per-pod encoding cost. Returns (scheduled, total).
+        mutation, no placed records, no failure diagnosis. Returns
+        (scheduled, total). `pods`: a PodStore, or pod dicts (PodStore.of);
+        repeated probes over one batch convert it once and pass the store
+        (CapacityPlanner does), so no probe signs a pod again.
 
         Caveats the caller must own (CapacityPlanner.try_build guards both):
         pre-bound pods all commit BEFORE the unbound run regardless of list
@@ -1875,6 +1739,8 @@ class Simulator:
         a probe run would let the second half see placements the first never
         committed, changing the counted semantics)."""
         from ..obs import scope
+
+        pods = PodStore.of(pods)
 
         def attempt():
             self._xray_run = xray.begin_run("probe")
@@ -1896,36 +1762,33 @@ class Simulator:
         finally:
             self._xray_run = None
 
-    def _probe_pods_inner(self, pods: List[dict]) -> Tuple[int, int]:
-        run: List[dict] = []
+    def _probe_pods_inner(self, pods: PodStore) -> Tuple[int, int]:
+        runs: List[PodStore] = []
         scheduled = 0
         homeless = 0
-        if is_pod_store(pods) and pods.bound_mask() is None:
-            run = pods  # columnar fast path: no pre-bound rows, no per-pod scan
-        else:
-            for pod in pods:  # simonlint: ignore[per-pod-host-loop] -- pre-bound split for dict batches (stores carrying bound rows materialize by definition)
-                node_name = (pod.get("spec") or {}).get("nodeName")
-                if not node_name:
-                    run.append(pod)
-                    continue
-                ni = self.na.index.get(node_name)
-                if ni is None:
+        for run, bound in pods.split_bound():  # simonlint: ignore[per-pod-host-loop] -- O(runs of pre-bound rows): one (run view, row range) pair each
+            if len(run):
+                runs.append(run)
+            for _, ni in self._commit_bound(pods, bound):  # simonlint: ignore[per-pod-host-loop] -- pre-bound rows only: each commits on its own
+                if ni < 0:
                     homeless += 1
-                    self.homeless.append(pod)
                 else:
-                    self._commit_pod(pod, ni, scheduled=False)
                     scheduled += 1
         total_known = len(pods) - homeless
-        if not run:
+        if not runs or self.na.N == 0:
             return scheduled, total_known
-        if self.na.N == 0:
-            return scheduled, total_known
-        bt = self.encode_batch(run)
+        # pre-bound rows between unbound runs: the runs' rows as one run
+        faults.maybe_fail("encode")
+        with Span("encode.ids"):
+            parts = [self.encode_batch_ids(r) for r in runs]
+        rows = EncodedRows(np.concatenate([e.pod_group for e in parts]),
+                           np.concatenate([e.forced_node for e in parts]))
+        bt = self._padded(self._tables(rows))
         obs.ENCODE_BYTES.inc(batch_tables_nbytes(bt))
         tables, carry = self._to_device(bt)
         enable_gpu, enable_storage = plugin_flags(bt)
         jnp = _jax()
-        P = len(run)
+        P = len(rows)
         segs = self._segments(bt, P) if self.use_waves else [("serial", 0, P)]
         dims = self._dispatch_dims(bt)
         # probes never stage xray decision sets against mid-batch carries, so

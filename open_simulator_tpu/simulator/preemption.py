@@ -66,7 +66,6 @@ from ..obs import instruments as obs
 from ..resilience import guard
 from ..utils.objutil import labels_of, match_label_selector, name_of, namespace_of
 from .encode import (
-    SIG_MEMO_KEY,
     PlacedGroup,
     bucket_capped,
     build_batch_tables,
@@ -75,6 +74,7 @@ from .encode import (
     plugin_flags,
     scheduling_signature,
 )
+from .store import EncodedRows, PodStore
 
 # First-failing-stage classification, in the engine's stage order
 # (engine._STAGE_ORDER). UnschedulableAndUnresolvable stages can never be
@@ -259,7 +259,8 @@ def _fits(sim, g: int, node_i: int, placed2) -> bool:
     compiled feasibility kernel, read the one node's bit."""
     import jax.numpy as jnp
 
-    bt = build_batch_tables(sim.encoder, [(g, -1)], placed2, sim.match_cache,
+    one = EncodedRows(np.array([g], np.int32), np.array([-1], np.int32))
+    bt = build_batch_tables(sim.encoder, one, placed2, sim.match_cache,
                             pad_to=1)
     bt = pad_encoder_axes(bt)
     bt = pad_batch_tables(bt, bucket_capped(sim.na.N, 1024))
@@ -331,7 +332,6 @@ def try_preempt(sim, pod: dict) -> Tuple[int, List[dict], Dict[str, int]]:
     import jax.numpy as jnp
 
     bt = sim.encode_batch([pod])
-    pod.pop(SIG_MEMO_KEY, None)  # keep the (possibly recorded) pod dict clean
     tables, carry = sim._to_device(bt)
     enable_gpu, enable_storage = plugin_flags(bt)
     g, forced = int(bt.pod_group[0]), int(bt.forced_node[0])
@@ -425,8 +425,8 @@ def _select_victims_on_node(sim, g: int, node_i: int, prio: int) -> Optional[dic
     removed = list(potential)
     victims: List[dict] = []
     pdb_violations = 0
-    for batch, is_violating in ((violating, True), (non_violating, False)):
-        for p in batch:
+    for tier, is_violating in ((violating, True), (non_violating, False)):
+        for p in tier:
             # reprieve: add p back; keep it iff the preemptor still fits
             removed.remove(p)
             if not _fits(sim, g, node_i, _placed_minus(sim, removed, node_i)):
@@ -512,7 +512,7 @@ def _max_replays() -> int:
         return 512
 
 
-def schedule_with_preemption(sim, pods: List[dict]) -> List[UnscheduledPod]:
+def schedule_with_preemption(sim, pods: PodStore) -> List[UnscheduledPod]:
     """schedule_pods with the PostFilter armed (mixed priorities present).
 
     The batched run goes first; each failure that might preempt gets the exact
@@ -526,7 +526,7 @@ def schedule_with_preemption(sim, pods: List[dict]) -> List[UnscheduledPod]:
     if not failed:
         return failed
     recorded: List[UnscheduledPod] = []
-    remaining = list(pods)
+    remaining = pods
     # (signature, priority) → len(_commits_prio) at the failed attempt. The
     # priority is part of the key because scheduling_signature excludes
     # spec.priority: a later same-spec pod with HIGHER priority sees a larger
@@ -589,7 +589,7 @@ def schedule_with_preemption(sim, pods: List[dict]) -> List[UnscheduledPod]:
             return recorded
 
 
-def _select_target(sim, remaining: List[dict], failed: List[UnscheduledPod],
+def _select_target(sim, remaining: PodStore, failed: List[UnscheduledPod],
                    attempted: Dict[object, int]) -> Optional[int]:
     """First failed pod worth a preemption attempt, by serial position."""
     fail_ids = {id(u.pod) for u in failed}

@@ -80,6 +80,7 @@ from .encode import (
     pad_encoder_axes,
     plugin_flags,
 )
+from .store import PodStore
 
 _jnp = None
 
@@ -119,14 +120,16 @@ class ProbeSession:
 
     @classmethod
     def try_build(cls, base_nodes: List[dict], new_node: Optional[dict],
-                  pods: List[dict], cluster_objects=None,
+                  pods, cluster_objects=None,
                   app_objects: Sequence = (), sched_config=None,
                   n_new: int = 2, fanout: int = 8,
                   mesh=None) -> Optional["ProbeSession"]:
         """Build a session able to probe up to (at least) n_new template
-        copies, or None when the workload fails an equivalence gate."""
+        copies, or None when the workload fails an equivalence gate.
+        `pods`: a PodStore or pod dicts (PodStore.of)."""
         from .engine import Simulator
 
+        pods = PodStore.of(pods)
         if new_node is None:
             return None
         if guard.default_quarantined():
@@ -167,27 +170,22 @@ class ProbeSession:
         # shares); the unbound remainder becomes the one encoded run.
         from ..utils.objutil import pod_resource_requests
 
-        run: List[dict] = []
         bound_scheduled = 0
         bound_cpu = bound_mem = 0.0
         homeless = 0
-        for pod in pods:
-            node_name = (pod.get("spec") or {}).get("nodeName")
-            if not node_name:
-                run.append(pod)
-                continue
-            if run:
+        for run, bound in pods.split_bound():  # simonlint: ignore[per-pod-host-loop] -- O(runs of pre-bound rows): one (run view, row range) pair each
+            if not bound:
+                break
+            if len(run):
                 return None  # bound-after-unbound: probe order-inequivalent
-            ni = sim.na.index.get(node_name)
-            if ni is None:
-                homeless += 1
-                sim.homeless.append(pod)
-            else:
-                sim._commit_pod(pod, ni, scheduled=False)
-                bound_scheduled += 1
-                req = pod_resource_requests(pod)
-                bound_cpu += req.get("cpu", 0.0)
-                bound_mem += req.get("memory", 0.0)
+            for pod, ni in sim._commit_bound(pods, bound):  # simonlint: ignore[per-pod-host-loop] -- pre-bound rows only: each commits on its own
+                if ni < 0:
+                    homeless += 1
+                else:
+                    bound_scheduled += 1
+                    req = pod_resource_requests(pod)
+                    bound_cpu += req.get("cpu", 0.0)
+                    bound_mem += req.get("memory", 0.0)
 
         self = object.__new__(cls)
         self._sim = sim
@@ -217,8 +215,9 @@ class ProbeSession:
         # constraints reject the session anyway, so don't pay a 100k-pod
         # encode just to discover that (the group-level check below stays as
         # the authoritative backstop)
-        if any((p.get("spec") or {}).get("topologySpreadConstraints")
-               for p in run):
+        tmpls = run.base.templates
+        if any((tmpls[t].get("spec") or {}).get("topologySpreadConstraints")
+               for t in run.templates_present()):
             return None
 
         bt_raw = sim.encode_batch_raw(run)

@@ -1,26 +1,27 @@
 """Columnar host stores: struct-of-arrays pod batches and node sets.
 
-The host side of the engine was a dict-of-dicts world: every pod a Python
-dict, every encode a per-pod traversal, every commit a handful of dict
-mutations. That is fine at 10k pods and ruinous at 10M (ROADMAP item 2 —
-~60% of the 1M-pod row's wall was Python-side encode + commit bookkeeping).
-This module keeps the expensive representation staged once and lets the
-engine view it zero-copy, the same move serve/image.py made on the device
-side (Orca, PAPERS.md):
+The engine has one pod-batch form past its entry: the PodStore. Every
+public entry (`Simulator.schedule_pods`, `probe_pods`, `encode_batch*`, the
+capacity prober's build, serve's requests) turns what it is handed into one
+with `PodStore.of` exactly once; every internal function takes a store. This
+module keeps the expensive representation staged once and lets the engine
+view it zero-copy, the same move serve/image.py made on the device side
+(Orca, PAPERS.md):
 
-- **PodStore** — a pod batch as template blocks: each block is one validated
-  pod template plus a replica count and a name recipe. Columns (`tmpl_of`,
-  `node_of`, `commit_seq`) are numpy arrays over the whole batch; the
-  scheduling-relevant content lives once per TEMPLATE, so `encode_batch_ids`
-  is one group interning per template plus one vectorized gather
-  (`EncodedRows`), and the engine's bulk commit writes placements as array
-  ops. Per-pod dicts are materialized lazily — only for the few pods a
+- **PodStore** — a pod batch as templates: each row names one validated pod
+  template, plus a name recipe. Columns (`tmpl_of`, `node_of`, `commit_seq`)
+  are numpy arrays over the whole batch; the scheduling-relevant content
+  lives once per TEMPLATE, so `encode_batch_ids` is one group interning per
+  template plus one vectorized gather (`EncodedRows`), and the engine's bulk
+  commit writes placements as array ops. A store is built two ways:
+  `add_block` appends replicas of one template (workload generators, the
+  benchmark), and `PodStore.of` wraps a caller's dict list, one template per
+  distinct signature, each row's dict cached as its materialization. Per-pod
+  dicts of block rows are materialized lazily — only for the few pods a
   caller actually reads back (failure records, preemption victims,
   `pods_on_node` listings) — and a materialized dict is cached so its
   identity is stable. A PodStore is Sequence-compatible: code that iterates
-  or indexes it transparently gets pod dicts, bit-identical to the dicts the
-  legacy path would have carried (the double-encode parity suite in
-  tests/test_store.py holds the two encodes to byte equality).
+  or indexes it gets pod dicts, the caller's own for a wrapped dict list.
 
 - **NodeStore** — the node set as blocks sharing one template (allocatable,
   taint pattern, constant labels) plus indexed label recipes (hostname,
@@ -43,6 +44,7 @@ can never observe a dict/column split-brain.
 
 from __future__ import annotations
 
+import copy
 import itertools
 import pickle
 from typing import Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple
@@ -55,16 +57,15 @@ from .encode import SIG_MEMO_KEY
 
 __all__ = [
     "EncodedRows", "PodStore", "NodeStore", "LazyNodeSeq",
-    "NodePodList", "PodsOnNode", "is_pod_store",
+    "NodePodList", "PodsOnNode",
 ]
 
 
 class EncodedRows(Sequence):
     """The pod-axis encode of a store view: (group_id, forced_node) as
-    columns. Sequence-compatible with the legacy List[(g, f)] — len,
-    iteration, and indexing all yield row tuples, so lane assemblers
-    (serve/sweep) consume it unchanged; the engine and
-    build_pod_axis_tables use the arrays directly."""
+    columns. A Sequence of (g, f) rows — len, iteration, and indexing all
+    yield row tuples, so lane assemblers (serve/sweep) read rows; the
+    engine and build_pod_axis_tables use the arrays directly."""
 
     __slots__ = ("pod_group", "forced_node")
 
@@ -85,8 +86,12 @@ class EncodedRows(Sequence):
         return (int(self.pod_group[i]), int(self.forced_node[i]))
 
 
-def is_pod_store(obj) -> bool:
-    return isinstance(obj, PodStore)
+def _priority(spec: dict) -> int:
+    """spec.priority as an int, 0 where unset or malformed."""
+    try:
+        return int(spec.get("priority") or 0)
+    except (TypeError, ValueError):
+        return 0
 
 
 class _PodBase:
@@ -122,6 +127,16 @@ class _PodBase:
         self.node_names: Optional[Sequence[str]] = None
         self.frozen = False
 
+    def add_template(self, template: dict, sig) -> int:
+        """Intern one template (held by reference) with its signature."""
+        spec = template.get("spec") or {}
+        self.templates.append(template)
+        self.blobs.append(None)  # pickled lazily on first materialization
+        self.sigs.append(sig)
+        self.tmpl_priority.append(_priority(spec))
+        self.tmpl_bound.append(bool(spec.get("nodeName")))
+        return len(self.templates) - 1
+
     def _columns(self) -> None:
         """Append the pending blocks' rows to the row columns, once."""
         if not self._pending:
@@ -155,11 +170,12 @@ class _PodBase:
 class PodStore(Sequence):
     """A columnar pod batch (or a contiguous view of one).
 
-    Build with add_block(); schedule by passing the store straight to
-    Simulator.schedule_pods / probe_pods. Slicing returns a view sharing the
-    commit columns (the engine's OOM bisection and streaming chunks slice
-    freely); copy.deepcopy returns an independent store with its own commit
-    state and materialization cache (the sweep oracle's isolation contract).
+    Build with add_block(), or wrap a dict list with PodStore.of(); schedule
+    by passing the store straight to Simulator.schedule_pods / probe_pods.
+    Slicing returns a view sharing the commit columns (the engine's OOM
+    bisection and streaming chunks slice freely); copy.deepcopy returns an
+    independent store with its own commit state and materialization cache
+    (the sweep oracle's isolation contract).
     """
 
     def __init__(self, _base: Optional[_PodBase] = None,
@@ -188,18 +204,9 @@ class PodStore(Sequence):
             return self
         if names is not None and len(names) != count:
             raise ValueError("names length != count")
-        ti = len(b.templates)
-        b.templates.append(template)
-        b.blobs.append(None)  # pickled lazily on first materialization
         from .encode import scheduling_signature
 
-        b.sigs.append(scheduling_signature(template))
-        spec = template.get("spec") or {}
-        try:
-            b.tmpl_priority.append(int(spec.get("priority") or 0))
-        except (TypeError, ValueError):
-            b.tmpl_priority.append(0)
-        b.tmpl_bound.append(bool(spec.get("nodeName")))
+        ti = b.add_template(template, scheduling_signature(template))
         b.blk_tmpl.append(ti)
         b.blk_fmt.append(name_fmt)
         b.blk_names.append(list(names) if names is not None else None)
@@ -218,6 +225,61 @@ class PodStore(Sequence):
         self._b.cache[row] = pod
         self._b.row_by_id[id(pod)] = row
         return self
+
+    @classmethod
+    def of(cls, pods) -> "PodStore":
+        """The engine's one pod-batch form. A PodStore passes through; a
+        dict sequence becomes a store whose rows ARE the caller's dicts
+        (each cached as its row's materialization, as add_pod does), with
+        one template per distinct (signature, priority, nodeName): the
+        encode then interns once per signature. An unbound template is a
+        copy of its first pod, because commits write into the rows' dicts
+        and later runs of this store still encode from the template; a
+        pre-bound row never enters a run, so its template is the pod itself.
+
+        A workload-expansion memo (SIG_MEMO_KEY) is the signature hint and
+        is consumed here, so no dict past the entry carries it. A pod with
+        spec.affinity is keyed by its own full signature: DaemonSet
+        replicas share the memo of the unpinned template but pin different
+        nodes, and one template would put them all on one node. The memo
+        still names such a pod's placed-census group, as it did per pod."""
+        if isinstance(pods, PodStore):
+            return pods
+        from .encode import scheduling_signature
+
+        store = cls()
+        b = store._b
+        pods = list(pods)
+        if not pods:
+            return store
+        by_key: Dict[tuple, int] = {}
+        tmpl_of: List[int] = []
+        for pod in pods:
+            memo = pod.pop(SIG_MEMO_KEY, None)
+            spec = pod.get("spec") or {}
+            full = (scheduling_signature(pod)
+                    if memo is None or spec.get("affinity") is not None
+                    else memo)
+            node = spec.get("nodeName")
+            key = (full, _priority(spec), node)
+            ti = by_key.get(key)
+            if ti is None:
+                ti = by_key[key] = b.add_template(
+                    pod if node else pickle.loads(pickle.dumps(pod, -1)),
+                    full if memo is None else memo)
+            tmpl_of.append(ti)
+        n = len(pods)
+        b._tmpl_of = np.array(tmpl_of, np.int32)
+        b._node_of = np.full(n, -1, np.int32)
+        b._blk_start = np.array([0, n], np.int64)  # simonlint: ignore[dtype-drift] -- host-side row offsets
+        b.blk_tmpl.append(0)
+        b.blk_fmt.append(None)
+        b.blk_names.append(None)
+        b.blk_name_base.append(None)
+        b.n_rows = store._hi = n
+        b.cache = dict(enumerate(pods))
+        b.row_by_id = {id(p): i for i, p in enumerate(pods)}
+        return store
 
     # ----------------------------------------------------------- sequence --
 
@@ -260,6 +322,10 @@ class PodStore(Sequence):
         nb.commit_seq = (b.commit_seq.copy()
                          if b.commit_seq is not None else None)
         nb.node_names = b.node_names
+        # materialized rows carry what no template holds (a wrapped dict's
+        # own name and content): the copy takes copies of them
+        nb.cache = copy.deepcopy(b.cache, memo)
+        nb.row_by_id = {id(d): r for r, d in nb.cache.items()}
         return PodStore(nb)
 
     # ------------------------------------------------------------- columns --
@@ -286,22 +352,38 @@ class PodStore(Sequence):
         with Span("readback"):
             return self._b.node_of[self._lo:self._hi]
 
+    def templates_present(self) -> List[int]:
+        """Distinct template indices of this view's rows, ascending."""
+        return np.unique(self.tmpl_rows()).tolist()
+
     def priorities_present(self) -> List[int]:
         """Distinct spec.priority values across this view's templates."""
-        tis = np.unique(self.tmpl_rows())
-        return sorted({self._b.tmpl_priority[int(t)] for t in tis})
+        return sorted({self._b.tmpl_priority[t]
+                       for t in self.templates_present()})
 
     def bound_mask(self) -> Optional[np.ndarray]:
         """[P] bool of rows whose template is pre-bound (spec.nodeName set),
         or None when no template in the view is bound (the common case)."""
         b = self._b
-        if not any(b.tmpl_bound[int(t)] for t in np.unique(self.tmpl_rows())):
+        if not any(b.tmpl_bound):
             return None
-        bound_t = np.array(b.tmpl_bound, bool)
-        return bound_t[self.tmpl_rows()]
+        bound = np.array(b.tmpl_bound, bool)[self.tmpl_rows()]
+        return bound if bound.any() else None
 
-    def sig_of_row(self, abs_row: int):
-        return self._b.sigs[int(self._b.tmpl_of[abs_row])]
+    def split_bound(self) -> Iterator[Tuple["PodStore", range]]:
+        """The reference's serial order around pre-bound pods: (an unbound
+        run, the view indices of the pre-bound rows right after it), in
+        order; the last pair's range is empty. Runs are views and may be
+        empty; the cost is O(runs of pre-bound rows), not O(rows)."""
+        bound = self.bound_mask()
+        prev = 0
+        if bound is not None:
+            edges = np.flatnonzero(np.diff(bound, prepend=False,
+                                           append=False)).tolist()
+            for lo, hi in zip(edges[0::2], edges[1::2]):
+                yield self[prev:lo], range(lo, hi)
+                prev = hi
+        yield self[prev:], range(0)
 
     def template_of_row(self, abs_row: int) -> dict:
         return self._b.templates[int(self._b.tmpl_of[abs_row])]
@@ -316,6 +398,16 @@ class PodStore(Sequence):
         """Absolute row of a materialized pod dict, or None (identity map,
         populated at materialization)."""
         return self._b.row_by_id.get(id(pod))
+
+    def signed_copies(self) -> List[dict]:
+        """Deep copies of this view's pods, each carrying its row's
+        signature as the memo: PodStore.of reads it back, so a copy that
+        enters the engine again is not signed again."""
+        sigs = self._b.sigs
+        out = [copy.deepcopy(p) for p in self]
+        for pod, ti in zip(out, self.tmpl_rows().tolist()):
+            pod[SIG_MEMO_KEY] = sigs[ti]
+        return out
 
     # ------------------------------------------------------ materialization --
 
@@ -360,10 +452,12 @@ class PodStore(Sequence):
 
     def cached_rows_in(self, rows: np.ndarray) -> List[Tuple[int, dict]]:
         """(abs_row, dict) for the subset of `rows` already materialized —
-        the bulk commit/rollback patch set (cache-sized, never O(rows))."""
+        the bulk commit/rollback patch set, O(min(cache, rows))."""
         cache = self._b.cache
         if not cache:
             return []
+        if len(cache) > len(rows):  # a wrapped dict list caches every row
+            return [(r, cache[r]) for r in rows.tolist() if r in cache]
         rs = set(rows.tolist())
         return [(r, d) for r, d in cache.items() if r in rs]
 

@@ -195,7 +195,7 @@ class SweepRunner:
         if self.image is None:
             return ("fresh", "image declined (cluster gate)")
         session = self.image.session(sc.pods, drains=sc.drains)
-        gate = self.image.eligible(session.batch, sc.pods)
+        gate = self.image.eligible(session.batch, session.pods)
         if gate is not None:
             return ("fresh", gate)
         segs = self._wave_segments(sc, session.batch)
@@ -491,10 +491,11 @@ class SweepRunner:
         oracle — and what the bench's serial loop times."""
         sim, bound = self._fresh_sim(sc)
         request = [copy.deepcopy(p) for p in sc.pods]
-        # signatures snapshot BEFORE scheduling: _commit_pod writes
-        # spec.nodeName (part of the signature subtree) and pops the memo,
-        # so a post-schedule signature would be node-dependent and never
-        # match the batched lane's pre-schedule census keys
+        # signatures snapshot BEFORE scheduling: a commit writes
+        # spec.nodeName (part of the signature subtree) and the entry
+        # consumes the memo, so a post-schedule signature would be
+        # node-dependent and never match the batched lane's pre-schedule
+        # census keys
         sig_of = {(p["metadata"].get("namespace", "default"),
                    p["metadata"]["name"]): scheduling_signature(p)
                   for p in request}

@@ -256,8 +256,8 @@ def synth_cluster_store(
     """Columnar twin of synth_cluster: the SAME cluster and workload, emitted
     as a (NodeStore, PodStore) pair (simulator/store.py) — one node template
     block and one pod template block per synth "app" instead of n dicts. The
-    double-encode parity suite (tests/test_store.py) asserts a Simulator over
-    this form encodes and places bit-identically to the dict form; at 1M+
+    parity suite (tests/test_store.py) asserts a Simulator over this form
+    encodes and places bit-identically to the dict form; at 1M+
     pods this is the only form that fits in host memory at all."""
     from ..simulator.store import NodeStore, PodStore
 

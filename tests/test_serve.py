@@ -274,6 +274,40 @@ def test_ineligible_requests_route_fresh():
     svc.stop()
 
 
+def test_gated_request_signs_each_pod_once(monkeypatch):
+    """A request gated to the fresh path probes copies that carry the
+    session's signatures: the conversions sign each request pod once (a
+    new group's template is signed where the encoder interns it)."""
+    import sys
+
+    from open_simulator_tpu.simulator import encode
+
+    nodes, bound = make_cluster(8, 3)
+    img = ResidentImage.try_build(nodes, pods=bound)
+    svc = WhatIfService(img, window_ms=0.0)
+    pods = whatif_pods("gate", 4)
+    pods[0]["spec"]["nodeName"] = "n-1"
+    names = sorted(p["metadata"]["name"] for p in pods)
+    signed = []
+    real = encode.scheduling_signature
+
+    def spy(pod):
+        if sys._getframe(1).f_code.co_name == "of":  # PodStore.of
+            signed.append(pod["metadata"]["name"])
+        return real(pod)
+
+    monkeypatch.setattr(encode, "scheduling_signature", spy)
+    try:
+        r = svc.submit(pods)
+    finally:
+        svc.stop()
+    assert r["path"] == "fresh" and r["total"] == 4
+    assert sorted(n for n in signed if n.startswith("wi-gate")) == names
+    monkeypatch.setattr(encode, "scheduling_signature", real)
+    assert_same_response(r, img.fresh_probe(whatif_pods("gate", 4)[1:] + [
+        dict(pods[0])]))
+
+
 # ------------------------------------------------------------- non-donation ----
 
 

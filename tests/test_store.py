@@ -1,11 +1,13 @@
-"""Double-encode parity + behavior of the columnar host path
-(simulator/store.py): a Simulator fed a PodStore/NodeStore must encode
-BIT-IDENTICAL BatchTables and produce bit-identical placements to the same
-workload as plain dicts — including the workloads that route OFF the bulk
-path (gpushare, local storage, pre-bound pods, armed preemption), where the
-store transparently materializes. The lazy read-back boundary, bulk-commit
-rollback, streaming chunk equivalence, and the serve image staged from a
-store are covered here too (ISSUE 15 acceptance)."""
+"""Parity + behavior of the columnar host path (simulator/store.py): a
+dict batch, converted once at the engine's entry (PodStore.of: one template
+per signature, the caller's dicts as rows), and the block PodStore/NodeStore
+a workload generator builds must encode BIT-IDENTICAL BatchTables and
+produce bit-identical placements — including the workloads that route OFF
+the bulk commit (gpushare, local storage, armed preemption), where store
+rows materialize. The lazy read-back boundary, bulk-commit rollback,
+streaming chunk equivalence, the serve image staged from a store, and the
+entry's conversion itself (pre-bound splits, DaemonSet pins, the caller's
+dicts handed back) are covered here too."""
 
 from __future__ import annotations
 
@@ -18,7 +20,7 @@ import numpy as np
 import pytest
 
 from open_simulator_tpu.resilience import faults
-from open_simulator_tpu.simulator.encode import scheduling_signature
+from open_simulator_tpu.simulator.encode import SIG_MEMO_KEY, scheduling_signature
 from open_simulator_tpu.simulator.engine import Simulator
 from open_simulator_tpu.simulator.store import (
     EncodedRows,
@@ -65,8 +67,8 @@ def pod_template(**kw):
 
 
 def run_both(nodes, pods, store_nodes, store_pods, use_waves=True):
-    """Schedule the dict form and the store form; assert encode + placement
-    bit-identity; return the two simulators."""
+    """Schedule the dict form (converted at the entry) and the block store;
+    assert encode + placement bit-identity; return the two simulators."""
     simd = Simulator(nodes, use_mesh=False)
     simd.use_waves = use_waves
     sims = Simulator(store_nodes, use_mesh=False)
@@ -772,3 +774,221 @@ def test_store_node_arrays_extend_matches_dicts():
     simd.na.extend([copy.deepcopy(added)])
     assert decoded_columns(sims.na) == decoded_columns(simd.na)
     assert sims.na.nodes[len(nodes)] == simd.na.nodes[len(nodes)]
+
+
+# ------------------------------------------------------ the entry's edge --
+#
+# A dict batch becomes a PodStore once, at the entry (PodStore.of): one
+# template per signature, the caller's dicts cached as the rows. Each case
+# schedules it beside the block store a workload generator would build.
+
+EDGE_CASES = ("no_bound", "bound_first", "bound_between", "bound_unknown",
+              "bound_runs", "bound_last", "daemonset")
+
+
+def _edge_nodes():
+    return [synth_node(i, cpu_milli=4000, pods=16) for i in range(6)]
+
+
+def _edge_pods(case, nodes):
+    from fixtures import make_daemonset
+    from open_simulator_tpu.models.workloads import pods_from_daemonset
+
+    def named(tmpl, prefix, n):
+        out = []
+        for i in range(n):
+            p = copy.deepcopy(tmpl)
+            p["metadata"]["name"] = f"{prefix}-{i:02d}"
+            out.append(p)
+        return out
+
+    big = named(pod_template(cpu_milli=900), "big", 20)
+    small = named(pod_template(cpu_milli=500, labels={"app": "s"}), "small",
+                  10)
+    bound = named(pod_template(cpu_milli=1500), "bound", 1)[0]
+    if case == "no_bound":
+        return big + small
+    if case == "bound_first":
+        bound["spec"]["nodeName"] = "node-00002"
+        return [bound] + big + small
+    if case == "bound_between":
+        bound["spec"]["nodeName"] = "node-00002"
+        return big + [bound] + small
+    if case == "bound_unknown":
+        bound["spec"]["nodeName"] = "node-nowhere"
+        return big + [bound] + small
+    if case in ("bound_runs", "bound_last"):
+        # consecutive pre-bound rows, one of them on an unknown node
+        trio = named(pod_template(cpu_milli=1500), "bound", 3)
+        for p, node in zip(trio, ("node-00002", "node-nowhere",
+                                  "node-00004")):
+            p["spec"]["nodeName"] = node
+        if case == "bound_last":
+            return big + small + trio
+        return big[:10] + trio + big[10:] + small
+    # DaemonSet replicas: one pinned pod per node, all sharing the memo of
+    # the unpinned template
+    ds = pods_from_daemonset(make_daemonset("agent", cpu="300m"), nodes)
+    assert len({p[SIG_MEMO_KEY] for p in ds}) == 1
+    return big + ds + small
+
+
+def _block_store(pods):
+    """The block PodStore of `pods`: one block per run of pods equal but
+    for their name, templates without the memo."""
+    def body(p):
+        q = copy.deepcopy(p)
+        q.pop(SIG_MEMO_KEY, None)
+        q["metadata"].pop("name")
+        return q
+
+    ps = PodStore()
+    run = []
+    for p in pods + [None]:
+        if run and (p is None or body(p) != body(run[0])):
+            ps.add_block(body(run[0]), len(run),
+                         names=[q["metadata"]["name"] for q in run])
+            run = []
+        if p is not None:
+            run.append(p)
+    return ps
+
+
+def _names(pods):
+    return sorted(p["metadata"]["name"] for p in pods)
+
+
+@pytest.mark.parametrize("case", EDGE_CASES)
+def test_edge_conversion_matches_block_store(case):
+    from open_simulator_tpu.simulator.probe import ProbeSession
+
+    nodes = _edge_nodes()
+    pods = _edge_pods(case, nodes)
+    mine = copy.deepcopy(pods)
+    simd = Simulator(copy.deepcopy(nodes), use_mesh=False)
+    sims = Simulator(copy.deepcopy(nodes), use_mesh=False)
+    failed_d = simd.schedule_pods(mine)
+    failed_s = sims.schedule_pods(_block_store(pods))
+    assert census_of(simd) == census_of(sims)
+    assert fail_names(failed_d) == fail_names(failed_s)
+    assert _names(simd.homeless) == _names(sims.homeless)
+    assert len(simd.homeless) == (case in ("bound_unknown", "bound_runs",
+                                           "bound_last"))
+
+    # the caller's own dicts come back, placed ones written as a commit
+    # writes them, and none carries the memo
+    ids = {id(p) for p in mine}
+    placed = [p for lst in simd.pods_on_node for p in lst]
+    assert len(placed) + len(failed_d) + len(simd.homeless) == len(mine)
+    back = placed + [u.pod for u in failed_d] + simd.homeless
+    assert {id(p) for p in back} == ids
+    for i, lst in enumerate(simd.pods_on_node):
+        for p in lst:
+            assert p["spec"]["nodeName"] == simd.na.names[i]
+            assert p["status"] == {"phase": "Running"}
+    assert not any(SIG_MEMO_KEY in p for p in mine)
+
+    # probes: the dict list and the block store count the same, and the
+    # prober's build agrees with them wherever it builds
+    counts = Simulator(copy.deepcopy(nodes)).probe_pods(copy.deepcopy(pods))
+    assert counts == Simulator(copy.deepcopy(nodes)).probe_pods(
+        _block_store(pods))
+    template = synth_node(99, cpu_milli=4000, pods=16)
+    built = [ProbeSession.try_build(copy.deepcopy(nodes), template, form,
+                                    n_new=2, fanout=1)
+             for form in (copy.deepcopy(pods), _block_store(pods))]
+    assert (built[0] is None) == (built[1] is None)
+    if case in ("bound_between", "bound_unknown", "bound_runs",
+                "bound_last"):
+        assert built[0] is None  # bound after unbound: order-inequivalent
+    for session in built:
+        if session is not None:
+            assert session.probe_many([0])[0][:2] == counts
+
+
+@pytest.mark.parametrize("mask, pairs", [
+    ("", [(0, 0, 0, 0)]),
+    ("..", [(0, 2, 0, 0)]),
+    ("b..", [(0, 0, 0, 1), (1, 3, 0, 0)]),
+    (".bb.b", [(0, 1, 1, 3), (3, 4, 4, 5), (5, 5, 0, 0)]),
+    ("..bb", [(0, 2, 2, 4), (4, 4, 0, 0)]),
+])
+def test_split_bound_pairs_runs_with_bound_ranges(mask, pairs):
+    """(unbound run, range of the pre-bound rows right after it), in
+    order, the last range empty; in view indices of a view too."""
+    pods = []
+    for i, c in enumerate("u" + mask + "u"):
+        p = pod_template(cpu_milli=100 + i)
+        p["metadata"]["name"] = f"p{i}"
+        if c == "b":
+            p["spec"]["nodeName"] = f"node-{i:05d}"
+        pods.append(p)
+    view = PodStore.of(pods)[1:-1]
+    got = [(run.lo - view.lo, run.hi - view.lo, bound.start, bound.stop)
+           for run, bound in view.split_bound()]
+    assert got == pairs
+
+
+def test_pre_bound_rows_are_their_own_templates():
+    """A pre-bound row never enters a run, so its template is the pod
+    itself; an unbound template is a copy, since commits write the rows."""
+    pods = _edge_pods("bound_runs", _edge_nodes())
+    store = PodStore.of(pods)
+    b = store.base
+    assert len(b.templates) == 5  # big, the three pre-bound pods, small
+    for row, pod in enumerate(pods):
+        tmpl = b.templates[int(b.tmpl_of[row])]
+        if pod["spec"].get("nodeName"):
+            assert tmpl is pod
+        else:
+            assert tmpl is not pod and id(tmpl) not in b.row_by_id
+
+
+def test_signed_copies_are_not_signed_again(monkeypatch):
+    """A store's signed copies carry each row's signature, so converting
+    them again signs only the pods keyed by their own full signature (the
+    DaemonSet pins), and every row keeps its signature."""
+    from open_simulator_tpu.simulator import encode
+
+    pods = _edge_pods("daemonset", _edge_nodes())
+    store = PodStore.of(pods)
+    copies = store.signed_copies()
+    assert all(c is not p for c, p in zip(copies, pods))
+    assert not any(SIG_MEMO_KEY in p for p in pods)
+    signed = []
+    real = encode.scheduling_signature
+    monkeypatch.setattr(encode, "scheduling_signature",
+                        lambda p: signed.append(p["metadata"]["name"])
+                        or real(p))
+    again = PodStore.of(copies)
+    assert signed and all(n.startswith("agent") for n in signed)
+    assert ([again.base.sigs[t] for t in again.tmpl_rows()]
+            == [store.base.sigs[t] for t in store.tmpl_rows()])
+    assert not any(SIG_MEMO_KEY in c for c in copies)
+
+
+def test_dict_batch_takes_the_bulk_commit(monkeypatch, tmp_path):
+    from open_simulator_tpu.obs import xray
+
+    nodes = _edge_nodes()
+    pods = _edge_pods("no_bound", nodes)
+    calls = []
+    real = Simulator._commit_pod
+
+    def counted(self, *a, **kw):
+        calls.append(1)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(Simulator, "_commit_pod", counted)
+    bulk = Simulator(copy.deepcopy(nodes), use_mesh=False)
+    failed = bulk.schedule_pods(copy.deepcopy(pods))
+    assert not calls  # no feature on: one bulk commit, no per-pod commit
+    xray.enable(str(tmp_path / "trace"))
+    try:
+        per_pod = Simulator(copy.deepcopy(nodes), use_mesh=False)
+        failed_x = per_pod.schedule_pods(copy.deepcopy(pods))
+    finally:
+        xray.disable()
+    assert len(calls) == len(pods) - len(failed_x)  # the recorder's loop
+    assert census_of(bulk) == census_of(per_pod)
+    assert fail_names(failed) == fail_names(failed_x)
